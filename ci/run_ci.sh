@@ -13,10 +13,18 @@ cd "$(dirname "$0")/.."
 MODE="${1:-full}"
 
 echo "=== [1/20] native libraries ==="
-make -C native
+python - <<'PYEOF'
+# both libraries build from the tracked .cpp through the loader the
+# engine itself uses; a missing toolchain fails CI here, loudly
+from spark_rapids_tpu import native
+from spark_rapids_tpu.native import _loader
+from spark_rapids_tpu.shuffle import native_tcp
+assert native.available() and native_tcp.available(), _loader.LOADED
+print(_loader.LOADED)
+PYEOF
 
 echo "=== [2/20] API contract validation ==="
-timeout 300 python tools/api_validation.py
+JAX_PLATFORMS=cpu timeout 300 python tools/api_validation.py
 
 echo "=== [3/20] docgen drift check ==="
 timeout 300 python -m spark_rapids_tpu.docgen
@@ -102,12 +110,17 @@ PYEOF
 timeout 60 python tools/check_trace.py \
     --prometheus "$SRT_FR_DIR/metrics.prom" \
     --doctor "$SRT_FR_DIR/doctor.json"
-# sentinel smoke: diff the two banked rounds (both stale replays -> same
-# evidence class, allowed); then prove the live-vs-stale gate refuses
-timeout 60 python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+# sentinel smoke: two generated stale replays (same evidence class,
+# allowed) diff cleanly; then prove the live-vs-stale gate refuses
+printf '{"metric":"x","value":8,"rows":1,"platform":"tpu","captured_at":"2026-08-01T00:00:00Z"}' \
+    > "$SRT_FR_DIR/stale_a.json"
+printf '{"metric":"x","value":9,"rows":1,"platform":"tpu","captured_at":"2026-08-02T00:00:00Z"}' \
+    > "$SRT_FR_DIR/stale_b.json"
+timeout 60 python tools/bench_diff.py "$SRT_FR_DIR/stale_a.json" \
+    "$SRT_FR_DIR/stale_b.json"
 printf '{"metric":"x","value":9,"rows":1,"platform":"tpu","evidence":"live"}' \
     > "$SRT_FR_DIR/live.json"
-if python tools/bench_diff.py "$SRT_FR_DIR/live.json" BENCH_r05.json \
+if python tools/bench_diff.py "$SRT_FR_DIR/live.json" "$SRT_FR_DIR/stale_b.json" \
         >/dev/null 2>&1; then
     echo "ERROR: bench_diff failed to refuse live-vs-stale"; exit 1
 fi
@@ -754,7 +767,7 @@ fi
 
 if [ "$MODE" != quick ]; then
     echo "=== [17/20] scale rig ==="
-    SRT_SCALE_PLATFORM=cpu timeout 3600 \
+    JAX_PLATFORMS=cpu timeout 3600 \
         python -m spark_rapids_tpu.testing.scaletest 100000
 else
     echo "=== [17/20] scale rig skipped (quick) ==="

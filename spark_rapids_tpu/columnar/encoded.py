@@ -1,9 +1,7 @@
 """Encoded column representations that survive through the engine.
 
 "GPU Acceleration of SQL Analytics on Compressed Data" (PAPERS.md) shows
-operators can run directly on encoded columns; the engine's profile says
-it is transfer-bound, not compute-bound (BENCH_r05: 0.221 GB/s/chip on q1,
-0.0134 on the join shape vs ~820 GB/s HBM).  This module generalizes the
+operators can run directly on encoded columns.  This module generalizes the
 ``columnar/prepack.py`` narrow-before-the-wire trick into first-class
 encoded batch citizens:
 

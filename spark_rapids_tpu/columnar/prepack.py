@@ -1,5 +1,5 @@
 """Device-side pre-pack for host fetches: shrink bytes BEFORE they cross
-the wire (VERDICT r4 #3; reference analog: the nvcomp shuffle codecs,
+the wire (reference analog: the nvcomp shuffle codecs,
 ``NvcompLZ4CompressionCodec.scala:26`` + ``TableCompressionCodec.scala`` —
 the reference compresses table buffers on device before they travel).
 
@@ -232,9 +232,9 @@ def _min_bytes() -> int:
 
 
 def enabled() -> bool:
-    """'auto' (default) = on when the device is remote (non-CPU backend:
-    narrowing trades a little device compute + one probe RTT for a large
-    wire saving); 'true' forces on (tests/CPU-mesh measurement), 'false'
+    """'auto' (default) = on for a non-CPU backend (narrowing trades a
+    little device compute + one probe round trip for fewer bytes over the
+    host link); 'true' forces on (tests/CPU-mesh measurement), 'false'
     kills."""
     from ..config import D2H_PREPACK, RapidsConf
     try:

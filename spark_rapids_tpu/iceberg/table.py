@@ -615,7 +615,7 @@ class IcebergTable:
 
     def _device_scan_df(self, filters, snapshot_id, as_of_timestamp_ms):
         """Per-FILE device decode with schema-evolution projection
-        (VERDICT r4 #8 — the round-4 gate declined the whole scan when
+        (the round-4 gate declined the whole scan when
         ANY column mismatched).  Each delete-free file becomes a
         ``read.parquet`` frame projected to the snapshot schema:
 

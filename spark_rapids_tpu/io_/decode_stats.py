@@ -1,4 +1,4 @@
-"""Device-decode engagement counters (VERDICT round 5, Weak #7).
+"""Device-decode engagement counters.
 
 Every device decoder (parquet/ORC/CSV/JSON/Avro) either ENGAGES a file
 (builds device columns straight from raw bytes) or DECLINES it to the

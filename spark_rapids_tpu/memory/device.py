@@ -15,8 +15,9 @@ from typing import Optional
 
 from ..config import ALLOC_FRACTION, RESERVE_BYTES, RapidsConf
 
-#: fallback HBM size when the backend reports no memory stats (CPU tests)
-_DEFAULT_HBM_BYTES = 16 << 30
+#: accounted pool size on the CPU platform, whose devices report no memory
+#: stats (the test suite).  An accelerator that reports none is an error.
+_CPU_POOL_BYTES = 16 << 30
 
 
 class DeviceManager:
@@ -63,15 +64,15 @@ class DeviceManager:
 
     def hbm_bytes(self) -> int:
         if self._hbm_bytes is None:
-            stats = None
-            try:
-                stats = self.device.memory_stats()
-            except Exception:
-                stats = None
+            stats = self.device.memory_stats()
             if stats and stats.get("bytes_limit"):
                 self._hbm_bytes = int(stats["bytes_limit"])
+            elif self.device.platform == "cpu":
+                self._hbm_bytes = _CPU_POOL_BYTES
             else:
-                self._hbm_bytes = _DEFAULT_HBM_BYTES
+                raise RuntimeError(
+                    f"{self.device} reports no memory_stats()['bytes_limit']"
+                    f"; the buffer pool cannot be sized")
         return self._hbm_bytes
 
     def pool_limit_bytes(self) -> int:
@@ -81,10 +82,7 @@ class DeviceManager:
         return max(limit, 1 << 20)
 
     def bytes_in_use(self) -> int:
-        try:
-            stats = self.device.memory_stats()
-            if stats and stats.get("bytes_in_use") is not None:
-                return int(stats["bytes_in_use"])
-        except Exception:
-            pass
+        stats = self.device.memory_stats()
+        if stats and stats.get("bytes_in_use") is not None:
+            return int(stats["bytes_in_use"])
         return 0

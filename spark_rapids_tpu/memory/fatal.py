@@ -6,7 +6,7 @@ self-terminate with exit code 20 so Spark reschedules the work on a
 healthy executor; non-fatal errors stay task-local).
 
 TPU analog: a runtime ``XlaRuntimeError`` that is NOT a memory condition
-means the device/tunnel is in an unknown state.  The guard captures a
+means the device is in an unknown state.  The guard captures a
 diagnostics bundle (exception, backend/device info, spill-catalog state,
 live config) to ``spark.rapids.tpu.fatalDump.path`` and raises
 :class:`FatalDeviceError`; with ``spark.rapids.tpu.fatalErrorExit`` the

@@ -23,13 +23,9 @@ def _load() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        from ._loader import find_or_build
-        so = find_or_build("libsrt_native.so", "srt_native.cpp")
-        if so is None:
-            return None
-        try:
-            lib = ctypes.CDLL(so)
-        except OSError:
+        from ._loader import load
+        lib = load("libsrt_native.so", "srt_native.cpp")
+        if lib is None:
             return None
         _register(lib)
         _lib = lib

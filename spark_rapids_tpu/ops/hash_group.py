@@ -170,6 +170,22 @@ def _sorted_ids(jnp, keys, row_mask):
     return _first_occurrence_ids(jnp, jnp.clip(rank, 0, cap), row_mask, cap)
 
 
+def _statically_compact(cols) -> bool:
+    """True when the compact prelude is certain to accept these keys
+    whatever the data: every key is dictionary-encoded on a sorted
+    dictionary (its one key word is a code in ``[0, size]``, plus a null
+    digit), and the product of those static ranges fits the code space.
+    A trace-time fact — dictionary sizes are pytree aux data."""
+    from ..columnar.encoded import DictEncodedColumn, op_enabled
+    space = 1
+    for c in cols:
+        if not (isinstance(c, DictEncodedColumn) and c.dictionary.sorted
+                and op_enabled("aggsort")):
+            return False
+        space *= 2 * (c.dictionary.size + 1)
+    return space <= _COMPACT_MAX_CODES
+
+
 def _device_ids(jnp, cols, row_mask, make_probe):
     """Shared device-path scaffolding for :func:`group_ids` /
     :func:`group_ids_small`: build each key word ONCE (shared by the
@@ -181,7 +197,12 @@ def _device_ids(jnp, cols, row_mask, make_probe):
     keys = [w for nulls, ws in col_words
             for w in (nulls.astype(jnp.int64), *ws)]
     compact_ok, compact_codes = _compact_prelude(jnp, col_words, row_mask)
-    fallback = make_probe(keys) if _probe_beats_sort(jnp) else (
+    # keys whose code space is small BY CONSTRUCTION never reach the
+    # fallback; give them the probe kernel, which costs nothing to
+    # compile, instead of a variadic sort the chip's compiler spends
+    # minutes on (q1's two dictionary keys: a 10-operand sort, >600 s)
+    fallback = make_probe(keys) if (
+        _probe_beats_sort(jnp) or _statically_compact(cols)) else (
         lambda _: _sorted_ids(jnp, keys, row_mask))
     return jax.lax.cond(compact_ok,
                         lambda _: _compact_finish(jnp, compact_codes,

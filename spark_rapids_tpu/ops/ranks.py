@@ -45,8 +45,8 @@ def lex_sort(xp, keys):
 
     64-bit integer keys are split into (hi int32, lo uint32) comparator
     pairs: under the TPU toolchain's x64 rewrite a 64-bit sort comparator
-    lowers poorly (docs/perf_notes.md round-3 note — the split measured
-    faster to compile and no slower to run), and the lexicographic order
+    lowers poorly (the split measured faster to compile and no slower to
+    run), and the lexicographic order
     of (hi, lo-as-unsigned) equals the 64-bit order exactly (same hi =>
     two's-complement low words compare unsigned).  Sorted key values are
     reconstructed from the sorted pairs, so callers see the same

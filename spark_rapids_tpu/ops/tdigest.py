@@ -15,7 +15,7 @@ which is exactly the MergingDigest construction specialized to sorted
 input.  The state per group is a FIXED [C]-centroid layout (C = δ/2+2),
 so multi-batch and partial/merge flows are bounded at O(groups·C)
 device memory regardless of group size — the property the exact sorted
-selection lacks (VERDICT r2 #7).
+selection lacks.
 
 Merging digests is the same kernel run over the centroids as weighted
 rows.  Quantile queries interpolate between centroid midpoints with

@@ -18,6 +18,7 @@ the same plane as flat columns.
 
 from __future__ import annotations
 
+import collections
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -41,6 +42,17 @@ class MeshCollectiveTimeout(MeshShuffleUnsupported):
 #: observability: exchanges that actually rode the mesh plane (tests assert
 #: on this; the metrics layer reads it for the shuffle mode report)
 STATS = {"mesh_exchanges": 0, "fallbacks": 0, "collective_timeouts": 0}
+
+#: where the newest exchanges (newest last) left their data: the mesh's
+#: per-device ``bytes_in_use`` right after the exchange program returned
+#: (None where the backend reports no memory stats), the devices its
+#: outputs lay on, and the devices the batches handed on lie on
+RECENT_EXCHANGES: collections.deque = collections.deque(maxlen=32)
+
+
+def _lives_on(arrays) -> List[str]:
+    return sorted({f"{d.platform}:{d.id}" for a in arrays
+                   for d in a.devices()})
 
 
 def _collective_timed_out(detail: str) -> MeshCollectiveTimeout:
@@ -182,6 +194,37 @@ def _leaf_fold(leaf, cap: int):
     return leaf.reshape((cap, k) + tuple(leaf.shape[1:])), k
 
 
+def exchange_program(mesh, n_dev: int, cap: int, nleaves: int):
+    """The (un-jitted) mesh exchange step for ``nleaves`` folded leaves of
+    per-shard capacity ``cap``: ``step(valid, pids, *leaves)`` over
+    mesh-global arrays sharded on "data" — all_to_all row exchange, then
+    on-chip compaction of the received rows.  Returns
+    ``(count[n_dev], *leaves[n_dev * n_dev*cap, ...])``.  Separate from
+    :func:`mesh_shuffle_batches` so the chip-compiler tests can lower the
+    very program the exchange runs (tests/test_tpu_compile.py)."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from ..ops.join import compact_indices
+    from ..shims import shard_map as _shim_shard_map
+    from .shuffle import build_ici_shuffle
+    shard_map = _shim_shard_map()  # version-shimmed (shims/, L6 analog)
+    exchange = build_ici_shuffle(mesh, "data", n_dev, cap)
+
+    def step(valid, pids_, *leaves):
+        arrays = {str(j): leaf for j, leaf in enumerate(leaves)}
+        recv, rvalid = exchange(arrays, valid, pids_)
+        # on-chip compaction: received rows to the front, count live
+        perm = compact_indices(jnp, rvalid)
+        out = [jnp.take(recv[str(j)], perm, axis=0) for j in range(nleaves)]
+        count = jnp.sum(rvalid).astype(jnp.int32)
+        return (count[None], *out)
+
+    return shard_map(step, mesh=mesh,
+                     in_specs=(P("data"),) * (2 + nleaves),
+                     out_specs=(P("data"),) * (1 + nleaves))
+
+
 def mesh_shuffle_batches(mesh, batches: List, pids: List, nt: int) -> List:
     """Exchange ``n_dev`` per-shard batches into ``nt == n_dev`` target
     partitions through one compiled all_to_all program over ``mesh``.
@@ -202,14 +245,8 @@ def mesh_shuffle_batches(mesh, batches: List, pids: List, nt: int) -> List:
         raise _collective_timed_out("chaos-injected")
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from ..shims import shard_map as _shim_shard_map
-    shard_map = _shim_shard_map()  # version-shimmed (shims/, L6 analog)
 
     from ..columnar.batch import ColumnarBatch
-    from ..ops.join import compact_indices
-    from .shuffle import build_ici_shuffle
 
     n_dev = len(batches)
     if nt != n_dev:
@@ -242,47 +279,58 @@ def mesh_shuffle_batches(mesh, batches: List, pids: List, nt: int) -> List:
                               for p in pids])
     g_valid = jnp.concatenate([b.row_mask() for b in batches])
 
-    exchange = build_ici_shuffle(mesh, "data", n_dev, cap)
     out_cap = n_dev * cap
-    nleaves = len(g_leaves)
-
-    def step(valid, pids_, *leaves):
-        arrays = {str(j): leaf for j, leaf in enumerate(leaves)}
-        recv, rvalid = exchange(arrays, valid, pids_)
-        # on-chip compaction: received rows to the front, count live
-        perm = compact_indices(jnp, rvalid)
-        out = [jnp.take(recv[str(j)], perm, axis=0) for j in range(nleaves)]
-        count = jnp.sum(rvalid).astype(jnp.int32)
-        return (count[None], *out)
 
     # one compiled program per (mesh size, capacity, leaf signature) —
     # repeated collects of the same query reuse it (kernel_cache model)
     from ..sql.physical.kernel_cache import cached_jit
     key = ("mesh_shuffle", n_dev, cap,
            tuple((tuple(g.shape), str(g.dtype)) for g in g_leaves))
-
-    jitted = cached_jit(key, shard_map(
-        step, mesh=mesh,
-        in_specs=(P("data"),) * (2 + nleaves),
-        out_specs=(P("data"),) * (1 + nleaves)))
+    jitted = cached_jit(key, exchange_program(mesh, n_dev, cap,
+                                              len(g_leaves)))
 
     from ..config import MESH_COLLECTIVE_DEADLINE_MS, RapidsConf
     deadline_s = int(RapidsConf.get_global().get(
         MESH_COLLECTIVE_DEADLINE_MS)) / 1e3
+
+    # the stacked inputs lie on the home device (every upload and every
+    # earlier exchange's output does): lay them out over the mesh
+    # explicitly rather than leave it to jit, which refuses arrays that
+    # are committed to one device
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    on_mesh = NamedSharding(mesh, P("data"))
+    g_valid, g_pids, *g_leaves = jax.device_put(
+        [g_valid, g_pids, *g_leaves], on_mesh)
 
     def dispatch():
         with mesh:
             return jitted(g_valid, g_pids, *g_leaves)
 
     counts, *outs = _run_with_deadline(dispatch, deadline_s)
-    counts = np.asarray(counts)
+    counts = np.asarray(counts)   # waits for the program: outputs exist
     STATS["mesh_exchanges"] += 1
+    record = {"bytes_in_use": [(d.memory_stats() or {}).get("bytes_in_use")
+                               for d in mesh.devices.flat],
+              "program_outputs_live_on": _lives_on(outs)}
+
+    # Target t's rows are exactly shard t of every output (P("data") over
+    # n_dev devices, out_cap rows each).  Take that shard where it lies and
+    # bring it to the engine's home device, where every stage program runs:
+    # slicing the GLOBAL array instead leaves each batch spread over the
+    # whole mesh, and the next stage then asks XLA to partition a
+    # single-device program — which the chip refuses as soon as it holds a
+    # Pallas kernel ("Mosaic kernels cannot be automatically partitioned":
+    # the first four-chip run, PR 22).
+    from ..memory.device import DeviceManager
+    home = DeviceManager.get().device
+    shards = [{(s.index[0].start or 0) // out_cap: s.data
+               for s in g.addressable_shards} for g in outs]
 
     result = []
     for t in range(nt):
         leaves_t = []
-        for j, g in enumerate(outs):
-            leaf = g[t * out_cap:(t + 1) * out_cap]
+        for j in range(len(outs)):
+            leaf = jax.device_put(shards[j][t], home)
             if ks[j] != 1:
                 leaf = leaf.reshape((out_cap * ks[j],)
                                     + tuple(leaf.shape[2:]))
@@ -290,4 +338,7 @@ def mesh_shuffle_batches(mesh, batches: List, pids: List, nt: int) -> List:
         cols = tree_unflatten(treedef, leaves_t)
         result.append(ColumnarBatch.make(names, cols,
                                          int(counts[t])).shrunk())
+    record["batches_handed_on_live_on"] = _lives_on(
+        leaf for b in result for leaf in jax.tree_util.tree_leaves(b.columns))
+    RECENT_EXCHANGES.append(record)
     return result

@@ -4,7 +4,9 @@ SparkShimServiceProvider pattern (``ShimLoader.scala:46-76``,
 the Spark version; ours is the jax/jaxlib version: APIs this framework
 leans on have moved between releases (``shard_map`` graduated from
 ``jax.experimental``, the ``jax.tree`` namespace replaced ``tree_util``
-entry points), and one artifact must serve all of them.
+entry points).  One provider exists today (jax >= 0.6, the installed
+line); an older or newer jax whose surface differs gets a provider of its
+own when it is actually installed and tested.
 
 Providers are probed in order against the running jax version; the first
 match supplies the version-dependent API surface.  New jax releases get a
@@ -87,36 +89,8 @@ class JaxModernShim(ShimProvider):
         return jax.tree.unflatten
 
 
-class JaxLegacyShim(ShimProvider):
-    """jax 0.4.x-0.5.x: shard_map lives in jax.experimental; tree ops via
-    tree_util."""
-
-    min_version = (0, 4)
-    max_version = (0, 6)
-
-    def shard_map(self):
-        try:
-            from jax.experimental.shard_map import shard_map
-            return shard_map
-        except ImportError:  # some 0.5 builds re-exported it
-            import jax
-            return jax.shard_map
-
-    def tree_map(self):
-        import jax
-        return jax.tree_util.tree_map
-
-    def tree_flatten(self):
-        import jax
-        return jax.tree_util.tree_flatten
-
-    def tree_unflatten(self):
-        import jax
-        return jax.tree_util.tree_unflatten
-
-
 #: probe order — first match wins (ShimLoader service-provider probing)
-PROVIDERS: List[type] = [JaxModernShim, JaxLegacyShim]
+PROVIDERS: List[type] = [JaxModernShim]
 
 _lock = threading.Lock()
 _active: Optional[ShimProvider] = None

@@ -36,14 +36,10 @@ def _load() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        from ..native._loader import find_or_build
-        so = find_or_build("libsrt_transport.so", "srt_transport.cpp",
-                           extra_flags=("-pthread",))
-        if so is None:
-            return None
-        try:
-            lib = ctypes.CDLL(so)
-        except OSError:
+        from ..native._loader import load
+        lib = load("libsrt_transport.so", "srt_transport.cpp",
+                   extra_flags=("-pthread",))
+        if lib is None:
             return None
         i64, u64p, u8pp = (ctypes.c_int64, ctypes.POINTER(ctypes.c_uint64),
                            ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)))

@@ -305,7 +305,7 @@ class ShuffleExchangeExec(PhysicalPlan):
         rows route over ICI to their OWNER device (target % n_dev) and
         each device's received batch splits locally into the `group`
         partitions it owns — so partition counts no longer have to match
-        the mesh exactly (VERDICT r2 weak #8)."""
+        the mesh exactly."""
         from ...parallel.mesh import (MeshShuffleUnsupported, align_batches,
                                       device_mesh, mesh_shuffle_batches)
         from ...parallel.partitioning import (HashPartitioning,

@@ -624,6 +624,7 @@ class TpuSession:
         from .physical.base import collect_metrics
         self.last_query_metrics = collect_metrics(phys)
         self._last_phys = phys
+        self._last_logical = logical
         tables = [device_to_arrow(b) for b in batches if b.num_rows_int > 0]
         arrow_schema = pa.schema([
             pa.field(a.name, T.to_arrow(a.dtype)) for a in logical.output])
@@ -751,11 +752,17 @@ class TpuSession:
         except RuntimeError as e:
             return {"last": OD.LAST_VERDICT, "note": str(e)}
 
-    def explain(self, df: DataFrame, all_ops: bool = True) -> str:
+    def explain(self, df: Optional[DataFrame] = None,
+                all_ops: bool = True) -> str:
         """Placement report (spark.rapids.sql.explain=ALL equivalent) plus
-        the physical tree."""
+        the physical tree — of ``df``, or of the most recently collected
+        query when ``df`` is None."""
         from .overrides import TpuOverrides
-        meta = TpuOverrides.apply(df._plan, self._conf)
+        logical = df._plan if df is not None else getattr(
+            self, "_last_logical", None)
+        if logical is None:
+            raise RuntimeError("no query has been collected yet")
+        meta = TpuOverrides.apply(logical, self._conf)
         from ..config import OPTIMIZER_ENABLED
         if bool(self._conf.get(OPTIMIZER_ENABLED)):
             # keep the placement report consistent with the physical plan
@@ -763,7 +770,7 @@ class TpuSession:
             apply_cost_optimizer(meta, self._conf)
         try:
             phys_str = Planner(self._conf).plan_for_collect(
-                df._plan).tree_string()
+                logical).tree_string()
         except NotImplementedError as e:
             # diagnostics must not crash on unplannable queries (e.g.
             # unsupported DISTINCT shapes) — report the reason instead

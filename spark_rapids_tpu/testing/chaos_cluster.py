@@ -126,10 +126,10 @@ def child_main() -> None:
     its map output, registers the lineage callback (any map regenerates
     from the seed), then answers "query" commands on stdin until "exit"."""
     cfg = json.loads(sys.argv[1])
-    plat = os.environ.get("SRT_CHAOS_PLATFORM", "cpu")
-    if plat == "cpu":
-        from .. import pin_host_platform
-        pin_host_platform()
+    # executors are separate processes and a chip belongs to ONE process:
+    # the fault-domain rig always runs its executors on the host platform
+    from .. import pin_host_platform
+    pin_host_platform()
     import spark_rapids_tpu as srt
     from ..observability import tracer as OT
     from ..observability.export import write_event_log

@@ -1,4 +1,4 @@
-"""TPC-DS breadth for the scale rig (VERDICT r3 missing #3 follow-up).
+"""TPC-DS breadth for the scale rig.
 
 The reference's milestone ladder ends at full TPC-DS (BASELINE configs
 3-4) and its scale suite spans join/agg/window shapes
@@ -791,7 +791,7 @@ ORDER BY y1.s_store_name, y1.d_dow
 
 # ---------------------------------------------------------------------------
 # round-5 additions: multi-CTE / set-operation / subquery planner stress
-# (VERDICT r4 #5 — the TPC-DS stragglers that exercise INTERSECT/EXCEPT,
+# (the TPC-DS stragglers that exercise INTERSECT/EXCEPT,
 # FULL OUTER JOIN, CTE self-joins, correlated subqueries, EXISTS chains
 # and ROLLUP rather than re-covering star joins)
 # ---------------------------------------------------------------------------

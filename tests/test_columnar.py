@@ -151,7 +151,7 @@ def test_object_column_concat_and_repad():
 
 
 # ---------------------------------------------------------------------------
-# ragged-string width-class splitting (VERDICT r2 weak #5)
+# ragged-string width-class splitting
 # ---------------------------------------------------------------------------
 
 class TestRaggedStringSplit:

@@ -2,7 +2,7 @@
 reads only the reduce partitions it owns, pulling the peer slice's
 contributions over the TCP (DCN) plane while its own blocks stay on the
 local (ICI-tier) store (SURVEY §2.8; reference UCX transport SPI + peer
-registry — VERDICT r2 missing #8)."""
+registry)."""
 
 import numpy as np
 import pyarrow as pa

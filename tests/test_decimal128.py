@@ -1,4 +1,4 @@
-"""Decimal128 end-to-end aggregation (VERDICT r3 #4).
+"""Decimal128 end-to-end aggregation.
 
 The reference aggregates decimal(19-38) on device via
 ``Aggregation128Utils`` chunked-int32 extraction

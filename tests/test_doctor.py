@@ -311,13 +311,15 @@ def test_bench_diff_threshold_band(tmp_path):
             for r in rows}["tpch_q1_like_rows_per_sec"] == "OK"
 
 
-def test_bench_diff_banked_artifacts_smoke():
-    """The committed round artifacts diff cleanly (the CI smoke): both
-    are stale replays, so the evidence gate PASSES without --allow-stale
-    (same class) and the join improvement r04->r05 is visible."""
+def test_bench_diff_same_class_stale_artifacts_smoke(tmp_path):
+    """Two stale replays diff cleanly (the CI smoke): same evidence
+    class, so the gate PASSES without --allow-stale, and a join metric
+    that improves between them is visible."""
     bd = _bench_diff()
-    a, b = os.path.join(REPO, "BENCH_r04.json"), \
-        os.path.join(REPO, "BENCH_r05.json")
+    a = _artifact(tmp_path, "a.json", captured_at="2026-08-01T00:00:00Z",
+                  extra_metrics={"join_rows_per_sec": 100})
+    b = _artifact(tmp_path, "b.json", captured_at="2026-08-02T00:00:00Z",
+                  extra_metrics={"join_rows_per_sec": 130})
     ra, rb = bd.load_artifact(a), bd.load_artifact(b)
     assert bd.evidence_of(ra) == bd.evidence_of(rb) == "stale-replay"
     rc, rows = bd.run(a, b, 0.10, allow_stale=False, as_json=False)

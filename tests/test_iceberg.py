@@ -327,7 +327,7 @@ def test_trivial_scan_rides_device_decode(sess, tmp_path):
 def test_partial_device_decode_after_drop_readd(sess, tmp_path):
     """Drop+re-add of a column allocates a fresh field id; the OLD file's
     stale same-named values must null-fill while its untouched columns
-    STILL ride the device decode (VERDICT r4 #8 — round 4 declined the
+    STILL ride the device decode (round 4 declined the
     whole scan).  The new file device-decodes fully."""
     t = IcebergTable.create(sess, str(tmp_path / "t"), SCHEMA)
     t.append(make_batch(0, 3000))

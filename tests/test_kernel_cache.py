@@ -1,4 +1,4 @@
-"""Compile-cache + whole-stage-fusion behavior (VERDICT round-1 items 2-3):
+"""Compile-cache + whole-stage-fusion behavior:
 repeated collect() of the same query must reuse compiled kernels instead of
 re-tracing, and fused plans must match unfused results exactly."""
 

@@ -197,7 +197,7 @@ def test_mesh_subquery_semi_join(ici_sess, rng):
 def test_mesh_rides_when_partitions_exceed_devices(session):
     """nt=16 partitions on an 8-device mesh: rows route to their owner
     device over ICI, then split locally — the exchange must still ride
-    the mesh plane (VERDICT r2 weak #8) with exact results."""
+    the mesh plane with exact results."""
     from spark_rapids_tpu.parallel import mesh as MESH
     import spark_rapids_tpu as srt
     from spark_rapids_tpu.sql import functions as F

@@ -144,7 +144,7 @@ def test_fit_linear_regression_recovers_weights(sess):
 
 
 def test_gradient_boosting_multi_batch_device_resident(sess):
-    """BASELINE config 5 depth (VERDICT r3 #10): a GBT-shaped model
+    """BASELINE config 5 depth: a GBT-shaped model
     trains on MULTI-BATCH engine output with the training data resident
     on device throughout, and actually fits a nonlinear target a linear
     model cannot."""

@@ -134,3 +134,67 @@ def test_pallas_seg_sum_single_slot_and_tiny(rng):
                                         jnp.asarray(rank), 2,
                                         interpret=True))
     assert np.allclose(got, [[5.0, 2.0]])
+
+
+# --------------------------------------------------------------------------
+# built from what git commits; the compile cache where it is told to be
+# --------------------------------------------------------------------------
+
+def test_loader_builds_both_libraries_from_source(tmp_path, monkeypatch):
+    """A fresh clone holds only the .cpp: both libraries build from it,
+    next to the source, under the content-tagged name."""
+    import os
+    import shutil
+
+    from spark_rapids_tpu.native import _loader
+    repo_native = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "native")
+    for src in ("srt_native.cpp", "srt_transport.cpp"):
+        shutil.copy(os.path.join(repo_native, src), tmp_path / src)
+    monkeypatch.setattr(_loader, "_candidate_dirs", lambda: [str(tmp_path)])
+    monkeypatch.setattr(_loader, "LOADED", {})
+    for lib, src, flags in (("libsrt_native.so", "srt_native.cpp", ()),
+                            ("libsrt_transport.so", "srt_transport.cpp",
+                             ("-pthread",))):
+        so = _loader.find_or_build(lib, src, extra_flags=flags)
+        tag = _loader._src_tag(str(tmp_path / src))
+        assert so == str(tmp_path / f"{lib[:-3]}-{tag}.so")
+        assert os.path.exists(so)
+        assert _loader.LOADED[lib] == {"path": so, "error": None}
+    assert not [f for f in os.listdir(tmp_path) if f.endswith(".tmp")]
+
+
+def test_loader_records_why_a_library_is_missing(tmp_path, monkeypatch):
+    from spark_rapids_tpu.native import _loader
+    (tmp_path / "broken.cpp").write_text("this is not C++\n")
+    monkeypatch.setattr(_loader, "_candidate_dirs", lambda: [str(tmp_path)])
+    monkeypatch.setattr(_loader, "LOADED", {})
+    assert _loader.load("libbroken.so", "broken.cpp") is None
+    assert "building" in _loader.LOADED["libbroken.so"]["error"]
+    assert _loader.load("libabsent.so", "absent.cpp") is None
+    assert "not found" in _loader.LOADED["libabsent.so"]["error"]
+
+
+@pytest.mark.parametrize("given", [None, "outside-cache"])
+def test_compile_cache_placement(tmp_path, given):
+    """JAX_COMPILATION_CACHE_DIR set: the package touches no cache setting;
+    unset: ``<checkout>/.jax_cache``, flat.  (A child process: the choice
+    is made once, at import; importing initializes no backend.)"""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR")}
+    env["PYTHONPATH"] = repo
+    if given:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / given)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import spark_rapids_tpu as s; print(s.compile_cache_dir())"],
+        env=env, capture_output=True, text=True, timeout=120, check=True)
+    want = str(tmp_path / given) if given else os.path.join(repo,
+                                                            ".jax_cache")
+    assert out.stdout.strip().splitlines()[-1] == want
+    if given:   # the package made no directory of its own there either
+        assert not os.path.exists(want)

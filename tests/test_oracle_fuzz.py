@@ -2,7 +2,7 @@
 device path vs PANDAS (a genuinely independent engine — the reference's
 tier-1 model where CPU Spark is the oracle, asserts.py:560).  The engine's
 own numpy backend shares kernels with the device path and cannot catch
-shared bugs (VERDICT r1 weak #6); pandas can.
+shared bugs; pandas can.
 
 OOM injection is armed for every query so the retry/spill machinery is
 exercised at scale (reference conftest inject_oom)."""
@@ -382,7 +382,7 @@ def test_window_functions_vs_pandas(sess, data):
 
 def test_lateral_view_explode_fuzz_vs_pandas(sess):
     """Randomized LATERAL VIEW [OUTER] explode/posexplode over generated
-    nested rows vs pandas explode (VERDICT r3 weak #4: round-3 surfaces
+    nested rows vs pandas explode (round-3 surfaces
     had example-based tests only)."""
     rng = np.random.default_rng(61)
     n = 4000

@@ -1,5 +1,5 @@
 """Out-of-core machinery driven END-TO-END through the planner by real
-scale-rig queries (VERDICT r4 #7): the spill catalog, OOM retry/split and
+scale-rig queries: the spill catalog, OOM retry/split and
 out-of-core sort paths are covered by unit suites at their seams — this
 exercises them through planned joins/aggregates/sorts with the pandas
 oracle still checking results.  Reference: inject_oom in every

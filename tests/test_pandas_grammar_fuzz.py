@@ -1,4 +1,4 @@
-"""Grammar fuzz for the Spark-ONLY SQL surface (VERDICT r4 #10): the
+"""Grammar fuzz for the Spark-ONLY SQL surface: the
 sqlite-oracle fuzz (test_sql_grammar_fuzz.py) is constrained to the
 dialect intersection — no datetime functions, no DECIMAL, no LATERAL
 VIEW.  This harness reuses its type-directed-generator idea with DUAL

@@ -1,16 +1,12 @@
-"""Shim-axis proof (VERDICT r3 #8): BOTH jax ShimProviders load and
-serve the SAME engine code end-to-end — the reference's parallel-world
-property (``ShimLoader.scala:46-76``), where one artifact works across
-its whole compatibility axis.
+"""Shim-axis proof: the jax ShimProvider loads and serves the engine code
+end-to-end — the reference's ShimLoader pattern (``ShimLoader.scala:46-76``).
 
-The installed jax still ships the legacy entry points
-(``jax.tree_util.*``, experimental/top-level ``shard_map``), so the
-legacy provider is genuinely exercisable here: these tests force each
-provider in turn (provider injection, the test-time analog of running
-under an old jaxlib) and drive real engine work through every shimmed
-entry point — batch pytrees (tree_map/flatten/unflatten ride every
-collect via columnar/convert and collect_fusion) and the mesh
-``shard_map`` data plane."""
+One provider exists (jax >= 0.6, the installed line).  Each case runs two
+ways: with the provider RESOLVED by version probing from a cold start, and
+with it INJECTED (the seam a future provider's tests will use) — and drives
+real engine work through every shimmed entry point: batch pytrees
+(tree_map/flatten/unflatten ride every collect via columnar/convert and
+collect_fusion) and the mesh ``shard_map`` data plane."""
 
 import numpy as np
 import pyarrow as pa
@@ -21,12 +17,13 @@ from spark_rapids_tpu import shims
 from spark_rapids_tpu.sql import functions as F
 
 
-@pytest.fixture(params=["JaxModernShim", "JaxLegacyShim"])
+@pytest.fixture(params=["resolved", "injected"])
 def forced_shim(request):
-    """Force one provider, restore afterwards."""
-    cls = {c.__name__: c for c in shims.PROVIDERS}[request.param]
+    """The one provider, reached by probing or by injection; restored
+    afterwards."""
+    cls = shims.JaxModernShim
     old = shims._active
-    shims._active = cls()
+    shims._active = None if request.param == "resolved" else cls()
     try:
         yield cls
     finally:
@@ -35,10 +32,7 @@ def forced_shim(request):
 
 def _shard_map_or_skip(provider):
     """The provider's shard_map entry point, or skip — the same
-    availability skip tests/test_shuffle.py uses: the installed jax may
-    not expose the FORCED provider's entry point (e.g. jax 0.4.x has no
-    top-level ``jax.shard_map`` for JaxModernShim), and tier-1 must be
-    green-or-skip on such environments."""
+    availability skip tests/test_shuffle.py uses."""
     try:
         return provider.shard_map()
     except (ImportError, AttributeError):
@@ -50,17 +44,14 @@ def test_provider_probing_matches_versions():
     assert shims.JaxModernShim.matches((0, 6, 0))
     assert shims.JaxModernShim.matches((0, 9, 0))
     assert not shims.JaxModernShim.matches((0, 5, 3))
-    assert shims.JaxLegacyShim.matches((0, 4, 30))
-    assert shims.JaxLegacyShim.matches((0, 5, 3))
-    assert not shims.JaxLegacyShim.matches((0, 6, 0))
+    assert not shims.JaxModernShim.matches((0, 4, 30))
     # the running jax resolves to exactly one provider
     v = shims._jax_version()
     assert sum(c.matches(v) for c in shims.PROVIDERS) == 1
 
 
-def test_both_providers_supply_working_apis(forced_shim):
-    """Each provider's four entry points work against the installed
-    jax (the legacy surface still exists in modern jax)."""
+def test_provider_supplies_working_apis(forced_shim):
+    """The provider's four entry points work against the installed jax."""
     s = shims.get_shim()
     assert type(s) is forced_shim
     tree = {"a": np.arange(3), "b": (np.ones(2),)}
@@ -73,11 +64,10 @@ def test_both_providers_supply_working_apis(forced_shim):
     assert callable(_shard_map_or_skip(s))
 
 
-def test_engine_query_end_to_end_under_each_provider(forced_shim):
+def test_engine_query_end_to_end_through_the_provider(forced_shim):
     """A real query (filter + join + agg + sort -> collect) runs through
     the forced provider: batch pytrees traverse tree_flatten/unflatten
-    in the packed D2H fetch, tree_map in transitions — the quick-tier
-    slice of the engine on BOTH shim worlds."""
+    in the packed D2H fetch, tree_map in transitions."""
     sess = srt.session()
     rng = np.random.default_rng(1)
     fact = pa.table({"k": rng.integers(0, 50, 20_000),
@@ -99,7 +89,7 @@ def test_engine_query_end_to_end_under_each_provider(forced_shim):
     assert np.allclose(got["sv"], exp["sv"])
 
 
-def test_mesh_shard_map_under_each_provider(forced_shim):
+def test_mesh_shard_map_through_the_provider(forced_shim):
     """The ICI mesh data plane compiles and runs through the forced
     provider's shard_map on the 8-device virtual mesh."""
     import jax

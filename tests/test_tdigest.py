@@ -1,7 +1,7 @@
 """t-digest approx_percentile — kernel accuracy, strategy selection, and
 the digest-per-batch merge path that keeps percentile memory bounded at
 O(groups x delta/2) regardless of group size (reference
-``GpuApproximatePercentile.scala:1-222``; VERDICT r2 #7)."""
+``GpuApproximatePercentile.scala:1-222``)."""
 
 import os
 import tempfile

@@ -312,20 +312,31 @@ def test_tcp_traced_fetch_falls_back_on_old_peer():
 def test_serializer_frame_trace_extension_and_compat():
     from spark_rapids_tpu.columnar.convert import (arrow_to_device,
                                                    device_to_arrow)
-    from spark_rapids_tpu.shuffle.serializer import (deserialize_batch,
+    from spark_rapids_tpu.shuffle.serializer import (_deserialize_batch,
+                                                     deserialize_batch,
                                                      serialize_batch)
+
+    def trace_extension(frame):
+        """The frame's decoded schema-header trace extension (the frame
+        body may be zstd-compressed: raw bytes prove nothing)."""
+        found = []
+        _deserialize_batch(frame, trace_out=found)
+        return found
+
     t = pa.table({"x": np.arange(64, dtype=np.int64),
                   "y": np.random.default_rng(0).random(64)})
     batch = arrow_to_device(t)
     tracer = OT.get_tracer()
     assert not OT.TRACING["on"]
     frame_off = serialize_batch(batch)
-    assert b'"trace"' not in frame_off  # off: wire bytes unchanged
+    assert not trace_extension(frame_off)  # off: no extension on the wire
     OT.TRACING["on"] = True
     tracer.reset(session="ser-test")
     try:
         frame_on = serialize_batch(batch)
-        assert b'"trace"' in frame_on  # on: versioned schema extension
+        # on: versioned schema extension
+        (ext,) = trace_extension(frame_on)
+        assert ext["trace"] and ext["span"]
         # new reader surfaces the producer's context on its span
         out = deserialize_batch(frame_on)
         assert device_to_arrow(out).equals(t)

@@ -1,0 +1,236 @@
+"""Chip-compiler compiles, without the chip (on-chip-measurement guide §2,
+rehearsal 3): the programs of the main path are lowered and compiled for a
+DESCRIBED ``v5e:2x2`` topology at real widths, so what the TPU compiler
+refuses — and the CPU interpreter accepts — fails here, at no chip time.
+
+Nothing runs: a compile that passes is not a chip run (``chip_smoke.py`` is).
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load the TPU library, and every xdist worker imports
+every test file), and all of these tests live in this ONE file so one
+worker holds the library.  The persistent compile cache is off around
+them: an AOT TPU entry cannot be read back without a chip.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import spark_rapids_tpu  # noqa: F401  (x64 on: the condition that broke Mosaic)
+from spark_rapids_tpu.ops import pallas_kernels as PK
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    return compiled, compiled.as_text()
+
+
+# --------------------------------------------------------------------------
+# the two Pallas kernels at real widths
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n", [1 << 20, 1 << 23])
+def test_murmur3_kernel_compiles_for_v5e(one_chip, n):
+    _, text = _compile(
+        lambda v: PK.murmur3_long_pallas(v, np.uint32(42)),
+        jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("s,out,n", [(1, 8, 1 << 23), (8, 64, 1 << 20),
+                                     (3, 256, 1 << 21)])
+def test_seg_sum_kernel_compiles_for_v5e(one_chip, s, out, n):
+    """(slots, groups, rows) spanning the one-hot-matmul envelope the
+    aggregate uses it in (groups <= _MATMUL_MAX_GROUPS)."""
+    _, text = _compile(
+        lambda v, r: PK.seg_sum_f32_pallas(v, r, out),
+        jax.ShapeDtypeStruct((s, n), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip))
+    assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------------
+# the gate: loud on a TPU, silent nowhere
+# --------------------------------------------------------------------------
+
+@pytest.fixture()
+def as_if_on_tpu(monkeypatch):
+    """Steer the gate's TPU branch on from the test; kernels run in
+    interpret mode so the probe can execute on the CPU."""
+    monkeypatch.setattr(PK, "on_tpu", lambda: True)
+    monkeypatch.setattr(PK, "_PROBE_OK", {})
+    real_m, real_s = PK.murmur3_long_pallas, PK.seg_sum_f32_pallas
+    monkeypatch.setattr(PK, "murmur3_long_pallas",
+                        functools.partial(real_m, interpret=True))
+    monkeypatch.setattr(PK, "seg_sum_f32_pallas",
+                        functools.partial(real_s, interpret=True))
+    return real_m, real_s
+
+
+def test_probe_is_safe_inside_a_trace(as_if_on_tpu):
+    """Both call sites sit inside jitted stages: the probe must compile,
+    run and verify for real while an outer trace is being staged."""
+    @jax.jit
+    def stage(x):
+        assert PK.murmur3_available() and PK.seg_sum_available()
+        return x + 1
+
+    assert int(stage(jnp.arange(3))[2]) == 3
+    assert PK._PROBE_OK == {"murmur3": True, "seg_sum": True}
+
+
+def test_probe_raises_on_tpu_when_kernel_does_not_compile(
+        as_if_on_tpu, monkeypatch):
+    real_m, _ = as_if_on_tpu
+    # non-interpret Pallas cannot compile on the CPU backend: the stand-in
+    # for a kernel Mosaic refuses
+    monkeypatch.setattr(PK, "murmur3_long_pallas", real_m)
+    with pytest.raises(RuntimeError, match="murmur3.*failed its probe"):
+        jax.jit(lambda x: (PK.murmur3_available(), x)[1])(jnp.arange(3))
+    assert "murmur3" not in PK._PROBE_OK
+
+
+def test_probe_raises_on_tpu_when_kernel_answers_wrongly(
+        as_if_on_tpu, monkeypatch):
+    monkeypatch.setattr(
+        PK, "seg_sum_f32_pallas",
+        lambda v, r, out: jnp.zeros((v.shape[0], out), jnp.float32))
+    with pytest.raises(RuntimeError, match="seg_sum.*wrong answer"):
+        PK.seg_sum_available()
+
+
+def test_probe_answers_false_off_tpu_without_trying(monkeypatch):
+    def boom(*a, **k):
+        raise AssertionError("the kernel was tried off the TPU")
+    monkeypatch.setattr(PK, "murmur3_long_pallas", boom)
+    monkeypatch.setattr(PK, "_PROBE_OK", {})
+    assert PK.murmur3_available() is False
+
+
+# --------------------------------------------------------------------------
+# the four-device mesh exchange program
+# --------------------------------------------------------------------------
+
+def test_mesh_exchange_program_compiles_for_four_chips(topo):
+    """The very step ``mesh_shuffle_batches`` jits (all_to_all exchange +
+    on-chip compaction) on a Mesh of the described devices, with an
+    (int64, float64, bool) batch.  2^12 rows per shard keeps the routing
+    argsort under the size where the TPU compiler's sort gets slow (the
+    same program at 2^18 rows per shard compiles too, in ~2 minutes:
+    PERF.md)."""
+    from jax.sharding import Mesh
+    from spark_rapids_tpu.parallel.mesh import exchange_program
+    n_dev, cap = 4, 1 << 12
+    mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
+    sh = NamedSharding(mesh, P("data"))
+
+    def g(shape, dt):
+        return jax.ShapeDtypeStruct((n_dev * cap,) + shape, dt, sharding=sh)
+
+    compiled, text = _compile(
+        exchange_program(mesh, n_dev, cap, 3),
+        g((), jnp.bool_), g((), jnp.int32),
+        g((1,), jnp.int64), g((1,), jnp.float64), g((1,), jnp.bool_))
+    assert "all-to-all" in text
+    # per-device footprint must fit one v5e chip with room to spare
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes + ma.output_size_in_bytes < 8 << 30
+
+
+# --------------------------------------------------------------------------
+# the q1 aggregate program, non-CPU branches steered on
+# --------------------------------------------------------------------------
+
+def test_q1_aggregate_programs_compile_for_v5e(one_chip, monkeypatch):
+    """TPC-H q1 through the session on the CPU at a small size, recording
+    every aggregate program the kernel cache builds; each is then lowered
+    again for the v5e with ``jax.default_backend()`` answering "tpu" — so
+    the batched reduce, the one-hot-matmul aggregate and the Pallas
+    ``seg_sum`` call (branches no CPU test executes) are what compiles."""
+    import spark_rapids_tpu as srt
+    from spark_rapids_tpu.sql.physical import kernel_cache as KC
+    from spark_rapids_tpu.testing import scaletest as ST
+
+    recorded = []
+    real_cached_jit = KC.cached_jit
+
+    def recording_cached_jit(key, fn, donate_argnums=None):
+        inner = real_cached_jit(key, fn, donate_argnums=donate_argnums)
+        if key[0] != "HashAggregateExec":
+            return inner
+
+        def call(*args):
+            recorded.append((fn, args))
+            return inner(*args)
+        return call
+
+    KC.clear_cache()
+    monkeypatch.setattr(KC, "cached_jit", recording_cached_jit)
+    lineitem = ST.build_tpch_tables(20_000)["lineitem"]
+    sess = srt.session()
+    sess.create_dataframe(lineitem, num_partitions=1) \
+        .createOrReplaceTempView("lineitem")
+    got = sess.sql(ST._TPCH_Q1_SQL).collect().to_pandas()
+    ST._q1_oracle_check(got, lineitem)
+    monkeypatch.undo()
+    KC.clear_cache()
+    assert recorded, "q1 built no aggregate program"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(PK, "_PROBE_OK", {"murmur3": True, "seg_sum": True})
+
+    def abstract(x):
+        if isinstance(x, (jax.Array, np.ndarray)):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        return x
+
+    seen, pallas_calls = set(), 0
+    for fn, args in recorded:
+        shapes = jax.tree_util.tree_map(abstract, args)
+        sig = (id(fn), str(jax.tree_util.tree_structure(shapes)),
+               tuple((s.shape, str(s.dtype))
+                     for s in jax.tree_util.tree_leaves(shapes)))
+        if sig in seen:
+            continue
+        seen.add(sig)
+        # a fresh wrapper: jit caches traces by function identity, and
+        # the CPU run already traced ``fn`` down its CPU branches
+        _, text = _compile(lambda *a, fn=fn: fn(*a), *shapes)
+        pallas_calls += text.count("tpu_custom_call")
+        assert " sort(" not in text, \
+            "q1's dictionary keys are statically compact: no sort fallback"
+    assert pallas_calls > 0, "no aggregate program called the Pallas seg_sum"

@@ -381,13 +381,13 @@ def test_grouped_agg_udf_global_and_aliased_key(sess):
 
 
 # ---------------------------------------------------------------------------
-# out-of-process worker pool (python/rapids/daemon.py analog, VERDICT r3 #9)
+# out-of-process worker pool (python/rapids/daemon.py analog)
 # ---------------------------------------------------------------------------
 
 def test_udf_worker_crash_fails_task_not_session(sess):
     """A UDF that kills its interpreter takes down its WORKER process;
     the task fails with WorkerCrashed, and the session keeps serving
-    queries afterwards (the done-criteria of VERDICT r3 #9)."""
+    queries afterwards."""
     import pytest as _pytest
     from spark_rapids_tpu.pyworker import STATS, WorkerCrashed
     t = pa.table({"x": [1.0, 2.0, 3.0]})

@@ -153,8 +153,6 @@ def main() -> int:
     cmd = sys.argv[1] if len(sys.argv) > 1 else "check"
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     if cmd == "generate":
         c = generate()
         print(f"wrote {CONTRACT}: {len(c['execs'])} execs, "

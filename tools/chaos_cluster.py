@@ -66,10 +66,9 @@ def main(argv) -> int:
     # the child executors import the package by name too
     os.environ["PYTHONPATH"] = repo + os.pathsep + os.environ.get(
         "PYTHONPATH", "")
-    plat = os.environ.get("SRT_CHAOS_PLATFORM", "cpu")
-    if plat == "cpu":
-        from spark_rapids_tpu import pin_host_platform
-        pin_host_platform()
+    # N executor processes cannot share one chip: host platform, always
+    from spark_rapids_tpu import pin_host_platform
+    pin_host_platform()
     from spark_rapids_tpu.testing.chaos_cluster import SCENARIOS, run_suite
 
     out = args.out or tempfile.mkdtemp(prefix="srt-chaos-cluster-")

@@ -89,7 +89,7 @@ def check(path: str, min_events: int = 1, require_cat: str = "",
     return spans, sorted(c for c in cats if c)
 
 
-#: the doctor's verdict taxonomy (observability/doctor.py VERDICTS)
+#: the doctor's verdict classes (observability/doctor.py VERDICTS)
 DOCTOR_VERDICTS = ("sync-bound", "compile-bound", "h2d-d2h-bound",
                    "dispatch-bound", "sem_wait-bound", "spill-bound",
                    "shuffle-bound", "admission-bound", "slo-burn",

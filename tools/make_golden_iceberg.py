@@ -6,7 +6,7 @@ list / manifests are written in the REAL nested layout
 (``manifest_entry{status, snapshot_id, data_file: r2{...}}`` /
 ``manifest_file{manifest_path, ...}``) by a from-scratch minimal avro
 container encoder below — i.e. the shapes a pyiceberg/Spark writer
-produces.  Fixtures land in tests/golden/iceberg/ (VERDICT r2 #5).
+produces.  Fixtures land in tests/golden/iceberg/.
 
 Run from the repo root:  python tools/make_golden_iceberg.py
 """

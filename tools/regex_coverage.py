@@ -2,8 +2,8 @@
 
 Extracts candidate patterns from the reference's regex suites
 (`tests/.../RegularExpressionTranspilerSuite.scala` + Parser/Regression
-suites — the same corpus the reference validates its own transpiler on,
-VERDICT r2 #8), keeps the ones that are valid Java-style regexes (proxy:
+suites — the same corpus the reference validates its own transpiler on),
+keeps the ones that are valid Java-style regexes (proxy:
 Python `re` compiles them), and reports what fraction this engine's DFA
 accepts on-device, by mode:
 
