@@ -9,7 +9,6 @@ is a single ``jnp.asarray`` per buffer.
 
 from __future__ import annotations
 
-import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -272,22 +271,18 @@ def bulk_device_get(tree):
         if len(_PACK_CACHE) > 512:
             _PACK_CACHE.clear()
             _PACK_CACHE[cache_key] = pack
-    tracing = _trace.TRACING["on"]
-    t0 = time.perf_counter() if tracing else 0.0
     try:
-        bufs = pack(*devs)
-        for b in bufs:  # overlap the (few) transfers: one latency, not N
-            b.copy_to_host_async()
-        host = [np.asarray(b) for b in bufs]
+        with _trace.span("d2h", "bulk_device_get", leaves=len(devs)) as sp:
+            bufs = pack(*devs)
+            for b in bufs:  # overlap the (few) transfers: one latency
+                b.copy_to_host_async()
+            host = [np.asarray(b) for b in bufs]
+            sp.set_metadata(bytes=sum(b.nbytes for b in host))
     except Exception:
         # e.g. an exotic dtype the pack program can't lower on this
         # toolchain — correctness first, one pull per leaf as before
         with _trace.span("d2h", "device_get.fallback", leaves=len(devs)):
             return jax.device_get(tree)
-    if tracing:
-        _trace.get_tracer().complete(
-            "d2h", "bulk_device_get", t0, time.perf_counter() - t0,
-            bytes=sum(b.nbytes for b in host), leaves=len(devs))
     for i, leaf in zip(dev_idx, unpack_buffers(host, sig)):
         leaves[i] = leaf
     from ..shims import tree_unflatten

@@ -249,6 +249,59 @@ def enabled() -> bool:
     return jax.default_backend() != "cpu"
 
 
+def _narrowed_fetch(devs, sig, naive: int, sp):
+    """The probe and, unless it keeps every leaf (None), the narrowed
+    fetch, widened back on the host.  ``sp`` is the caller's d2h span:
+    the bytes that crossed are known only here."""
+    with _LOCK:
+        probe = _PROBE_CACHE.get(sig)
+        if probe is None:
+            probe = _PROBE_CACHE[sig] = _probe_program(sig)
+            if len(_PROBE_CACHE) > 256:
+                _PROBE_CACHE.clear()
+                _PROBE_CACHE[sig] = probe
+    mins_d, maxs_d, flags_d = probe(*devs)
+    for b in (mins_d, maxs_d, flags_d):
+        b.copy_to_host_async()
+    mins, maxs, flags = (np.asarray(mins_d), np.asarray(maxs_d),
+                         np.asarray(flags_d))
+    probe_nbytes = mins.nbytes + maxs.nbytes + flags.nbytes
+    with _LOCK:  # shuffle writer/reader pools fetch concurrently
+        STATS["probe_bytes"] += probe_nbytes
+        STATS["bytes_on_wire"] += probe_nbytes  # probe crossed too
+    codes = _choose_codes(sig, mins, maxs, flags)
+    if all(c == "keep" for c in codes):
+        sp.set_metadata(bytes=probe_nbytes)
+        return None
+    # keep-f64 leaves ride pack_leaves_traced, whose word layout
+    # depends on the f64 encoding mode (backend + packFloat64 conf) —
+    # part of the key, like bulk_device_get's cache (convert.py)
+    from .convert import _f64_as_pair, _pack_f64_enabled
+    key = (sig, codes, _f64_as_pair(), _pack_f64_enabled())
+    with _LOCK:
+        entry = _PACK_CACHE.get(key)
+        if entry is None:
+            entry = _PACK_CACHE[key] = _pack_program(sig, codes)
+            if len(_PACK_CACHE) > 256:
+                _PACK_CACHE.clear()
+                _PACK_CACHE[key] = entry
+    pack, nsig = entry
+    bufs = pack(*devs)
+    for b in bufs:
+        b.copy_to_host_async()
+    host = [np.asarray(b) for b in bufs]
+    from .convert import unpack_buffers
+    narrowed_host = unpack_buffers(host, nsig)
+    widened = _widen(narrowed_host, sig, codes)
+    wire = sum(b.nbytes for b in host)
+    with _LOCK:
+        STATS["prepacked_fetches"] += 1
+        STATS["bytes_on_wire"] += wire
+        STATS["bytes_naive"] += naive
+    sp.set_metadata(bytes=wire + probe_nbytes)
+    return widened
+
+
 def prepacked_device_get(tree):
     """Drop-in for ``bulk_device_get`` with device-side narrowing.
 
@@ -282,64 +335,16 @@ def prepacked_device_get(tree):
     if narrowable < _min_bytes():
         return bulk_device_get(tree)
     from ..observability import tracer as _trace
-    tracing = _trace.TRACING["on"]
-    import time as _time
-    t0 = _time.perf_counter() if tracing else 0.0
+    # probe + narrowed fetch: both crossings in one d2h span
     try:
-        with _LOCK:
-            probe = _PROBE_CACHE.get(sig)
-            if probe is None:
-                probe = _PROBE_CACHE[sig] = _probe_program(sig)
-                if len(_PROBE_CACHE) > 256:
-                    _PROBE_CACHE.clear()
-                    _PROBE_CACHE[sig] = probe
-        mins_d, maxs_d, flags_d = probe(*devs)
-        for b in (mins_d, maxs_d, flags_d):
-            b.copy_to_host_async()
-        mins, maxs, flags = (np.asarray(mins_d), np.asarray(maxs_d),
-                             np.asarray(flags_d))
-        probe_nbytes = mins.nbytes + maxs.nbytes + flags.nbytes
-        with _LOCK:  # shuffle writer/reader pools fetch concurrently
-            STATS["probe_bytes"] += probe_nbytes
-            STATS["bytes_on_wire"] += probe_nbytes  # probe crossed too
-        codes = _choose_codes(sig, mins, maxs, flags)
-        if all(c == "keep" for c in codes):
-            return bulk_device_get(tree)
-        # keep-f64 leaves ride pack_leaves_traced, whose word layout
-        # depends on the f64 encoding mode (backend + packFloat64 conf) —
-        # part of the key, like bulk_device_get's cache (convert.py)
-        from .convert import _f64_as_pair, _pack_f64_enabled
-        key = (sig, codes, _f64_as_pair(), _pack_f64_enabled())
-        with _LOCK:
-            entry = _PACK_CACHE.get(key)
-            if entry is None:
-                entry = _PACK_CACHE[key] = _pack_program(sig, codes)
-                if len(_PACK_CACHE) > 256:
-                    _PACK_CACHE.clear()
-                    _PACK_CACHE[key] = entry
-        pack, nsig = entry
-        bufs = pack(*devs)
-        for b in bufs:
-            b.copy_to_host_async()
-        host = [np.asarray(b) for b in bufs]
-        from .convert import unpack_buffers
-        narrowed_host = unpack_buffers(host, nsig)
-        widened = _widen(narrowed_host, sig, codes)
-        wire = sum(b.nbytes for b in host)
-        with _LOCK:
-            STATS["prepacked_fetches"] += 1
-            STATS["bytes_on_wire"] += wire
-            STATS["bytes_naive"] += naive
-        if tracing:
-            # probe + narrowed fetch: both crossings in one d2h span (the
-            # fallback paths above land in bulk_device_get's own span)
-            _trace.get_tracer().complete(
-                "d2h", "prepacked_device_get", t0,
-                _time.perf_counter() - t0, bytes=wire + probe_nbytes,
-                bytes_naive=naive, leaves=len(devs))
+        with _trace.span("d2h", "prepacked_device_get", bytes_naive=naive,
+                         leaves=len(devs)) as sp:
+            widened = _narrowed_fetch(devs, sig, naive, sp)
     except Exception:  # pragma: no cover - toolchain-specific lowerings
         with _LOCK:
             STATS["fallbacks"] += 1
+        return bulk_device_get(tree)
+    if widened is None:     # the probe kept every leaf: plain path
         return bulk_device_get(tree)
     for i, leaf in zip(dev_idx, widened):
         leaves[i] = leaf

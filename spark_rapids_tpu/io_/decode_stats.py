@@ -56,10 +56,12 @@ def record_engaged(fmt: str, nbytes: int = 0) -> None:
 
 
 def record_declined(fmt: str, nbytes: int = 0,
-                    reason: Optional[str] = None) -> None:
-    if fmt not in DECODE_STATS:
-        return
+                    reason: Optional[str] = None) -> str:
+    """Counts the decline and returns the reason it was counted under
+    (the scan's ``host_decode`` span carries it)."""
     reason = reason or _take_reason("decoder-declined")
+    if fmt not in DECODE_STATS:
+        return reason
     with _LOCK:
         s = DECODE_STATS[fmt]
         s["files_declined"] += 1

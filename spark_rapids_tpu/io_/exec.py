@@ -24,6 +24,7 @@ from ..config import (CSV_DEVICE_DECODE, JSON_DEVICE_DECODE,
                       PARQUET_PUSHDOWN_ENABLED, PARQUET_READER_TYPE,
                       READER_CHUNKED, READER_CHUNKED_TARGET_ROWS,
                       RapidsConf)
+from ..observability import tracer as _trace
 from ..sql.physical.base import CPU, TPU, PhysicalPlan, TaskContext
 from . import registry
 from .filecache import resolve_read_path
@@ -69,8 +70,10 @@ class FileScanExec(PhysicalPlan):
                     (pf.metadata.num_row_groups, len(keep)), tctx)
                 if not keep:
                     return pf.schema_arrow.empty_table()
-                return pf.read_row_groups(keep)
-        return registry.read_file(self.node.fmt, path, self.node.options)
+                return self._host_decode(pf, keep)
+        with _trace.span("scan", "host_decode", fmt=self.node.fmt):
+            return registry.read_file(self.node.fmt, path,
+                                      self.node.options)
 
     def _read_chunked_orc(self, path, tctx: TaskContext):
         """ORC chunked reads: one pa.Table per stripe run up to the
@@ -107,7 +110,19 @@ class FileScanExec(PhysicalPlan):
             yield pf.schema_arrow.empty_table()
             return
         for run in runs:
-            yield pf.read_row_groups(run)
+            yield self._host_decode(pf, run)
+
+    @staticmethod
+    def _host_decode(pf, run, declined: str = ""):
+        """pyarrow's read of one row-group run, as its own span: the
+        caller hands the table to ``upload`` afterwards, so decode and
+        H2D are told apart (``declined``: why the device decoder gave the
+        run up, '' when it was never asked)."""
+        with _trace.span("scan", "host_decode", row_groups=len(run),
+                         bytes=sum(pf.metadata.row_group(rg).total_byte_size
+                                   for rg in run),
+                         declined=declined):
+            return pf.read_row_groups(run)
 
     def _parquet_runs(self, path: str):
         """The ONE implementation of prune-then-split for parquet reads
@@ -117,33 +132,34 @@ class FileScanExec(PhysicalPlan):
         are off).  Returns ``(pf, runs, prune_stats)`` with prune_stats
         either None or ``(total_groups, kept_groups)`` — the caller that
         commits to a path emits the metrics exactly once."""
-        import pyarrow.parquet as pq
-        pf = pq.ParquetFile(path)
-        keep = None
-        stats = None
-        if self.pushed_filters and bool(
-                self.conf.get(PARQUET_PUSHDOWN_ENABLED)):
-            from .pushdown import prune_row_groups
-            keep = prune_row_groups(pf, self.pushed_filters)
-            if keep is not None:
-                stats = (pf.metadata.num_row_groups, len(keep))
-        groups = list(range(pf.metadata.num_row_groups)) \
-            if keep is None else keep
-        if not bool(self.conf.get(READER_CHUNKED)):
-            return pf, ([groups] if groups else []), stats
-        target = int(self.conf.get(READER_CHUNKED_TARGET_ROWS))
-        runs: List[List[int]] = []
-        run: List[int] = []
-        rows = 0
-        for rg in groups:
-            run.append(rg)
-            rows += pf.metadata.row_group(rg).num_rows
-            if rows >= target:
+        with _trace.span("scan", "footer"):
+            import pyarrow.parquet as pq
+            pf = pq.ParquetFile(path)
+            keep = None
+            stats = None
+            if self.pushed_filters and bool(
+                    self.conf.get(PARQUET_PUSHDOWN_ENABLED)):
+                from .pushdown import prune_row_groups
+                keep = prune_row_groups(pf, self.pushed_filters)
+                if keep is not None:
+                    stats = (pf.metadata.num_row_groups, len(keep))
+            groups = list(range(pf.metadata.num_row_groups)) \
+                if keep is None else keep
+            if not bool(self.conf.get(READER_CHUNKED)):
+                return pf, ([groups] if groups else []), stats
+            target = int(self.conf.get(READER_CHUNKED_TARGET_ROWS))
+            runs: List[List[int]] = []
+            run: List[int] = []
+            rows = 0
+            for rg in groups:
+                run.append(rg)
+                rows += pf.metadata.row_group(rg).num_rows
+                if rows >= target:
+                    runs.append(run)
+                    run, rows = [], 0
+            if run:
                 runs.append(run)
-                run, rows = [], 0
-        if run:
-            runs.append(run)
-        return pf, runs, stats
+            return pf, runs, stats
 
     @staticmethod
     def _emit_prune_stats(stats, tctx: Optional[TaskContext]) -> None:
@@ -177,14 +193,18 @@ class FileScanExec(PhysicalPlan):
                 tctx.inc_metric("chunkedReadBatches")
             run_bytes = sum(pf.metadata.row_group(rg).total_byte_size
                             for rg in run)
-            batch = None if declined else decode_file(
-                path, run, tctx, pf=pf, conf=self.conf)
+            batch = None
+            if not declined:
+                with _trace.span("scan", "device_decode", bytes=run_bytes,
+                                 row_groups=len(run)):
+                    batch = decode_file(path, run, tctx, pf=pf,
+                                        conf=self.conf)
             if batch is None:
-                DS.record_declined(
+                reason = DS.record_declined(
                     "parquet", run_bytes,
                     reason="prior-decline" if declined else None)
                 declined = True
-                yield from upload(pf.read_row_groups(run))
+                yield from upload(self._host_decode(pf, run, reason))
             else:
                 DS.record_engaged("parquet", run_bytes)
                 yield batch if self.backend != CPU \
@@ -235,20 +255,27 @@ class FileScanExec(PhysicalPlan):
             if len(runs) > 1:
                 tctx.inc_metric("chunkedReadBatches")
             run_bytes = fsize * len(run) // max(f.nstripes, 1)
-            batch = None if declined else decode_file(
-                path, run if len(runs) > 1 else None, tctx,
-                orc_file=f, conf=self.conf)
+            batch = None
+            if not declined:
+                with _trace.span("scan", "device_decode", bytes=run_bytes,
+                                 stripes=len(run)):
+                    batch = decode_file(
+                        path, run if len(runs) > 1 else None, tctx,
+                        orc_file=f, conf=self.conf)
             if batch is None:
-                DS.record_declined(
+                reason = DS.record_declined(
                     "orc", run_bytes,
                     reason="prior-decline" if declined else None)
                 declined = True
-                if len(runs) > 1:
-                    parts = [pa.Table.from_batches([f.read_stripe(s)])
-                             for s in run]
-                    yield from upload(pa.concat_tables(parts))
-                else:
-                    yield from upload(f.read())
+                with _trace.span("scan", "host_decode", bytes=run_bytes,
+                                 stripes=len(run), declined=reason):
+                    if len(runs) > 1:
+                        table = pa.concat_tables(
+                            [pa.Table.from_batches([f.read_stripe(s)])
+                             for s in run])
+                    else:
+                        table = f.read()
+                yield from upload(table)
             else:
                 DS.record_engaged("orc", run_bytes)
                 if self.backend == CPU:
@@ -275,10 +302,13 @@ class FileScanExec(PhysicalPlan):
             from . import decode_stats as DS
             nb = sum(pf.metadata.row_group(rg).total_byte_size
                      for rg in groups)
-            batch = decode_file(path, groups, tctx, pf=pf, conf=self.conf)
+            with _trace.span("scan", "device_decode", bytes=nb,
+                             row_groups=len(groups)):
+                batch = decode_file(path, groups, tctx, pf=pf,
+                                    conf=self.conf)
             if batch is None:
-                DS.record_declined("parquet", nb)
-                pieces = upload(pf.read_row_groups(groups))
+                reason = DS.record_declined("parquet", nb)
+                pieces = upload(self._host_decode(pf, groups, reason))
                 if len(pieces) == 1:
                     batches.append(pieces[0])
                 else:

@@ -8,9 +8,11 @@ trace+compile, or H2D/D2H bytes is not a diagnosis.  This package is the
 TPU analog of the reference's SQL-UI GpuMetric plumbing + NVTX ranges +
 Spark eventLog, recast as one in-process timeline:
 
-* :mod:`.tracer` — thread-safe bounded ring buffer of span/counter
-  events (categories ``op``/``kernel_compile``/``sync``/``h2d``/``d2h``/
-  ``spill``/``shuffle``/``sem_wait``), near-zero overhead when disabled.
+* :mod:`.tracer` — one span primitive with two sinks: a thread-safe
+  bounded ring buffer of span/counter events (categories ``op``/
+  ``compile``/``sync``/``h2d``/``d2h``/``spill``/``shuffle``/
+  ``sem_wait``/...) and ``jax.profiler`` annotations on the profiler's
+  clock; near-zero overhead when disabled.
 * :mod:`.export` — Chrome trace-event JSON (Perfetto-loadable) and an
   append-only JSONL event log per query (eventLog/history analog).
 * :mod:`.report` — per-query attribution: blocking-readback count & ms

@@ -6,7 +6,7 @@ The verdict classes (docs/observability.md):
 ==================  ======================================================
 ``sync-bound``      blocking scalar readbacks (cat ``sync``) dominate —
                     each is a full host<->device round trip
-``compile-bound``   kernel trace+compile (cat ``kernel_compile``) — cold
+``compile-bound``   kernel trace+compile (cat ``compile``) — cold
                     cache; warm reruns are the fix, not kernel work
 ``h2d-d2h-bound``   transfer spans (cats ``h2d``+``d2h``) — bytes crossing
                     the host link; prepack/resident tiers are the levers
@@ -55,7 +55,7 @@ LAST_VERDICT: "Optional[Dict[str, Any]]" = None
 #: tracer category -> verdict category
 _CAT_TO_VERDICT = {
     "sync": "sync-bound",
-    "kernel_compile": "compile-bound",
+    "compile": "compile-bound",
     "h2d": "h2d-d2h-bound",
     "d2h": "h2d-d2h-bound",
     "sem_wait": "sem_wait-bound",
@@ -196,7 +196,8 @@ def _dispatch_evidence(dispatches: int,
     launches end to end); ``top_kernels`` ranks the per-key launch
     counters (``dispatches{kernel}``) so the verdict names the
     originating exec instead of just a total — kernel labels are
-    ``ExecName#hash``, so the heaviest key IS the exec to fuse."""
+    ``srt_<ExecName>_<what>_<digest>`` (kernel_cache.program_name), so
+    the heaviest key IS the exec to fuse."""
     ev: Dict[str, Any] = {"device_dispatches": dispatches}
     probes = int(metrics.get("joinFastpathProbes", 0)
                  + metrics.get("joinFallbackProbes", 0))
@@ -217,7 +218,8 @@ def _dispatch_evidence(dispatches: int,
         top = top[:DISPATCH_TOP_K]
         ev["top_kernels"] = [
             {"kernel": k, "launches": int(n)} for k, n in top]
-        ev["top_exec"] = top[0][0].split("#", 1)[0]
+        from ..sql.physical.kernel_cache import exec_of_program
+        ev["top_exec"] = exec_of_program(top[0][0])
     return ev
 
 

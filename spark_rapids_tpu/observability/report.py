@@ -39,7 +39,7 @@ def aggregate_by_exec(events: List[Dict[str, Any]]
             row["sync_n"] += 1
             if cat == "d2h":
                 row["d2h_bytes"] += int(args.get("bytes", 0))
-        elif cat == "kernel_compile":
+        elif cat == "compile":
             row["compile_ms"] += ms
             row["compile_n"] += 1
         elif cat == "h2d":

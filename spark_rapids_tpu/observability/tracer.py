@@ -1,24 +1,53 @@
-"""Structured query-timeline tracer.
+"""Structured query-timeline tracer: one span primitive, two sinks.
 
-One process-wide :class:`QueryTracer` holds a thread-safe bounded ring
-buffer of events.  Instrumented chokepoints (columnar/convert.py,
-sql/physical/transitions.py, kernel_cache.py, memory/spill.py,
-memory/semaphore.py, shuffle/serializer.py, the join sizing readbacks)
-guard every emission on the module-level ``TRACING`` flag — the same
-single-dict pattern as ``PROFILING`` in sql/physical/base.py — so a
-disabled tracer costs one dict lookup per chokepoint, nothing else.
+:func:`span` is the only way the program marks host work.  It feeds
+
+* the **profiler** sink (``TRACING["profiler"]``, armed per query from
+  ``spark.rapids.tpu.trace.enabled``): the span is a
+  ``jax.profiler.TraceAnnotation`` named ``srt:<cat>:<name>`` carrying the
+  span's args and ``query=<id>``.  It lies on the profiler's clock, on the
+  thread that did the work, beside the device plane of a
+  ``jax.profiler.trace``; nothing is written to the ring and no lock is
+  taken.  While no profiler session is open an annotation costs well
+  under a microsecond.  A span never syncs the device.
+* the **ring** sink (``TRACING["on"]``, armed per query from
+  ``spark.rapids.tpu.trace.sink`` / ``profile.enabled``): one process-wide
+  :class:`QueryTracer` holds a thread-safe bounded ring buffer of events
+  (Chrome export, JSONL event log, doctor, ``/metrics``).
+
+Both off, :func:`span` returns the shared null object: the cost is the
+flag lookups, nothing else.  :meth:`QueryTracer.complete` (a retroactive
+span from a measured ``t0``/duration) can only feed the ring; it remains
+for the sites off the query's own path (semaphore and queue waits, shuffle
+transport and serialization, serving admission, lifecycle, faults), which
+a profiler trace therefore does not show.
 
 Event categories:
 
 =================  =========================================================
+``query``          one collect, root of everything below (``query``,
+                   ``session`` args; sql/session.py)
+``plan``           ``parse`` (SQL text -> logical) and ``physical`` (every
+                   ``plan_for_collect``, re-plans included)
+``task``           one partition's task (``<Exec>:task<n>``; base.py)
 ``op``             exec-node batch production (and join pipeline stages)
-``kernel_compile`` a cached_jit kernel's trace+compile (first call / new
-                   input signature)
-``sync``           blocking scalar readbacks (join sizing, speculation)
+``dispatch``       one launch of a kernel-cache program, by program name
+``compile``        the launch of a program its jit wrapper has not run
+                   before (trace + lower + compile or cache load), and
+                   any later launch that re-traced (new input signature)
+``scan``           file scans: ``footer`` (open + prune), ``device_decode``
+                   and ``host_decode`` (pyarrow) per row-group run
+``sync``           blocking scalar readbacks: ``join.readback`` (join
+                   sizing), ``agg.group_count`` (the aggregate waits for
+                   the program it launched to learn its group count)
 ``h2d``            host -> device uploads (arrow decode, transitions)
 ``d2h``            device -> host fetches (bulk/prepacked device_get)
 ``spill``          spill-catalog tier movement
-``shuffle``        exchange materialization + frame (de)serialization
+``shuffle``        exchange materialization (``exchange.materialize`` and,
+                   inside it per map, ``exchange.partition_ids`` /
+                   ``.split`` / ``.write``, per reduce partition
+                   ``exchange.read``; ``mesh_exchange``) + frame
+                   (de)serialization
 ``sem_wait``       device-semaphore acquisition waits
 ``fault``          chaos fault injections, shuffle fetch retries, peer
                    blacklisting, lost-block recompute (robustness/)
@@ -26,6 +55,8 @@ Event categories:
                    bounded prefetch queue; sql/physical/async_exec.py)
 ``encode``         encoded-column lifecycle: scan-side dictionary encode
                    and decline-site materializations (columnar/encoded.py)
+``admission``      serving admission waits; ``cancel`` drain latency of a
+                   cancelled query; ``fatal`` device-fatal quarantine
 ``stage``          whole-stage program execution: one span per fused-stage
                    batch (map-chain program call or terminal-stage batch
                    production; sql/physical/fusion.py)
@@ -61,16 +92,20 @@ from typing import Any, Dict, List, Optional
 
 from . import metrics as _metrics
 
-#: master switch — flipped per query by the session (restored in a
+#: the two sink switches — ``on`` arms the ring, ``profiler`` the
+#: profiler annotations.  Flipped per query by the session (restored in a
 #: ``finally``, so an exception mid-query cannot leak tracing into the
 #: next session's query).  Near-zero overhead when off.
-TRACING = {"on": False}
+TRACING = {"on": False, "profiler": False}
 
 #: known span categories (exported traces may add more; the checker and
 #: the report treat unknown categories as opaque)
-CATEGORIES = ("op", "kernel_compile", "sync", "h2d", "d2h", "spill",
-              "shuffle", "sem_wait", "fault", "queue", "encode", "stage",
-              "admission", "cancel", "fatal")
+CATEGORIES = ("query", "plan", "task", "op", "stage", "dispatch", "compile",
+              "scan", "sync", "h2d", "d2h", "spill", "shuffle", "sem_wait",
+              "fault", "queue", "encode", "admission", "cancel", "fatal")
+
+#: every profiler annotation's name starts with this
+PROFILER_PREFIX = "srt:"
 
 #: default ring capacity (spark.rapids.tpu.trace.bufferEvents)
 DEFAULT_CAPACITY = 65536
@@ -150,8 +185,7 @@ def current_trace_context() -> Optional[Dict[str, Any]]:
     session label for untracked callers.  None when tracing is off."""
     if not TRACING["on"]:
         return None
-    from ..serving import lifecycle as _lc  # deferred: avoid import cycle
-    q = _lc.current()
+    q = _lifecycle().current()
     if q is not None:
         return {"trace": f"{q.session_id}:q{q.query_id}",
                 "query": q.query_id,
@@ -159,6 +193,20 @@ def current_trace_context() -> Optional[Dict[str, Any]]:
     sid = getattr(_tls, "sid", "") or _TRACER.session_label
     return {"trace": sid or f"pid-{os.getpid()}", "query": 0,
             "tenant": thread_tenant()}
+
+
+_LIFECYCLE = None
+
+
+def _lifecycle():
+    """serving/lifecycle.py, imported on first use (it imports this
+    module) and kept: a span on the profiler's clock asks it for the
+    running query on every entry."""
+    global _LIFECYCLE
+    if _LIFECYCLE is None:
+        from ..serving import lifecycle
+        _LIFECYCLE = lifecycle
+    return _LIFECYCLE
 
 
 def set_fetch_trace(ctx: Optional[Dict[str, Any]]) -> None:
@@ -323,30 +371,73 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **args):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("cat", "name", "args", "t0")
+_ANNOTATION = None
 
-    def __init__(self, cat: str, name: str, args: Dict[str, Any]):
+
+def _annotation(cat: str, name: str, args: Dict[str, Any]):
+    """The span as a ``jax.profiler.TraceAnnotation``.  ``query=`` comes
+    from the lifecycle token installed on THIS thread (pool and prefetch
+    threads reinstall the driver's), so one request carries one id on
+    every thread; the root span passes its own."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    if "query" not in args:
+        q = _lifecycle().current()
+        if q is not None:
+            args["query"] = q.query_id
+    return _ANNOTATION(f"{PROFILER_PREFIX}{cat}:{name}", **args)
+
+
+class _Span:
+    """A ring span, and the profiler's annotation around it when both
+    sinks are armed."""
+    __slots__ = ("cat", "name", "args", "t0", "ann")
+
+    def __init__(self, cat: str, name: str, args: Dict[str, Any],
+                 profiler: bool):
         self.cat, self.name, self.args = cat, name, args
+        self.ann = _annotation(cat, name, dict(args)) if profiler else None
 
     def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc):
-        _TRACER.complete(self.cat, self.name, self.t0,
-                         time.perf_counter() - self.t0, **self.args)
+    def set_metadata(self, **args):
+        """Args known only once the work is done (bytes fetched); same
+        call as ``TraceAnnotation.set_metadata``, inside the ``with``."""
+        self.args.update(args)
+        if self.ann is not None:
+            self.ann.set_metadata(**args)
+
+    def __exit__(self, exc_type, exc, tb):
+        # a pull that ends an iterator produced nothing: not an event
+        if exc_type is not StopIteration:
+            _TRACER.complete(self.cat, self.name, self.t0,
+                             time.perf_counter() - self.t0, **self.args)
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
         return False
 
 
 def span(cat: str, name: str, **args: Any):
-    """Context manager recording a complete span when tracing is on; a
-    shared null object otherwise.  Callers computing *expensive* span
-    args should guard on ``TRACING["on"]`` themselves."""
-    if not TRACING["on"]:
-        return _NULL_SPAN
-    return _Span(cat, name, args)
+    """Context manager marking host work for whichever sinks are armed
+    (module docstring); the shared null object when neither is.  Callers
+    computing *expensive* span args should guard on the flags themselves.
+    Never hold a span open across a ``yield``: it would cover the
+    consumer's work."""
+    if TRACING["on"]:
+        return _Span(cat, name, args, TRACING["profiler"])
+    if TRACING["profiler"]:
+        return _annotation(cat, name, args)
+    return _NULL_SPAN

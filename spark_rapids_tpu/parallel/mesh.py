@@ -58,16 +58,12 @@ def _lives_on(arrays) -> List[str]:
 def _collective_timed_out(detail: str) -> MeshCollectiveTimeout:
     """The LOUD part of the degrade path, shared by the real watchdog
     and the chaos site: counter + fault span, then the typed timeout."""
-    import time as _time
-
     from ..observability import metrics as _om
     from ..observability import tracer as _trace
     STATS["collective_timeouts"] += 1
     _om.inc("mesh_collective_timeouts_total")
-    if _trace.TRACING["on"]:
-        t0 = _time.perf_counter()
-        _trace.get_tracer().complete(
-            "fault", "mesh.collective.timeout", t0, 0.0, detail=detail)
+    with _trace.span("fault", "mesh.collective.timeout", detail=detail):
+        pass    # a marker: the time went into the abandoned collective
     return MeshCollectiveTimeout(
         f"mesh collective exceeded its deadline ({detail}); "
         f"degrading stage to the local plane")
@@ -306,8 +302,11 @@ def mesh_shuffle_batches(mesh, batches: List, pids: List, nt: int) -> List:
         with mesh:
             return jitted(g_valid, g_pids, *g_leaves)
 
-    counts, *outs = _run_with_deadline(dispatch, deadline_s)
-    counts = np.asarray(counts)   # waits for the program: outputs exist
+    from ..observability import tracer as _trace
+    with _trace.span("shuffle", "mesh_exchange", partitions=nt,
+                     devices=n_dev):
+        counts, *outs = _run_with_deadline(dispatch, deadline_s)
+        counts = np.asarray(counts)   # waits for the program: outputs exist
     STATS["mesh_exchanges"] += 1
     record = {"bytes_in_use": [(d.memory_stats() or {}).get("bytes_in_use")
                                for d in mesh.devices.flat],

@@ -47,7 +47,8 @@ class ServingEngine:
                               SERVING_BROADCAST_SHARE_MAX_BYTES,
                               SERVING_RESULT_CACHE_ENABLED,
                               SERVING_RESULT_CACHE_MAX_BYTES,
-                              TRACE_BUFFER_EVENTS, TRACE_SINK)
+                              TRACE_BUFFER_EVENTS, TRACE_ENABLED,
+                              TRACE_SINK)
         from ..observability import metrics as OM
         from ..observability import tracer as OT
         from ..robustness import faults as _faults
@@ -94,7 +95,7 @@ class ServingEngine:
             int(self._conf.get(HISTORY_MAX_QUERIES)),
             str(self._conf.get(HISTORY_PATH) or ""))
         # --- engine-scoped flag arming (save/restore in close()) ---------
-        self._prev_flags = (PROFILING["on"], OT.TRACING["on"],
+        self._prev_flags = (PROFILING["on"], dict(OT.TRACING),
                             OM.METRICS["on"])
         self._prev_chaos = _faults.snapshot_arming()
         _faults.apply_conf(self._conf)
@@ -110,6 +111,7 @@ class ServingEngine:
                                   session=self.engine_id)
         PROFILING["on"] = profiling or self._tracing
         OT.TRACING["on"] = self._tracing
+        OT.TRACING["profiler"] = bool(self._conf.get(TRACE_ENABLED))
         OM.METRICS["on"] = metrics_on
         # --- telemetry plane (observability/server.py + slo.py) ----------
         # SLO objectives always get a tracker (cheap; /slo and the
@@ -169,8 +171,8 @@ class ServingEngine:
         with self._lock:
             for s in self._sessions:
                 s._serving = None
-        PROFILING["on"], OT.TRACING["on"], OM.METRICS["on"] = \
-            self._prev_flags
+        PROFILING["on"], prev_trace, OM.METRICS["on"] = self._prev_flags
+        OT.TRACING.update(prev_trace)
         _faults.restore_arming(self._prev_chaos)
 
     def __enter__(self) -> "ServingEngine":

@@ -22,6 +22,7 @@ import numpy as np
 from ... import types as T
 from ...columnar.batch import ColumnarBatch
 from ...columnar.column import DeviceColumn
+from ...observability import tracer as _trace
 from ...ops.ranks import dense_rank_columns, dense_rank_pairs
 from ...ops.segmented import seg_count, seg_max, seg_min, seg_sum
 from ..expressions.aggregates import (COUNT, FIRST, LAST, MAX, MIN, SUM,
@@ -742,14 +743,17 @@ class HashAggregateExec(PhysicalPlan):
                 fused = self._fused_fns[spec] = self._fused_partial_fn(spec)
             count_stage_dispatch()
             out, ng = fused(batch)
-            ng_host = int(ng)
+            # the host waits here for the program it just launched
+            with _trace.span("sync", "agg.group_count"):
+                ng_host = int(ng)
             if ng_host <= spec:
                 return out.with_known_rows(ng_host)
             # mis-speculation: groups past `spec` were dropped — discard
             # and take the exact path below (which re-records the size)
         count_stage_dispatch(2)  # group phase + sized reduce
         batch2, mask, rank64, ng = self._get_group_fn()(batch)
-        ng_host = int(ng)
+        with _trace.span("sync", "agg.group_count"):
+            ng_host = int(ng)
         n = max(ng_host, 1)
         out_size = min(bucket_capacity(n, minimum=64), batch2.capacity)
         # max-join: a small tail batch must not clobber the spec a large

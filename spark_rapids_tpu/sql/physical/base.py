@@ -155,46 +155,44 @@ class PhysicalPlan:
         when profiling or tracing is on, time spent pulling each batch
         from this node's iterator (children included) accrues to the
         node; the report derives self-time as inclusive minus children.
-        When tracing is on, each pull additionally emits an ``op`` span
-        and brackets itself on the tracer's exec stack — a nested child
-        pull pushes the child on top, so chokepoint spans (sync/h2d/d2h/
-        spill) fired during the pull attribute to the innermost executing
-        exec."""
+        When tracing is on (either sink), each pull additionally runs
+        inside an ``op`` span; for the ring it also brackets itself on
+        the tracer's exec stack — a nested child pull pushes the child on
+        top, so chokepoint spans (sync/h2d/d2h/spill) fired during the
+        pull attribute to the innermost executing exec.  The span closes
+        before the batch is yielded: it never covers the consumer."""
         super().__init_subclass__(**kw)
         orig = cls.__dict__.get("execute")
         if orig is None or getattr(orig, "_profiled", False):
             return
 
         def execute(self, pid, tctx, _orig=orig):
-            if not (PROFILING["on"] or _trace.TRACING["on"]):
+            tr = _trace.TRACING
+            if not (PROFILING["on"] or tr["on"] or tr["profiler"]):
                 return _orig(self, pid, tctx)
             import time as _t
 
             def gen():
-                tracing = _trace.TRACING["on"]
-                name = self.node_name() if tracing else ""
+                ring = tr["on"]
+                name = self.node_name() if ring or tr["profiler"] else ""
                 t0 = _t.perf_counter_ns()
                 it = iter(_orig(self, pid, tctx))
                 self._prof_ns += _t.perf_counter_ns() - t0
                 while True:
                     t1 = _t.perf_counter_ns()
-                    if tracing:
+                    if ring:
                         _trace.push_exec(name)
                     try:
-                        b = next(it)
+                        with _trace.span("op", name, partition=pid):
+                            b = next(it)
                     except StopIteration:
                         self._prof_ns += _t.perf_counter_ns() - t1
                         return
                     finally:
-                        if tracing:
+                        if ring:
                             _trace.pop_exec()
-                    dt = _t.perf_counter_ns() - t1
-                    self._prof_ns += dt
+                    self._prof_ns += _t.perf_counter_ns() - t1
                     self._prof_batches += 1
-                    if tracing:
-                        _trace.get_tracer().complete(
-                            "op", name, t1 / 1e9, dt / 1e9, exec_=name,
-                            partition=pid)
                     yield b
             return gen()
 
@@ -230,10 +228,10 @@ class PhysicalPlan:
         or on a bounded thread pool when
         ``spark.rapids.tpu.task.parallelism`` > 1.  Each task acquires
         the device semaphore, arms test OOM injection (conftest.py:113-265
-        analog), and fires completion callbacks.  With
-        ``spark.rapids.tpu.trace.enabled`` each task runs inside a
-        ``jax.profiler`` TraceAnnotation (NVTX-range analog); task metrics
-        accumulate onto ``self.metrics`` for the session to report.
+        analog), and fires completion callbacks.  Each task runs inside a
+        ``task`` span (NVTX-range analog; on the profiler's clock with
+        ``spark.rapids.tpu.trace.enabled``); task metrics accumulate onto
+        ``self.metrics`` for the session to report.
 
         Ordering guarantee (docs/async_pipeline.md): batches within a
         partition keep their order, and the returned list concatenates
@@ -260,7 +258,7 @@ class PhysicalPlan:
         (thread-local, so each pool worker arms its own), semaphore
         acquire/release, metric merge, completion callbacks."""
         from ...config import (DUMP_ON_ERROR_PATH, TEST_INJECT_RETRY_OOM,
-                               TEST_INJECT_SPLIT_OOM, TRACE_ENABLED)
+                               TEST_INJECT_SPLIT_OOM)
         from ...memory.completion import ScalableTaskCompletion
         from ...memory.retry import arm_oom_injection
         from ...memory.semaphore import TpuSemaphore
@@ -268,7 +266,6 @@ class PhysicalPlan:
         from ...serving import lifecycle as _lc
         sem = TpuSemaphore.get()
         stc = ScalableTaskCompletion.get()
-        tracing = bool((conf or RapidsConf.get_global()).get(TRACE_ENABLED))
         out: List[ColumnarBatch] = []
         tctx = TaskContext(pid, conf)
         # save/restore the PREVIOUS context like as_current() does: a
@@ -301,14 +298,9 @@ class PhysicalPlan:
             arm_oom_injection(int(tctx.conf.get(TEST_INJECT_RETRY_OOM)),
                               int(tctx.conf.get(TEST_INJECT_SPLIT_OOM)))
             sem.acquire_if_necessary(pid, tctx)
-            with np.errstate(all="ignore"):
-                if tracing:
-                    import jax.profiler
-                    with jax.profiler.TraceAnnotation(
-                            f"{self.node_name()}:task{pid}"):
-                        _drain(self.execute(pid, tctx))
-                else:
-                    _drain(self.execute(pid, tctx))
+            with np.errstate(all="ignore"), _trace.span(
+                    "task", f"{self.node_name()}:task{pid}", partition=pid):
+                _drain(self.execute(pid, tctx))
         except BaseException as e:
             failed = True
             dump_dir = str(tctx.conf.get(DUMP_ON_ERROR_PATH))
@@ -414,6 +406,8 @@ class PhysicalPlan:
                                donate_argnums=donate_argnums),
                     retriable=not donate_argnums)
             import jax
+            from .kernel_cache import count_unkeyed_jit
+            count_unkeyed_jit()
             return guard_device_oom(jax.jit(fn))
         return fn
 

@@ -303,17 +303,11 @@ class FusedCollectExec(PhysicalPlan):
             yield from self._run_fallback_on([batch], pid, tctx)
             return
         from ...observability import tracer as _trace
-        tracing = _trace.TRACING["on"]
-        import time as _time
-        t0 = _time.perf_counter() if tracing else 0.0
-        for b in bufs:  # overlap transfers: one latency, not N
-            b.copy_to_host_async()
-        host = [np.asarray(b) for b in bufs]
-        if tracing:
-            _trace.get_tracer().complete(
-                "d2h", "fused_collect.fetch", t0,
-                _time.perf_counter() - t0,
-                bytes=sum(b.nbytes for b in host))
+        with _trace.span("d2h", "fused_collect.fetch") as sp:
+            for b in bufs:  # overlap transfers: one latency, not N
+                b.copy_to_host_async()
+            host = [np.asarray(b) for b in bufs]
+            sp.set_metadata(bytes=sum(b.nbytes for b in host))
         leaves = unpack_buffers(host, sig)
         ng_host = int(leaves[-1])
         if not is_final:

@@ -185,10 +185,16 @@ class ShuffleExchangeExec(PhysicalPlan):
                 pieces: List[Optional[ColumnarBatch]] = [merged]
             else:
                 ctx = EvalContext(merged, xp=self.xp)
-                pids = self.partitioning.partition_ids(ctx, merged, cpid)
-                pieces = [self._split_fn(merged, pids, t).shrunk()
-                          for t in range(nt)]
-            mgr.write_map_output(shuffle_id, map_base + cpid, pieces)
+                with _trace.span("shuffle", "exchange.partition_ids",
+                                 map=cpid):
+                    pids = self.partitioning.partition_ids(ctx, merged,
+                                                           cpid)
+                with _trace.span("shuffle", "exchange.split", map=cpid,
+                                 partitions=nt):
+                    pieces = [self._split_fn(merged, pids, t).shrunk()
+                              for t in range(nt)]
+            with _trace.span("shuffle", "exchange.write", map=cpid):
+                mgr.write_map_output(shuffle_id, map_base + cpid, pieces)
 
         for cpid, merged in enumerate(map_out):
             if merged is None:
@@ -222,7 +228,9 @@ class ShuffleExchangeExec(PhysicalPlan):
                     # (published above) over the DCN transport
                     out.append([])
                     continue
-                got = mgr.read_reduce_partition(shuffle_id, total_maps, t)
+                with _trace.span("shuffle", "exchange.read", partition=t):
+                    got = mgr.read_reduce_partition(shuffle_id, total_maps,
+                                                    t)
                 out.append([got] if got is not None else [])
         except BaseException:
             # an aborted materialization (query cancel/deadline, fetch

@@ -55,7 +55,6 @@ execution, which also keeps it a more independent oracle.
 
 from __future__ import annotations
 
-import time
 from typing import List, Optional
 
 from ...columnar.batch import ColumnarBatch
@@ -260,18 +259,13 @@ class FusedStageExec(PhysicalPlan):
         t = self.terminal
         t.children = self.children
         label = self._stage_label()
-        tracing = _trace.TRACING["on"]
         it = t.execute(pid, tctx)
         while True:
-            t0 = time.perf_counter() if tracing else 0.0
             try:
-                batch = next(it)
+                with _trace.span("stage", label, partition=pid):
+                    batch = next(it)
             except StopIteration:
                 return
-            if tracing:
-                _trace.get_tracer().complete(
-                    "stage", label, t0, time.perf_counter() - t0,
-                    partition=pid)
             tctx.inc_metric("fusedStageBatches")
             yield batch
 

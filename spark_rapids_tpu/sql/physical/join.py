@@ -29,7 +29,7 @@ from ...ops.join import (JoinBuildSide, JoinInfo, compact_indices,
                          prepare_build_side, probe_join_info)
 from ..expressions.core import (AttributeReference, EvalContext, Expression,
                                 bind_references)
-from .base import PROFILING, TPU, PhysicalPlan, TaskContext
+from .base import TPU, PhysicalPlan, TaskContext
 from .exchange import BroadcastExchangeExec
 
 _PAIR_JOINS = ("inner", "left", "full", "cross")
@@ -271,28 +271,19 @@ class BaseJoinExec(PhysicalPlan):
 
     @contextmanager
     def _stage(self, tctx: Optional[TaskContext], name: str):
-        """Per-stage join profiling: a jax.profiler TraceAnnotation around
-        the host-side stage (dispatch or blocking fetch) plus a wall-time
-        metric in last_query_metrics (joinStage<Name>Ms) and a tracer
-        span (cat ``sync`` for the sizing readback)."""
-        ann = None
-        if PROFILING["on"] and self.backend == TPU:
-            import jax.profiler
-            ann = jax.profiler.TraceAnnotation(f"join:{name}")
-            ann.__enter__()
+        """Per-stage join profiling: a tracer span around the host-side
+        stage (dispatch or blocking fetch; cat ``sync`` for the sizing
+        readback) plus a wall-time metric in last_query_metrics
+        (joinStage<Name>Ms)."""
         t0 = time.perf_counter()
         try:
-            yield
+            with _tracer.span(self._STAGE_CAT.get(name, "op"),
+                              f"join.{name}"):
+                yield
         finally:
-            dt = time.perf_counter() - t0
             if tctx is not None:
                 tctx.inc_metric(f"joinStage{name[0].upper()}{name[1:]}Ms",
-                                dt * 1e3)
-            if _tracer.TRACING["on"]:
-                _tracer.get_tracer().complete(
-                    self._STAGE_CAT.get(name, "op"), f"join.{name}", t0, dt)
-            if ann is not None:
-                ann.__exit__(None, None, None)
+                                (time.perf_counter() - t0) * 1e3)
 
     def _fast_path_on(self, tctx: Optional[TaskContext]) -> bool:
         if not self._fast_ok:

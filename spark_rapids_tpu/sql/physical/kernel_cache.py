@@ -15,11 +15,15 @@ same query constructed at different times share kernels.
 
 from __future__ import annotations
 
+import hashlib
 import os
+import re
 import threading
 import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
+
+import jax.monitoring
 
 from ...observability import metrics as _om
 from ...observability import tracer as _trace
@@ -35,7 +39,14 @@ _MAX_ENTRIES = int(os.environ.get("SRT_KERNEL_CACHE_SIZE", "1024"))
 _CACHE: "OrderedDict[Tuple, Callable]" = OrderedDict()
 _LOCK = threading.Lock()
 _STATS = {"hits": 0, "misses": 0, "evictions": 0,
-          "compiles": 0, "compile_ms": 0.0, "dispatches": 0}
+          "compiles": 0, "compile_ms": 0.0, "dispatches": 0,
+          # what jax itself traced, lowered and compiled (or loaded from
+          # the persistent cache), through this cache or past it: the
+          # listener below.  0 over a warm collect is the steady state.
+          "retraces": 0, "retrace_ms": 0.0,
+          # fresh ``jax.jit`` wrappers built for a keyless exec program
+          # (base.py ``_jit`` without a key): each one traces anew
+          "unkeyed_jits": 0}
 
 #: cache GENERATION, bumped under ``_LOCK`` by every :func:`clear_cache`.
 #: The concurrent-sessions clearing contract (docs/serving.md): a clear
@@ -52,6 +63,52 @@ _GENERATION = [0]
 #: per key"); keyed by the human-readable kernel label
 _COMPILE_BY_KEY: Dict[str, Dict[str, float]] = {}
 
+#: per-program accounting of jax's own trace / lower / compile events
+#: (name -> traces, ms), by the name the program carries (``srt_...``
+#: through this cache; the traced function's own name past it)
+_RETRACE_BY_NAME: Dict[str, Dict[str, float]] = {}
+#: its own lock: the listener runs inside whatever call made jax compile
+_RETRACE_LOCK = threading.Lock()
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_COMPILE_EVENTS = (
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration")   # cache loads included
+
+try:
+    from jax._src.core import trace_state_clean as _outermost_trace
+except ImportError:  # pragma: no cover - a jax that moved it
+    def _outermost_trace() -> bool:
+        return True
+
+
+def _on_jax_duration(event: str, secs: float, fun_name: str = "",
+                     **_kw) -> None:
+    """``jax.monitoring`` listener: fires on a cache miss of ANY jitted
+    program of the process, never per launch.  A ``jnp`` call traced
+    inside another program's trace reports too; its time lies inside the
+    outer one's, so only the outermost trace is counted."""
+    traced = event == _TRACE_EVENT
+    if traced:
+        if not _outermost_trace():
+            return
+    elif event not in _LOWER_COMPILE_EVENTS:
+        return
+    name = str(fun_name)
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]
+    ms = float(secs) * 1e3
+    with _RETRACE_LOCK:
+        _STATS["retrace_ms"] += ms
+        e = _RETRACE_BY_NAME.setdefault(name, {"traces": 0, "ms": 0.0})
+        e["ms"] += ms
+        if traced:
+            _STATS["retraces"] += 1
+            e["traces"] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
 #: per-key LAUNCH accounting (doctor's dispatch-bound evidence names the
 #: top kernel keys); lock-free like _STATS["dispatches"] — a lost
 #: increment under contention is metric noise, a per-launch lock is
@@ -60,16 +117,17 @@ _DISPATCH_BY_KEY: Dict[str, int] = {}
 
 
 class _TrackedKernel:
-    """Thin wrapper over a jitted callable that detects re-traces (via
-    the jit wrapper's ``_cache_size``) and accounts trace+compile wall
-    time per kernel key — the tracer's ``kernel_compile`` spans.
+    """Thin wrapper over a jitted callable that marks every launch as a
+    ``dispatch`` span (``compile`` when it traces), detects re-traces
+    (via the jit wrapper's ``_cache_size``) and accounts trace+compile
+    wall time per kernel key.
 
-    Cost model: when tracing is OFF this is one dict lookup + one extra
+    Cost model: when tracing is OFF this is two dict lookups + one extra
     Python call per kernel launch (launches are per batch per op, never
-    per row).  When ON, a ``_cache_size()`` probe brackets the call; a
-    size increase means this call traced+compiled, and its wall time
-    (dispatch included — XLA compiles synchronously inside the call) is
-    recorded against the key.
+    per row).  When ON (either sink), a ``_cache_size()`` probe brackets
+    the call; a size increase means this call traced+compiled, and its
+    wall time (dispatch included — XLA compiles synchronously inside the
+    call) is recorded against the key.
     """
 
     __slots__ = ("_fn", "_label")
@@ -95,13 +153,18 @@ class _TrackedKernel:
             # names the top-K launch sources from these
             reg.inc("device_dispatches_by_kernel_total",
                     kernel=self._label)
-        if not _trace.TRACING["on"]:
+        tr = _trace.TRACING
+        if not (tr["on"] or tr["profiler"]):
             return self._fn(*args, **kwargs)
-        _trace.get_tracer().counter("deviceDispatches")
+        if tr["on"]:
+            _trace.get_tracer().counter("deviceDispatches")
         cs = getattr(self._fn, "_cache_size", None)
         before = cs() if cs is not None else -1
         t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
+        # a wrapper that has run nothing yet is about to trace and compile
+        with _trace.span("compile" if before == 0 else "dispatch",
+                         self._label):
+            out = self._fn(*args, **kwargs)
         dt = time.perf_counter() - t0
         if cs is not None and cs() > before:
             ms = dt * 1e3
@@ -112,8 +175,9 @@ class _TrackedKernel:
                     self._label, {"compiles": 0, "ms": 0.0})
                 e["compiles"] += 1
                 e["ms"] += ms
-            _trace.get_tracer().complete("kernel_compile", self._label,
-                                         t0, dt)
+            if before > 0 and tr["on"]:
+                # a re-trace for a new input signature: known only now
+                _trace.get_tracer().complete("compile", self._label, t0, dt)
             if _om.METRICS["on"]:
                 _om.get_registry().observe("kernel_compile_ms", ms,
                                            kernel=self._label)
@@ -166,6 +230,59 @@ def donation_supported() -> bool:
         return False
 
 
+_ADDRESS = re.compile(r" at 0x[0-9a-fA-F]+")
+
+
+def _render_key(x) -> str:
+    """A rendering of a program key that is the same in every process:
+    no ``hash()`` (Python salts it per process), no memory addresses, no
+    set order."""
+    if isinstance(x, (tuple, list)):
+        return "(" + ",".join(_render_key(e) for e in x) + ")"
+    if isinstance(x, (set, frozenset)):
+        return "{" + ",".join(sorted(_render_key(e) for e in x)) + "}"
+    if isinstance(x, dict):
+        return "{" + ",".join(sorted(
+            _render_key(k) + ":" + _render_key(v)
+            for k, v in x.items())) + "}"
+    if isinstance(x, type):
+        return f"{x.__module__}.{x.__qualname__}"
+    return _ADDRESS.sub("", repr(x))
+
+
+def program_name(key: Tuple, fn: Callable) -> str:
+    """``srt_<ExecType>_<what>_<digest>``: the one name a stage program
+    carries in the profiler's ``XLA Modules`` line (as ``jit_srt_...``),
+    in ``jax_log_compiles``, in the ``dispatch``/``compile`` spans and in
+    the per-key stats.  ``what`` is the key's own tag (``gather``,
+    ``split``, ...) or the traced function's name; the digest tells two
+    programs of one exec type apart (Q1's partial aggregate from Q6's)
+    and is identical in every process, so the persistent compile cache,
+    whose key holds the module name, keeps hitting across runs."""
+    head = (key[0] if key and isinstance(key[0], str)
+            else "program").replace("_", "")
+    tag = key[1] if len(key) > 1 and isinstance(key[1], str) else ""
+    what = tag if re.fullmatch(r"\w{1,24}", tag) \
+        else getattr(fn, "__name__", "fn").strip("_")
+    digest = hashlib.sha1(_render_key(key).encode()).hexdigest()[:8]
+    return re.sub(r"\W", "_", f"srt_{head}_{what}_{digest}")
+
+
+def exec_of_program(name: str) -> str:
+    """The exec type inside a :func:`program_name`."""
+    parts = name.split("_")
+    return parts[1] if len(parts) > 2 and parts[0] == "srt" else name
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: jax names the compiled module after the
+    jitted callable."""
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def cached_jit(key: Tuple, fn: Callable,
                donate_argnums: Optional[Tuple[int, ...]] = None) -> Callable:
     """Return the process-wide jitted callable for ``key``.
@@ -181,6 +298,10 @@ def cached_jit(key: Tuple, fn: Callable,
     from non-donating programs, and donated arguments must be sole-owner
     batches (retention.may_donate) that are never touched after the call.
     """
+    # the name is built from the key WITHOUT the salt: on a TPU the salt
+    # holds the bake-off's measured microseconds, which differ in every
+    # process (a sort verdict that flips changes the program itself)
+    unsalted = key
     key = key + _trace_salt()
     with _LOCK:
         cached = _CACHE.get(key)
@@ -192,11 +313,12 @@ def cached_jit(key: Tuple, fn: Callable,
         _STATS["misses"] += 1
         _om.inc("kernel_cache_misses_total")
         import jax
+        label = program_name(unsalted, fn)
+        fn = _named(fn, label)
         if donate_argnums and donation_supported():
             jitted = jax.jit(fn, donate_argnums=tuple(donate_argnums))
         else:
             jitted = jax.jit(fn)
-        label = f"{key[0]}#{abs(hash(key)) & 0xFFFF:04x}"
         wrapper = _TrackedKernel(jitted, label)
         _CACHE[key] = wrapper
         while len(_CACHE) > _MAX_ENTRIES:
@@ -221,9 +343,24 @@ def cache_generation() -> int:
 
 def compile_stats_by_key() -> Dict[str, Dict[str, float]]:
     """Per-kernel-key trace+compile accounting (label -> compiles, ms);
-    only accrues while tracing is on."""
+    only accrues while a tracing sink is armed."""
     with _LOCK:
         return {k: dict(v) for k, v in _COMPILE_BY_KEY.items()}
+
+
+def retraces_by_name() -> Dict[str, Dict[str, float]]:
+    """What jax traced, lowered and compiled since the last clear, by
+    program name (name -> traces, ms): ``srt_...`` for programs of this
+    cache, jax's own names (``_pad``, ``dynamic_slice``, an exec's
+    closure) for programs launched past it.  Always on."""
+    with _RETRACE_LOCK:
+        return {k: dict(v) for k, v in _RETRACE_BY_NAME.items()}
+
+
+def count_unkeyed_jit() -> None:
+    """A keyless exec program got a fresh ``jax.jit`` wrapper."""
+    with _LOCK:
+        _STATS["unkeyed_jits"] += 1
 
 
 def dispatch_stats_by_key() -> Dict[str, int]:
@@ -250,7 +387,12 @@ def clear_cache() -> None:
         _STATS["compiles"] = 0
         _STATS["compile_ms"] = 0.0
         _STATS["dispatches"] = 0
+        _STATS["unkeyed_jits"] = 0
         _DISPATCH_BY_KEY.clear()
+        with _RETRACE_LOCK:
+            _STATS["retraces"] = 0
+            _STATS["retrace_ms"] = 0.0
+            _RETRACE_BY_NAME.clear()
     # stale group-size speculations point at programs just dropped; a
     # speculated miss would recompile a size that may immediately
     # mis-speculate

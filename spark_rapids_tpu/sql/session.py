@@ -150,8 +150,21 @@ class TpuSession:
     def sql(self, query: str) -> DataFrame:
         """Run a SQL query over registered temp views — the same planning
         and execution path as the DataFrame API."""
+        from ..config import TRACE_ENABLED
+        from ..observability import tracer as OT
         from .sqlparser import parse_query
-        return parse_query(self, query)
+        # parsing runs before _execute arms the flags: arm the profiler
+        # sink around it the same way (a serving engine armed it for its
+        # lifetime; its sessions flip nothing)
+        prev = OT.TRACING["profiler"]
+        if self._serving is None:
+            OT.TRACING["profiler"] = bool(self._conf.get(TRACE_ENABLED))
+        try:
+            with OT.span("plan", "parse"):
+                return parse_query(self, query)
+        finally:
+            if self._serving is None:
+                OT.TRACING["profiler"] = prev
 
     def register_hive_function(self, name: str, impl) -> None:
         """Register a Hive-style function (the CREATE TEMPORARY FUNCTION
@@ -191,7 +204,7 @@ class TpuSession:
         from ..columnar.convert import device_to_arrow
         from ..config import (METRICS_ENABLED, METRICS_MAX_SERIES,
                               PROFILE_ENABLED, SERVING_RESULT_CACHE_ENABLED,
-                              TRACE_BUFFER_EVENTS, TRACE_SINK)
+                              TRACE_BUFFER_EVENTS, TRACE_ENABLED, TRACE_SINK)
         from ..observability import metrics as OM
         from ..observability import tracer as OT
         from ..robustness import faults as _faults
@@ -227,7 +240,7 @@ class TpuSession:
         # leak the flags into a later query or another session's.  The
         # flags being process-global at all rests on the single-driver
         # model — see PROFILING in physical/base.py.
-        prev_prof, prev_trace = PROFILING["on"], OT.TRACING["on"]
+        prev_prof, prev_trace = PROFILING["on"], dict(OT.TRACING)
         prev_metrics = OM.METRICS["on"]
         PROFILING["on"] = profiling or tracing
         self._query_seq = getattr(self, "_query_seq", 0) + 1
@@ -236,6 +249,7 @@ class TpuSession:
             OT.get_tracer().reset(int(self._conf.get(TRACE_BUFFER_EVENTS)),
                                   session=self.session_id)
         OT.TRACING["on"] = tracing
+        OT.TRACING["profiler"] = bool(self._conf.get(TRACE_ENABLED))
         if metrics_on:
             reg = OM.get_registry()
             reg.max_series = int(self._conf.get(METRICS_MAX_SERIES))
@@ -251,7 +265,9 @@ class TpuSession:
         t0 = _time.perf_counter()
         try:
             from ..serving import lifecycle as _lc
-            with _lc.installed(qctx):
+            with _lc.installed(qctx), OT.span(
+                    "query", "collect", query=self._query_seq,
+                    session=self.session_id):
                 out = self._execute_traced(logical, device_to_arrow,
                                            speculation)
             ok = True
@@ -265,7 +281,7 @@ class TpuSession:
         finally:
             duration_s = _time.perf_counter() - t0
             PROFILING["on"] = prev_prof
-            OT.TRACING["on"] = prev_trace
+            OT.TRACING.update(prev_trace)
             OM.METRICS["on"] = prev_metrics
             _faults.restore_arming(prev_chaos)
             self._finish_query_ctx(qctx)
@@ -346,7 +362,9 @@ class TpuSession:
         err: Optional[BaseException] = None
         t0 = _time.perf_counter()
         try:
-            with _lc.installed(qctx):
+            with _lc.installed(qctx), OT.span(
+                    "query", "collect", query=self._query_seq,
+                    session=self.session_id):
                 out = self._execute_traced(logical, device_to_arrow,
                                            speculation, conf=conf)
             ok = True
@@ -571,7 +589,12 @@ class TpuSession:
         # saturated engine plans smaller without mutating session state
         conf = conf or self._conf
         planner = Planner(conf)
-        phys = planner.plan_for_collect(logical)
+        from ..observability import tracer as OT
+
+        def plan(attempt: int):
+            with OT.span("plan", "physical", attempt=attempt):
+                return planner.plan_for_collect(logical)
+        phys = plan(0)
         # collect has no side effects, so speculative results may be
         # validated AFTER the fetch (zero extra pulls); a mis-speculation
         # recorded the corrected group-table size — re-plan and re-run.
@@ -609,7 +632,7 @@ class TpuSession:
                     from ..memory.spill import BufferCatalog
                     BufferCatalog.get().spill_all_device()
                     speculation.clear()
-                    phys = planner.plan_for_collect(logical)
+                    phys = plan(attempt)
                     continue
                 checks = speculation.drain()
                 bad = [c for c in checks if c.failed]
@@ -618,7 +641,7 @@ class TpuSession:
                 attempt += 1
                 speculation._bump("mis_speculations", len(bad))
                 speculation._bump("reruns")
-                phys = planner.plan_for_collect(logical)
+                phys = plan(attempt)
         finally:
             speculation.set_deferral(False)
         from .physical.base import collect_metrics

@@ -80,7 +80,7 @@ def test_h2d_bound_fixture():
 
 
 def test_compile_spill_shuffle_fixtures():
-    for cat, verdict in (("kernel_compile", "compile-bound"),
+    for cat, verdict in (("compile", "compile-bound"),
                          ("spill", "spill-bound"),
                          ("shuffle", "shuffle-bound")):
         events = [_ev(cat, "x", 200.0), _ev("sync", "r", 1.0, ts=300.0)]
@@ -119,7 +119,7 @@ def test_nested_compile_inside_shuffle_attributes_to_compile():
     events = [
         _ev("shuffle", "exchange.materialize", 300.0, ts=0.0,
             exec_="TpuShuffleExchange"),
-        _ev("kernel_compile", "HashAggregateExec#1", 280.0, ts=10.0,
+        _ev("compile", "srt_HashAggregateExec_grp_0badf00d", 280.0, ts=10.0,
             exec_="TpuHashAggregate"),
     ]
     diag = OD.diagnose(events, wall_ms=320.0)
@@ -148,7 +148,7 @@ def test_parallel_threads_do_not_cross_subtract():
     """Spans overlapping in time on DIFFERENT threads are independent."""
     events = [
         _ev("shuffle", "serialize", 100.0, ts=0.0, tid=1),
-        _ev("kernel_compile", "k", 100.0, ts=0.0, tid=2),
+        _ev("compile", "k", 100.0, ts=0.0, tid=2),
     ]
     diag = OD.diagnose(events)
     by_cat = {r["category"]: r for r in diag["ranked"]}

@@ -293,3 +293,278 @@ def test_execute_all_restores_outer_task_context():
         assert TaskContext.current() is outer
     finally:
         TaskContext._set_current(None)
+
+
+# --------------------------------------------------------------------------
+# one primitive, two sinks (PR 26): the sink matrix
+# --------------------------------------------------------------------------
+
+@pytest.fixture
+def sinks_off():
+    prev = dict(OT.TRACING)
+    OT.TRACING.update(on=False, profiler=False)
+    OT.get_tracer().reset()
+    yield OT.get_tracer()
+    OT.TRACING.update(prev)
+    OT.get_tracer().reset()
+
+
+def test_both_sinks_off_is_the_shared_null_span(sinks_off):
+    assert OT.span("d2h", "x", bytes=1) is OT._NULL_SPAN
+    assert OT.span("op", "y") is OT._NULL_SPAN
+    with OT.span("d2h", "x") as sp:
+        sp.set_metadata(bytes=3)      # accepted, goes nowhere
+    assert sinks_off.snapshot() == []
+
+
+def test_profiler_sink_alone_leaves_the_ring_empty(sinks_off):
+    seen = {}
+    import spark_rapids_tpu.sql.physical.fusion as fusion
+    real = fusion.FusedStageExec._execute_terminal
+
+    def spy(self, pid, tctx):
+        seen["flags"] = dict(OT.TRACING)
+        seen["span"] = type(OT.span("op", "probe")).__name__
+        return real(self, pid, tctx)
+    fusion.FusedStageExec._execute_terminal = spy
+    try:
+        sess = srt.session(**{"spark.rapids.tpu.trace.enabled": True})
+        _join_query(sess, salt=0.125).collect()
+    finally:
+        fusion.FusedStageExec._execute_terminal = real
+    # armed for the query only, and as an annotation, not a ring span
+    assert seen["flags"] == {"on": False, "profiler": True}
+    assert seen["span"] == "TraceAnnotation"
+    assert OT.TRACING == {"on": False, "profiler": False}
+    tr = OT.get_tracer()
+    assert tr.snapshot() == [] and tr.counters == {}
+    assert sess.last_query_trace_summary is None
+    assert "traceRingHighWater" not in sess.last_query_metrics
+
+
+def test_ring_sink_alone_yields_its_events(sinks_off):
+    sess = srt.session(**{"spark.rapids.tpu.trace.sink": "memory"})
+    q = _join_query(sess, salt=0.25)
+    q.collect()                       # compiles
+    events = list(sess._last_trace_events)
+    q.collect()                       # warm: dispatches
+    events += sess._last_trace_events
+    cats = {e["cat"] for e in events}
+    # what the ring held before the profiler sink existed ...
+    assert {"op", "stage", "sync", "h2d", "d2h", "compile",
+            "shuffle"} <= cats
+    assert any(e["name"] == "join.readback" and "Join" in e["exec"]
+               for e in events)
+    assert all("ts" in e and e["dur"] >= 0 and "tid" in e for e in events)
+    # ... plus the layer boundaries, and every category is a known one
+    assert {"query", "plan", "task", "dispatch"} <= cats
+    assert cats <= set(OT.CATEGORIES)
+    assert OT.get_tracer().counters["deviceDispatches"] > 0
+    assert OT.TRACING == {"on": False, "profiler": False}
+
+
+def test_both_sinks_on_feed_both(sinks_off, tmp_path):
+    import jax.profiler
+    sess = srt.session(**{"spark.rapids.tpu.trace.sink": "memory",
+                          "spark.rapids.tpu.trace.enabled": True})
+    q = _join_query(sess, salt=0.25)
+    q.collect()
+    with jax.profiler.trace(str(tmp_path), profiler_options=_no_python()):
+        q.collect()
+    ring = {(e["cat"], e["name"]) for e in sess._last_trace_events}
+    on_profiler = {name for line in _host_lines(str(tmp_path))
+                   for name, _, _, _ in line}
+    assert ("d2h", "fused_collect.fetch") in ring or \
+        ("d2h", "bulk_device_get") in ring
+    assert ("query", "collect") in ring
+    assert "srt:query:collect" in on_profiler
+    # what the ring alone holds are the retroactive complete() sites
+    assert {c for c, n in ring if f"srt:{c}:{n}" not in on_profiler} <= \
+        {"sem_wait", "queue"}
+
+
+# --------------------------------------------------------------------------
+# the spans on the profiler's clock
+# --------------------------------------------------------------------------
+
+def _no_python():
+    import jax.profiler
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    return options
+
+
+def _host_lines(trace_dir):
+    """Per host thread, the ``srt:`` events as (name, start, end, args)."""
+    import glob
+
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert len(files) == 1, files
+    lines = []
+    for plane in ProfileData.from_file(files[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            mine = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats)) for e in line.events
+                    if e.name.startswith("srt:")]
+            if mine:
+                lines.append(mine)
+    return lines
+
+
+def _aggregate_query(sess):
+    rng = np.random.default_rng(11)
+    t = pa.table({"k": rng.integers(0, 16, 5000), "v": rng.random(5000)})
+    return (sess.create_dataframe(t, num_partitions=2)
+            .filter(F.col("v") > 0.25).groupBy("k")
+            .agg(F.sum(F.col("v")).alias("sv")))
+
+
+@pytest.mark.parametrize("make", [_aggregate_query, _join_query],
+                         ids=["aggregate", "join"])
+def test_spans_nest_under_the_query_on_the_profilers_clock(make, tmp_path):
+    import jax.profiler
+    sess = srt.session(**{"spark.rapids.tpu.trace.enabled": True})
+    q = make(sess)
+    q.collect()                       # warm: the traced collect dispatches
+    with jax.profiler.trace(str(tmp_path), profiler_options=_no_python()):
+        q.collect()
+    lines = _host_lines(str(tmp_path))
+    roots = [(line, ev) for line in lines for ev in line
+             if ev[0] == "srt:query:collect"]
+    assert len(roots) == 1
+    driver, (_, q0, q1, qargs) = roots[0]
+    assert int(qargs["query"]) == sess._query_seq
+    assert qargs["session"] == sess.session_id
+    inside = {name for name, s, e, _ in driver if q0 <= s and e <= q1}
+    for prefix in ("srt:plan:physical", "srt:task:", "srt:op:",
+                   "srt:dispatch:", "srt:d2h:"):
+        assert any(n.startswith(prefix) for n in inside), (prefix, inside)
+    # every span of the collect lies inside the root, carries its query
+    # id, and none outlives the span it started in
+    for line in lines:
+        stack = []
+        for name, s, e, args in sorted(line, key=lambda ev: (ev[1], -ev[2])):
+            assert q0 <= s and e <= q1, name
+            assert int(args["query"]) == sess._query_seq, (name, args)
+            while stack and stack[-1] <= s:
+                stack.pop()
+            assert not stack or e <= stack[-1], name
+            stack.append(e)
+    assert OT.get_tracer().snapshot() == []     # nothing went to the ring
+
+
+def test_parquet_read_shows_scan_spans(tmp_path):
+    import jax.profiler
+    import pyarrow.parquet as pq
+    rng = np.random.default_rng(5)
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(pa.table({"a": rng.integers(0, 9, 4000),
+                             "b": rng.random(4000)}), path,
+                   row_group_size=1000)
+    trace_dir = str(tmp_path / "trace")
+    # device decode off: the host decodes, then uploads
+    sess = srt.session(**{
+        "spark.rapids.tpu.trace.enabled": True,
+        "spark.rapids.sql.format.parquet.deviceDecode.enabled": False,
+        "spark.rapids.sql.reader.chunked": True})
+    df = sess.read.parquet(path).groupBy("a").agg(F.sum(F.col("b")))
+    with jax.profiler.trace(trace_dir, profiler_options=_no_python()):
+        df.collect()
+    events = [ev for line in _host_lines(trace_dir) for ev in line]
+    names = [ev[0] for ev in events]
+    assert "srt:scan:footer" in names and "srt:scan:host_decode" in names
+    decode = [ev for ev in events if ev[0] == "srt:scan:host_decode"]
+    uploads = [ev for ev in events if ev[0] == "srt:h2d:arrow_to_device"]
+    assert uploads and int(decode[0][3]["row_groups"]) >= 1
+    # decode and upload are two spans: no upload lies inside a decode
+    assert not any(d[1] <= u[1] < d[2] for d in decode for u in uploads)
+
+
+# --------------------------------------------------------------------------
+# program names and the retrace counters
+# --------------------------------------------------------------------------
+
+_NAMES_SCRIPT = """
+import numpy as np, pyarrow as pa
+import spark_rapids_tpu as srt
+from spark_rapids_tpu.sql import functions as F
+from spark_rapids_tpu.sql.physical import kernel_cache as KC
+sess = srt.session()
+t = pa.table({"k": np.arange(600) % 7, "v": np.linspace(0, 1, 600),
+              "w": np.linspace(1, 2, 600)})
+df = sess.create_dataframe(t, num_partitions=2)
+df.filter(F.col("v") > 0.25).groupBy("k").agg(F.sum(F.col("v"))).collect()
+print("SUM_V", *sorted("jit_" + n for n in KC.retraces_by_name()
+                       if n.startswith("srt_")))
+KC.clear_cache()
+df.filter(F.col("v") > 0.25).groupBy("k").agg(F.sum(F.col("w"))).collect()
+print("SUM_W", *sorted("jit_" + n for n in KC.retraces_by_name()
+                       if n.startswith("srt_")))
+"""
+
+
+def test_program_names_are_the_same_in_every_process():
+    procs = [subprocess.Popen(
+        [sys.executable, "-c", _NAMES_SCRIPT], cwd=REPO,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        env={**os.environ, "PYTHONHASHSEED": seed, "JAX_PLATFORMS": "cpu"})
+        for seed in ("1", "2")]
+    outs = []
+    for p in procs:
+        out, err = p.communicate(timeout=600)
+        assert p.returncode == 0, err[-2000:]
+        outs.append({line.split()[0]: line.split()[1:]
+                     for line in out.splitlines()
+                     if line.startswith(("SUM_V", "SUM_W"))})
+    assert outs[0] == outs[1]
+    sum_v, sum_w = outs[0]["SUM_V"], outs[0]["SUM_W"]
+    assert sum_v and all(n.startswith("jit_srt_") for n in sum_v + sum_w)
+    # the partial aggregate over another expression is another program
+    agg_v = {n for n in sum_v if "HashAggregate" in n}
+    agg_w = {n for n in sum_w if "HashAggregate" in n}
+    assert agg_v and agg_w and agg_v != agg_w
+
+
+def test_program_name_ignores_the_trace_salt_and_tells_keys_apart():
+    from spark_rapids_tpu.sql.physical import kernel_cache as KC
+
+    def impl(b):
+        return b
+    a = KC.program_name(("HashAggregateExec", "grp", ("sum", 1)), impl)
+    b = KC.program_name(("HashAggregateExec", "grp", ("sum", 2)), impl)
+    c = KC.program_name(("ProjectExec", (("col", 0),), ("x",)), impl)
+    assert a != b and a.startswith("srt_HashAggregateExec_grp_")
+    assert c.startswith("srt_ProjectExec_impl_")
+    assert KC.exec_of_program(a) == "HashAggregateExec"
+    # sets and dicts render in one order whatever the hash seed
+    assert KC._render_key((frozenset({"b", "a"}), {"y": 1, "x": 2})) == \
+        "({'a','b'},{'x':2,'y':1})"
+    fn = KC.cached_jit(("ProjectExec", "named-test", 7), impl)
+    assert fn._label == KC.program_name(("ProjectExec", "named-test", 7),
+                                        impl)
+
+
+def test_retrace_counters_are_zero_on_a_warm_collect():
+    from spark_rapids_tpu.sql.physical.kernel_cache import (
+        cache_stats, retraces_by_name)
+    sess = srt.session()
+    q = _join_query(sess, salt=0.375)
+    s0 = cache_stats()
+    assert {"retrace_ms", "retraces", "unkeyed_jits"} <= set(s0)
+    q.collect()
+    s1 = cache_stats()
+    assert s1["retraces"] > s0["retraces"]
+    assert s1["retrace_ms"] > s0["retrace_ms"]
+    assert any(n.startswith("srt_") and row["traces"] >= 1
+               for n, row in retraces_by_name().items())
+    q.collect()
+    q.collect()
+    s2 = cache_stats()
+    q.collect()
+    s3 = cache_stats()
+    assert s3["retraces"] == s2["retraces"]
+    assert s3["retrace_ms"] == s2["retrace_ms"]

@@ -43,9 +43,9 @@ KNOWN_PH = ("X", "C", "i", "M", "B", "E", "s", "t", "f")
 #: categories the tracer emits today (observability/tracer.py
 #: CATEGORIES); unknown categories stay opaque — listed for reference
 #: and for --require-cat hints, not validated
-KNOWN_CATS = ("op", "kernel_compile", "sync", "h2d", "d2h", "spill",
-              "shuffle", "sem_wait", "fault", "queue", "encode", "stage",
-              "admission", "cancel", "fatal")
+KNOWN_CATS = ("query", "plan", "task", "op", "stage", "dispatch", "compile",
+              "scan", "sync", "h2d", "d2h", "spill", "shuffle", "sem_wait",
+              "fault", "queue", "encode", "admission", "cancel", "fatal")
 
 
 def check(path: str, min_events: int = 1, require_cat: str = "",
