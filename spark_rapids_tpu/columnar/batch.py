@@ -11,6 +11,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..types import DataType, StructField, StructType
 from .column import DeviceColumn, bucket_capacity
@@ -145,6 +146,13 @@ class ColumnarBatch:
     #: num_rows sync shrunk() needs costs a full host<->device round trip
     _SHRINK_MIN_CAPACITY = 4096
 
+    def shrunk_capacity(self, num_rows: int) -> int:
+        """The capacity :meth:`shrunk` leaves a batch of this capacity
+        that holds ``num_rows`` live rows."""
+        if self.capacity <= self._SHRINK_MIN_CAPACITY:
+            return self.capacity
+        return min(bucket_capacity(num_rows), self.capacity)
+
     def shrunk(self) -> "ColumnarBatch":
         """Drop excess capacity padding down to the row count's bucket.
         Host-side decision (syncs on num_rows); call at exec boundaries
@@ -152,10 +160,17 @@ class ColumnarBatch:
         downstream kernels/serializers don't chew dead padding."""
         if self.capacity <= self._SHRINK_MIN_CAPACITY:
             return self
-        cap = bucket_capacity(self.num_rows_int)
-        if cap >= self.capacity:
-            return self
-        return self.repadded(cap)
+        cap = self.shrunk_capacity(self.num_rows_int)
+        return self if cap >= self.capacity else self.repadded(cap)
+
+    def window(self, start, num_rows, capacity: int,
+               xp=jnp) -> "ColumnarBatch":
+        """Rows ``[start, start + num_rows)`` as a batch of ``capacity``
+        (>= num_rows): contiguous copies, for use inside a program —
+        ``start`` and ``num_rows`` may be traced int32."""
+        live = xp.arange(capacity, dtype=xp.int32) < num_rows
+        cols = tuple(c.window(start, capacity, live) for c in self.columns)
+        return ColumnarBatch(self.names, cols, num_rows)
 
     def sliced(self, start: int, length: int) -> "ColumnarBatch":
         """Host-side slice: returns a batch viewing rows [start, start+len).
@@ -175,22 +190,38 @@ class ColumnarBatch:
 
     @staticmethod
     def concat(batches: Sequence["ColumnarBatch"]) -> "ColumnarBatch":
-        """Concatenate batches (cudf ``Table.concatenate`` analog).  Uses a
-        gather per input into a fresh bucket so string widths re-align."""
+        """Concatenate batches (cudf ``Table.concatenate`` analog), row
+        order = piece order then row order, string widths re-aligned to
+        the widest.  Plain device columns go through ONE cached program
+        per call (:func:`_concat_program`); column kinds it cannot express
+        (:func:`concat_declined`) take the per-array path."""
         if not batches:
             raise ValueError("ColumnarBatch.concat requires at least one batch")
         batches = [b for b in batches if b.num_rows_int > 0] or list(batches[:1])
         if len(batches) == 1:
             return batches[0]
-        total = sum(b.num_rows_int for b in batches)
+        counts = [b.num_rows_int for b in batches]
+        total = sum(counts)
         cap = bucket_capacity(total)
-        out_cols: List[DeviceColumn] = []
         names = batches[0].names
-        for ci in range(batches[0].num_cols):
-            pieces = [b.columns[ci] for b in batches]
-            out_cols.append(_concat_columns(pieces, [b.num_rows_int for b in batches], cap))
-        out = ColumnarBatch.make(names, out_cols, total)
-        # a real multi-batch concat gathers into fresh buffers: mark it
+        if concat_declined(batches):
+            CONCAT_STATS["eager"] += 1
+            rows = _EagerRows(counts, cap)
+            out_cols = tuple(
+                _concat_columns([b.columns[ci] for b in batches], rows)
+                for ci in range(batches[0].num_cols))
+            out = ColumnarBatch.make(names, out_cols, total)
+        else:
+            CONCAT_STATS["programs"] += 1
+            out_cols, num_rows = _concat_program(batches, cap)(
+                tuple(tuple(_codes_column(c) for c in b.columns)
+                      for b in batches),
+                np.asarray(counts, dtype=np.int32))
+            out_cols = tuple(_dict_column(c, out) for c, out
+                             in zip(batches[0].columns, out_cols))
+            out = ColumnarBatch(names, out_cols,
+                                num_rows).with_known_rows(total)
+        # a real multi-batch concat writes fresh buffers: mark it
         # donation-eligible (memory/retention.py) — EXCEPT when an input
         # was encoded (dictionary objects are shared with the inputs);
         # may_donate declines encoded batches structurally anyway, but an
@@ -207,13 +238,160 @@ class ColumnarBatch:
                 f"cols={list(zip(self.names, [c.dtype for c in self.columns]))})")
 
 
-def _concat_columns(cols: Sequence[DeviceColumn], counts: Sequence[int],
-                    out_capacity: int) -> DeviceColumn:
+#: how :meth:`ColumnarBatch.concat` ran, per call of two or more live
+#: pieces: as its one cached program, or per array (``concat_declined``)
+CONCAT_STATS = {"programs": 0, "eager": 0}
+
+
+def concat_declined(batches: Sequence[ColumnarBatch]) -> str:
+    """Why ``concat`` cannot run these pieces as one program — ``encoded``
+    (dictionaries that differ are unified on the host; RLE and nested
+    encoded columns materialize), ``object`` (host nested columns),
+    ``numpy`` (host-backend arrays) — or ``""`` when it can.  Read off
+    the columns themselves."""
+    from .encoded import same_dictionary
+    for cols in zip(*(b.columns for b in batches)):
+        if same_dictionary(cols):
+            continue    # the codes concatenate like any int32 column
+        for c in cols:
+            why = _column_declined(c)
+            if why:
+                return why
+    return ""
+
+
+def _column_declined(col: DeviceColumn) -> str:
+    from .encoded import DictEncodedColumn, RLEColumn
+    if isinstance(col, (DictEncodedColumn, RLEColumn)):
+        return "encoded"
+    for arr in (col.data, col.validity, col.lengths, col.aux):
+        if arr is None or isinstance(arr, jax.Array):
+            continue
+        return "object" if getattr(arr, "dtype", None) == object else "numpy"
+    for child in col.children:
+        why = _column_declined(child)
+        if why:
+            return why
+    return ""
+
+
+def _column_signature(col: DeviceColumn) -> Tuple:
+    """What of a plain device column shapes the concat program besides
+    its capacity: types, string width, array slot width (by the child's
+    rows), presence of lengths/aux."""
+    return (repr(col.dtype),
+            None if col.data is None
+            else (str(col.data.dtype),) + tuple(col.data.shape[1:]),
+            col.lengths is not None, col.aux is not None,
+            tuple((ch.capacity, _column_signature(ch))
+                  for ch in col.children))
+
+
+def _codes_column(col: DeviceColumn) -> DeviceColumn:
+    """A dict-encoded column as the program sees it: its codes, a plain
+    int32 column (the dictionary stays outside, shared, uncopied)."""
+    from ..types import IntegerType
+    from .encoded import DictEncodedColumn
+    if isinstance(col, DictEncodedColumn):
+        return DeviceColumn(IntegerType(), col.codes, col.validity)
+    return col
+
+
+def _dict_column(first: DeviceColumn, out: DeviceColumn) -> DeviceColumn:
+    """The program's output column, over ``first``'s dictionary again
+    where the pieces were dict-encoded."""
+    from .encoded import DictEncodedColumn, _bump
+    if isinstance(first, DictEncodedColumn):
+        _bump("concat_unified")
+        return DictEncodedColumn(first.dtype, out.data, first.dictionary,
+                                 out.validity)
+    return out
+
+
+def _concat_program(batches: Sequence[ColumnarBatch], out_capacity: int):
+    """The cached program that concatenates pieces of these shapes:
+    ``(columns of each piece, int32[k] live row counts) -> (columns,
+    total rows)``.  The counts are data, so they are an operand."""
+    from ..sql.physical.kernel_cache import cached_jit
+    caps = tuple(b.capacity for b in batches)
+    sig = tuple(tuple(_column_signature(_codes_column(c)) for c in b.columns)
+                for b in batches)
+
+    def concat(pieces, counts):
+        rows = _TracedRows(counts, caps, out_capacity)
+        cols = tuple(_concat_columns([p[ci] for p in pieces], rows)
+                     for ci in range(len(pieces[0])))
+        return cols, rows.total
+
+    return cached_jit(("ColumnarBatch", "concat", sig, caps, out_capacity),
+                      concat)
+
+
+class _EagerRows:
+    """Row placement for the per-array path: every piece is sliced to its
+    live rows on the host's word, concatenated and padded — three eager
+    launches per array per call."""
+
+    def __init__(self, counts: Sequence[int], out_capacity: int):
+        self.counts = list(counts)
+        self.out_capacity = out_capacity
+
+    def scaled(self, width: int) -> "_EagerRows":
+        return _EagerRows([n * width for n in self.counts],
+                          self.out_capacity * width)
+
+    def cat(self, arrs, fill):
+        if getattr(arrs[0], "dtype", None) == object:  # host nested columns
+            return _concat_object(arrs, self.counts, self.out_capacity)
+        cat = jnp.concatenate([a[:n] for a, n in zip(arrs, self.counts)],
+                              axis=0)
+        pad = ([(0, self.out_capacity - cat.shape[0])]
+               + [(0, 0)] * (cat.ndim - 1))
+        return jnp.pad(cat, pad, constant_values=fill)
+
+
+class _TracedRows:
+    """Row placement inside the concat program: ``counts`` is a traced
+    int32[k].  Each piece is written whole (at its full capacity) at the
+    running offset of the live rows before it, in piece order, so the
+    dead tail of one piece is overwritten by the next piece's rows; rows
+    at and past the total get the fill."""
+
+    def __init__(self, counts, caps: Tuple[int, ...], out_capacity: int):
+        self.counts = counts
+        self.caps = caps
+        self.out_capacity = out_capacity
+        self.offsets = jnp.cumsum(counts, dtype=jnp.int32) - counts
+        self.total = jnp.sum(counts, dtype=jnp.int32)
+        self.live = jnp.arange(out_capacity, dtype=jnp.int32) < self.total
+
+    def scaled(self, width: int) -> "_TracedRows":
+        return _TracedRows(self.counts * width,
+                           tuple(c * width for c in self.caps),
+                           self.out_capacity * width)
+
+    def cat(self, arrs, fill):
+        tail = arrs[0].shape[1:]
+        # room past the end: a piece written at its offset never clamps
+        buf = jnp.full((self.out_capacity + max(self.caps),) + tail, fill,
+                       dtype=arrs[0].dtype)
+        for i, a in enumerate(arrs):
+            buf = jax.lax.dynamic_update_slice_in_dim(
+                buf, a, self.offsets[i], axis=0)
+        live = self.live.reshape((-1,) + (1,) * len(tail))
+        return jnp.where(live, buf[:self.out_capacity],
+                         jnp.asarray(fill, dtype=buf.dtype))
+
+
+def _concat_columns(cols: Sequence[DeviceColumn], rows) -> DeviceColumn:
+    """One output column from the pieces' columns; ``rows`` places the
+    arrays (:class:`_EagerRows` or, inside the program, :class:`_TracedRows`)."""
     from .column import DeviceColumn as DC
     from .encoded import DictEncodedColumn, try_concat_dict_columns
     if any(isinstance(c, DictEncodedColumn) for c in cols):
         if all(isinstance(c, DictEncodedColumn) for c in cols):
-            enc = try_concat_dict_columns(cols, counts, out_capacity)
+            enc = try_concat_dict_columns(cols, rows.counts,
+                                          rows.out_capacity)
             if enc is not None:
                 return enc
         # mixed / over-budget: fall through — the .data/.lengths property
@@ -224,56 +402,33 @@ def _concat_columns(cols: Sequence[DeviceColumn], counts: Sequence[int],
         # (each parent row owns a contiguous width-sized child block)
         width = max(c.array_width for c in cols)
         cols = [c.with_array_width(width) for c in cols]
+        child_rows = rows.scaled(width)
         children = tuple(
-            _concat_columns([c.children[k] for c in cols],
-                            [n * width for n in counts],
-                            out_capacity * width)
+            _concat_columns([c.children[k] for c in cols], child_rows)
             for k in range(len(cols[0].children)))
-        validity = _concat_1d([c.validity for c in cols], counts,
-                              out_capacity, False)
-        lengths = _concat_1d([c.lengths for c in cols], counts,
-                             out_capacity, 0)
-        return DC(dtype, None, validity, lengths, None, children)
+        return DC(dtype, None, rows.cat([c.validity for c in cols], False),
+                  rows.cat([c.lengths for c in cols], 0), None, children)
     if cols[0].data is None:  # struct
         children = tuple(
-            _concat_columns([c.children[k] for c in cols], counts, out_capacity)
+            _concat_columns([c.children[k] for c in cols], rows)
             for k in range(len(cols[0].children)))
-        validity = _concat_1d([c.validity for c in cols], counts, out_capacity, False)
-        return DC(dtype, None, validity, children=children)
+        return DC(dtype, None, rows.cat([c.validity for c in cols], False),
+                  children=children)
     datas = [c.data for c in cols]
     if datas[0].ndim == 2:
         width = max(d.shape[1] for d in datas)
         datas = [jnp.pad(d, ((0, 0), (0, width - d.shape[1]))) if d.shape[1] < width
                  else d for d in datas]
-    data = _concat_nd(datas, counts, out_capacity)
-    validity = _concat_1d([c.validity for c in cols], counts, out_capacity, False)
-    lengths = (_concat_1d([c.lengths for c in cols], counts, out_capacity, 0)
+    data = rows.cat(datas, 0)
+    validity = rows.cat([c.validity for c in cols], False)
+    lengths = (rows.cat([c.lengths for c in cols], 0)
                if cols[0].lengths is not None else None)
-    aux = (_concat_1d([c.aux for c in cols], counts, out_capacity, 0)
+    aux = (rows.cat([c.aux for c in cols], 0)
            if cols[0].aux is not None else None)
     return DC(dtype, data, validity, lengths, aux)
 
 
-def _concat_1d(arrs, counts, out_capacity, fill):
-    if getattr(arrs[0], "dtype", None) == object:  # host nested columns
-        return _concat_object(arrs, counts, out_capacity)
-    live = [a[:n] for a, n in zip(arrs, counts)]
-    cat = jnp.concatenate(live) if live else arrs[0][:0]
-    pad = out_capacity - cat.shape[0]
-    return jnp.pad(cat, (0, pad), constant_values=fill)
-
-
-def _concat_nd(arrs, counts, out_capacity):
-    if getattr(arrs[0], "dtype", None) == object:  # host nested columns
-        return _concat_object(arrs, counts, out_capacity)
-    live = [a[:n] for a, n in zip(arrs, counts)]
-    cat = jnp.concatenate(live, axis=0) if live else arrs[0][:0]
-    pad = [(0, out_capacity - cat.shape[0])] + [(0, 0)] * (cat.ndim - 1)
-    return jnp.pad(cat, pad)
-
-
 def _concat_object(arrs, counts, out_capacity):
-    import numpy as np
     out = np.empty(out_capacity, dtype=object)
     pos = 0
     for a, n in zip(arrs, counts):

@@ -147,6 +147,25 @@ class DeviceColumn:
             fix(self.aux),
             tuple(c.slice_capacity(new_capacity) for c in self.children))
 
+    def window(self, start, capacity: int, live) -> "DeviceColumn":
+        """Rows ``[start, start + capacity)`` as a column of that capacity
+        — a contiguous copy, no gather.  ``start`` may be traced; rows
+        past this column's end read as padding; ``live`` (bool[capacity])
+        says which rows of the window exist, the rest come out invalid."""
+        validity = _window(self.validity, start, capacity) & live
+        lengths = _window(self.lengths, start, capacity)
+        if self.is_array_like:
+            w = self.array_width
+            child_live = live[:, None].repeat(w, axis=1).reshape(-1)
+            return DeviceColumn(
+                self.dtype, None, validity, lengths, None,
+                tuple(c.window(start * w, capacity * w, child_live)
+                      for c in self.children))
+        return DeviceColumn(
+            self.dtype, _window(self.data, start, capacity), validity,
+            lengths, _window(self.aux, start, capacity),
+            tuple(c.window(start, capacity, live) for c in self.children))
+
     def gather(self, idx: jnp.ndarray, idx_valid: Optional[jnp.ndarray] = None
                ) -> "DeviceColumn":
         """Select rows by index (the JoinGatherer primitive).  ``idx`` may
@@ -193,6 +212,20 @@ class DeviceColumn:
         lengths = jnp.minimum(self.lengths, new_width)
         return DeviceColumn(self.dtype, None, self.validity, lengths, None,
                             children)
+
+
+def _window(arr, start, capacity: int):
+    """``arr[start : start + capacity]`` with a possibly traced ``start``,
+    zero-padded where the window runs past the array's end."""
+    if arr is None:
+        return None
+    if isinstance(arr, np.ndarray):     # host backend: ``start`` is concrete
+        out = np.zeros((capacity,) + arr.shape[1:], dtype=arr.dtype)
+        rows = arr[int(start):int(start) + capacity]
+        out[:rows.shape[0]] = rows
+        return out
+    pad = [(0, capacity)] + [(0, 0)] * (arr.ndim - 1)
+    return jax.lax.dynamic_slice_in_dim(jnp.pad(arr, pad), start, capacity)
 
 
 def _fix_1d(arr, new_capacity: int, fill):

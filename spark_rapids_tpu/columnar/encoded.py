@@ -388,6 +388,14 @@ class DictEncodedColumn(DeviceColumn):
             self.dtype, _fix_1d(self.codes, new_capacity, 0),
             self.dictionary, _fix_1d(self.validity, new_capacity, False))
 
+    def window(self, start, capacity: int, live) -> "DictEncodedColumn":
+        import jax.numpy as jnp
+        from .column import _window
+        validity = _window(self.validity, start, capacity) & live
+        codes = jnp.where(validity, _window(self.codes, start, capacity), 0)
+        return DictEncodedColumn(self.dtype, codes, self.dictionary,
+                                 validity)
+
     def gather(self, idx, idx_valid=None) -> "DictEncodedColumn":
         """Row selection gathers CODES, not values — the encoding survives
         filters, join output assembly, group-by key emission, and sorts.
@@ -493,6 +501,9 @@ class RLEColumn(DeviceColumn):
 
     def gather(self, idx, idx_valid=None) -> DeviceColumn:
         return self.materialized().gather(idx, idx_valid)
+
+    def window(self, start, capacity: int, live) -> DeviceColumn:
+        return self.materialized().window(start, capacity, live)
 
     def __repr__(self) -> str:  # pragma: no cover
         return (f"RLEColumn(rows={self.capacity}, runs={self.num_runs}, "
@@ -762,6 +773,17 @@ def materialize_np(col: DeviceColumn) -> DeviceColumn:
 # dict-aware concat (exchange reduce, broadcast, join build sides)
 # --------------------------------------------------------------------------
 
+def same_dictionary(cols: Sequence[DeviceColumn]) -> bool:
+    """All of ``cols`` are dict-encoded over one dictionary (the same
+    object, or equal by content): their codes concatenate as they are."""
+    first = cols[0]
+    return all(isinstance(c, DictEncodedColumn)
+               and (c.dictionary is first.dictionary
+                    or c.dictionary.content_hash
+                    == first.dictionary.content_hash)
+               for c in cols)
+
+
 def try_concat_dict_columns(cols: Sequence[DeviceColumn],
                             counts: Sequence[int],
                             out_capacity: int) -> Optional[DictEncodedColumn]:
@@ -774,9 +796,7 @@ def try_concat_dict_columns(cols: Sequence[DeviceColumn],
     import jax.numpy as jnp
     dtype = cols[0].dtype
     first = cols[0].dictionary
-    if all(c.dictionary is first
-           or c.dictionary.content_hash == first.content_hash
-           for c in cols):
+    if same_dictionary(cols):
         codes = _concat_padded([c.codes for c in cols], counts,
                                out_capacity, 0)
         validity = _concat_padded([c.validity for c in cols], counts,

@@ -492,7 +492,12 @@ class SpillableColumnarBatch:
     def get(self) -> ColumnarBatch:
         if self._handle is None:
             raise ValueError("SpillableColumnarBatch already closed")
-        return self._catalog.get_batch(self._handle)
+        batch = self._catalog.get_batch(self._handle)
+        if self._num_rows is not None:
+            # the catalog rebuilds the batch from its leaves: hand the
+            # registrant's host-known count on, so no reader syncs for it
+            batch.with_known_rows(self._num_rows)
+        return batch
 
     def get_and_close(self) -> ColumnarBatch:
         b = self.get()

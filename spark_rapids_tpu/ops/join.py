@@ -73,16 +73,41 @@ def compact_indices(xp, flags):
     an argsort — the compaction primitive behind filter, split, and join
     assembly (cuDF ``apply_boolean_mask`` analog)."""
     n = flags.shape[0]
-    idx = xp.arange(n, dtype=xp.int32)
     kept_pos = xp.cumsum(flags.astype(xp.int32))
     n_keep = kept_pos[-1] if n else xp.asarray(0, dtype=xp.int32)
     dead_pos = xp.cumsum((~flags).astype(xp.int32))
     dest = xp.where(flags, kept_pos - 1, n_keep + dead_pos - 1)
+    return _rows_at(xp, dest)
+
+
+def _rows_at(xp, dest):
+    """The inverse of a permutation: ``out[dest[i]] = i``."""
+    n = dest.shape[0]
     if xp.__name__ == "numpy":
         out = np.empty(n, dtype=np.int32)
-        out[dest] = idx
+        out[dest] = np.arange(n, dtype=np.int32)
         return out
-    return xp.zeros(n, dtype=xp.int32).at[dest].set(idx)
+    return xp.zeros(n, dtype=xp.int32).at[dest].set(
+        xp.arange(n, dtype=xp.int32))
+
+
+def partition_indices(xp, keys, num_keys: int):
+    """Stable counting sort of the row indices by ``keys`` (int32, every
+    one in ``[0, num_keys)``): ``(perm, counts)`` with the rows of key k at
+    ``perm[sum(counts[:k]) : sum(counts[:k + 1])]`` in their original
+    order, and ``counts`` int32[num_keys].  One cumsum per key and one
+    scatter — the all-targets form of :func:`compact_indices` (cuDF
+    ``Table.partition`` analog), so a caller gathers each array once."""
+    dest = xp.zeros(keys.shape[0], dtype=xp.int32)
+    start = xp.asarray(0, dtype=xp.int32)
+    counts = []
+    for k in range(num_keys):
+        mine = keys == k
+        rank = xp.cumsum(mine.astype(xp.int32), dtype=xp.int32)
+        dest = xp.where(mine, start + rank - 1, dest)
+        counts.append(xp.sum(mine, dtype=xp.int32))
+        start = start + counts[-1]
+    return _rows_at(xp, dest), xp.stack(counts)
 
 
 class JoinInfo(NamedTuple):

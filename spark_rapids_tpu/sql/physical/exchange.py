@@ -11,17 +11,19 @@ per-partition) is identical, mirroring the reference's shuffle-manager SPI.
 from __future__ import annotations
 
 import threading
+from contextlib import contextmanager
 from typing import Dict, List, Optional
 
 import numpy as np
 
-from ...columnar.batch import ColumnarBatch
+from ...columnar.batch import CONCAT_STATS, ColumnarBatch, concat_declined
 from ...observability import tracer as _trace
 from ...parallel.partitioning import (HashPartitioning, Partitioning,
                                       RangePartitioning, RoundRobinPartitioning,
                                       SinglePartitioning)
 from ..expressions.core import EvalContext
 from .base import TPU, PhysicalPlan, TaskContext
+from .kernel_cache import exprs_key
 
 
 def empty_batch_for(attrs) -> ColumnarBatch:
@@ -31,8 +33,25 @@ def empty_batch_for(attrs) -> ColumnarBatch:
         T.StructField(a.name, a.dtype, True) for a in attrs)))
 
 
-#: observability for the AQE skew-split reader (tests assert on these)
-STATS = {"skew_splits": 0, "skew_chunks": 0}
+#: observability (tests assert on these): the AQE skew-split reader, and
+#: how the local plane launched its work — per map output one ``map``
+#: program (rows ordered by target) and one ``shrink`` program (the pieces
+#: cut out at their buckets), one ``concat`` program per merge of pieces;
+#: ``eager_fallbacks`` counts the maps and merges that ran per launch or
+#: per array instead (their spans carry ``declined=<why>``)
+STATS = {"skew_splits": 0, "skew_chunks": 0, "map_programs": 0,
+         "shrink_programs": 0, "concat_programs": 0, "eager_fallbacks": 0}
+
+
+@contextmanager
+def _counting_concats():
+    """Credit the ``ColumnarBatch.concat`` calls made inside to STATS."""
+    programs, eager = CONCAT_STATS["programs"], CONCAT_STATS["eager"]
+    try:
+        yield
+    finally:
+        STATS["concat_programs"] += CONCAT_STATS["programs"] - programs
+        STATS["eager_fallbacks"] += CONCAT_STATS["eager"] - eager
 
 
 class ShuffleExchangeExec(PhysicalPlan):
@@ -61,7 +80,6 @@ class ShuffleExchangeExec(PhysicalPlan):
         #: materialize would run the whole map side twice and double-write
         #: shuffle blocks
         self._mat_lock = threading.Lock()
-        self._split_fn = self._jit(self._split_one, key=("split",))
         #: map-side runtime filter (bloom-filter join pushdown): applied to
         #: each map partition's merged output BEFORE the split/write, so
         #: dropped rows never ride the shuffle.  Installed by the join
@@ -77,10 +95,93 @@ class ShuffleExchangeExec(PhysicalPlan):
         return self.partitioning.num_partitions
 
     # --- device kernels ---------------------------------------------------
-    def _split_one(self, batch: ColumnarBatch, pids, target):
-        from .basic import compact_batch
-        keep = (pids == target) & batch.row_mask()
-        return compact_batch(self.xp, batch, keep)
+    def _split_all(self, batch: ColumnarBatch, pids):
+        """One map output to all of its pieces in one pass: the rows are
+        ordered by target, each target's in their original order (dead
+        rows last), so every array is gathered ONCE; piece ``t`` is rows
+        ``[sum(counts[:t]), sum(counts[:t + 1]))`` of the result.  With it
+        the int32 row count of every piece, for the host's one read."""
+        from ...ops.join import partition_indices
+        xp, nt = self.xp, self.num_partitions()
+        live = batch.row_mask()     # of the input and, ordered, of the output
+        perm, counts = partition_indices(
+            xp, xp.where(live, pids, nt), nt + 1)
+        cols = tuple(c.gather(perm, live) for c in batch.columns)
+        return (ColumnarBatch(batch.names, cols, batch.num_rows),
+                counts[:nt])
+
+    def _map_all(self, batch: ColumnarBatch, map_id):
+        """Partition ids and :meth:`_split_all` in one trace, so the
+        partitioner's hash (a Pallas call on the chip) is traced once per
+        key and input shape, not once per map."""
+        ctx = EvalContext(batch, xp=self.xp)
+        return self._split_all(
+            batch, self.partitioning.partition_ids(ctx, batch, map_id))
+
+    # --- map side: one output to its pieces -------------------------------
+    def _map_declined(self) -> str:
+        """Why a map output cannot go through the map program, or ""."""
+        if isinstance(self.partitioning, RangePartitioning):
+            # the bounds are data of this materialization, read by the
+            # partitioner from its own state and looped over on the host
+            return "range"
+        return "" if self.backend == TPU else "numpy"
+
+    def _split_map_output(self, merged: ColumnarBatch, cpid: int
+                          ) -> List[Optional[ColumnarBatch]]:
+        """The pieces of one map output, by target, each at its row
+        count's bucket and carrying its host-known count; ``None`` for a
+        target that got no row.  Two launches: the map program, then the
+        program that cuts the pieces (off the TPU backend the same
+        functions run un-jitted)."""
+        nt = self.num_partitions()
+        declined = self._map_declined()
+        why = {"declined": declined} if declined else {}
+        STATS["eager_fallbacks" if declined else "map_programs"] += 1
+        part = self.partitioning
+        with _trace.span("shuffle", "exchange.partition_ids", map=cpid,
+                         **why):
+            if declined == "range":
+                pids = part.partition_ids(
+                    EvalContext(merged, xp=self.xp), merged, cpid)
+                ordered, counts = self._jit(
+                    self._split_all, key=("split", nt))(merged, pids)
+            else:
+                ordered, counts = self._jit(self._map_all, key=(
+                    "map", type(part).__name__,
+                    exprs_key(part.exprs)
+                    if isinstance(part, HashPartitioning) else (), nt))(
+                        merged, np.int32(cpid))
+        with _trace.span("shuffle", "exchange.split", map=cpid,
+                         partitions=nt, **why):
+            return self._cut_pieces(ordered, counts)
+
+    def _cut_pieces(self, ordered: ColumnarBatch, counts
+                    ) -> List[Optional[ColumnarBatch]]:
+        """The pieces out of a target-ordered map output, in one program
+        keyed by the capacities it cuts to (``shrunk()``'s rule: the row
+        count's bucket, powers of two, so a dataset makes few keys)."""
+        xp = self.xp
+        host_counts = np.asarray(counts).tolist()    # the map's one read
+        caps = tuple(ordered.shrunk_capacity(n) if n else 0
+                     for n in host_counts)
+        todo = [t for t, c in enumerate(caps) if c]
+        out: List[Optional[ColumnarBatch]] = [None] * len(caps)
+        if not todo:
+            return out
+
+        def cut(batch, ns):
+            starts = xp.cumsum(ns, dtype=xp.int32) - ns
+            return tuple(batch.window(starts[t], ns[t], caps[t], xp)
+                         for t in todo)
+
+        pieces = self._jit(cut, key=("shrink", ordered.capacity, caps))(
+            ordered, counts)
+        if self.backend == TPU:
+            STATS["shrink_programs"] += 1
+        for t, p in zip(todo, pieces):
+            out[t] = p.with_known_rows(host_counts[t])
+        return out
 
     # --- materialization --------------------------------------------------
     def _ensure_materialized(self, tctx: TaskContext):
@@ -131,8 +232,9 @@ class ShuffleExchangeExec(PhysicalPlan):
             ctctx = TaskContext(cpid, tctx.conf, parent=tctx)
             with ctctx.as_current():
                 got = list(child.execute(cpid, ctctx))
-            map_out.append(ColumnarBatch.concat(got) if len(got) > 1
-                           else (got[0] if got else None))
+            with _counting_concats():
+                map_out.append(ColumnarBatch.concat(got) if len(got) > 1
+                               else (got[0] if got else None))
 
         if self.map_side_filter is not None:
             map_out = [self.map_side_filter(b) if b is not None else None
@@ -180,19 +282,17 @@ class ShuffleExchangeExec(PhysicalPlan):
         # slice runs the same plan, so num_maps agrees — docs/distributed)
         map_base = topo.slice_id * num_maps if multi else 0
 
+        #: one piece of every map: what the reduce side will merge
+        samples: List[ColumnarBatch] = []
+
         def _write_map(cpid: int, merged: ColumnarBatch) -> None:
             if nt == 1 or coalesce:
                 pieces: List[Optional[ColumnarBatch]] = [merged]
             else:
-                ctx = EvalContext(merged, xp=self.xp)
-                with _trace.span("shuffle", "exchange.partition_ids",
-                                 map=cpid):
-                    pids = self.partitioning.partition_ids(ctx, merged,
-                                                           cpid)
-                with _trace.span("shuffle", "exchange.split", map=cpid,
-                                 partitions=nt):
-                    pieces = [self._split_fn(merged, pids, t).shrunk()
-                              for t in range(nt)]
+                pieces = self._split_map_output(merged, cpid)
+            live = next((p for p in pieces if p is not None), None)
+            if live is not None:
+                samples.append(live)
             with _trace.span("shuffle", "exchange.write", map=cpid):
                 mgr.write_map_output(shuffle_id, map_base + cpid, pieces)
 
@@ -200,6 +300,8 @@ class ShuffleExchangeExec(PhysicalPlan):
             if merged is None:
                 continue
             _write_map(cpid, merged)
+        declined = concat_declined(samples) if len(samples) > 1 else ""
+        read_why = {"declined": declined} if declined else {}
 
         # lost-block recompute lineage: the collected map outputs + the
         # bound partitioner (range bounds already fixed above) make the
@@ -228,7 +330,8 @@ class ShuffleExchangeExec(PhysicalPlan):
                     # (published above) over the DCN transport
                     out.append([])
                     continue
-                with _trace.span("shuffle", "exchange.read", partition=t):
+                with _trace.span("shuffle", "exchange.read", partition=t,
+                                 **read_why), _counting_concats():
                     got = mgr.read_reduce_partition(shuffle_id, total_maps,
                                                     t)
                 out.append([got] if got is not None else [])
@@ -367,11 +470,8 @@ class ShuffleExchangeExec(PhysicalPlan):
         for d, b in enumerate(out):
             if b.num_rows_int == 0:
                 continue
-            ctx = EvalContext(b, xp=self.xp)
-            full = self.partitioning.partition_ids(ctx, b, d)
-            for t in range(d, nt, n_dev):
-                piece = self._split_fn(b, full, t).shrunk()
-                if piece.num_rows_int > 0:
+            for t, piece in enumerate(self._split_map_output(b, d)):
+                if piece is not None:
                     mat[t].append(piece)
         self._materialized = mat
         return True

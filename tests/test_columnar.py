@@ -151,6 +151,191 @@ def test_object_column_concat_and_repad():
 
 
 # ---------------------------------------------------------------------------
+# concat as one cached program
+# ---------------------------------------------------------------------------
+
+def _per_array_concat(batches):
+    """The per-array path (the fallback), whatever the columns are."""
+    from spark_rapids_tpu.columnar import batch as B
+    live = [b for b in batches if b.num_rows_int > 0]
+    counts = [b.num_rows_int for b in live]
+    rows = B._EagerRows(counts, bucket_capacity(sum(counts)))
+    cols = [B._concat_columns([b.columns[ci] for b in live], rows)
+            for ci in range(live[0].num_cols)]
+    return ColumnarBatch.make(live[0].names, cols, sum(counts))
+
+
+def _same_arrays(a: ColumnarBatch, b: ColumnarBatch):
+    """Leaf for leaf the same bits: live rows, fills and padding."""
+    import jax
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+def _plain(table, capacity=None):
+    """On the device with no column dict-encoded (short tables encode
+    every string otherwise, each upload over a dictionary of its own)."""
+    from spark_rapids_tpu.config import RapidsConf
+    return arrow_to_device(table, capacity, conf=RapidsConf(
+        {"spark.rapids.tpu.sql.encoded.enabled": False}))
+
+
+def _mixed_table(n, wide=False):
+    return pa.table({
+        "i": pa.array([None if k % 5 == 0 else k for k in range(n)],
+                      type=pa.int64()),
+        "f": pa.array([k / 7 for k in range(n)], type=pa.float64()),
+        "s": pa.array([None if k % 3 == 0 else
+                       ("w" * 40 if wide else "s") + str(k)
+                       for k in range(n)], type=pa.string()),
+        "d": pa.array([decimal.Decimal(k) * 10**20 for k in range(n)],
+                      type=pa.decimal128(38, 2)),
+        "st": pa.array([{"a": k, "b": f"x{k}"} for k in range(n)],
+                       type=pa.struct([("a", pa.int64()),
+                                       ("b", pa.string())])),
+    })
+
+
+@pytest.mark.parametrize("case", [
+    "zero_row_pieces", "one_live_piece", "fills_the_bucket",
+    "string_widths_differ", "piece_capacities_differ"])
+def test_concat_program_matches_the_per_array_path(case):
+    from spark_rapids_tpu.columnar import batch as B
+    if case == "zero_row_pieces":
+        whole = _plain(_mixed_table(300))
+        pieces = [whole.sliced(0, 0), whole.sliced(0, 120),
+                  whole.sliced(120, 0), whole.sliced(120, 180)]
+    elif case == "one_live_piece":
+        whole = _plain(_mixed_table(50))
+        pieces = [whole.sliced(0, 0), whole, whole.sliced(50, 0)]
+    elif case == "fills_the_bucket":
+        whole = _plain(_mixed_table(256))
+        pieces = [whole.sliced(0, 100), whole.sliced(100, 28),
+                  whole.sliced(128, 128)]
+    elif case == "string_widths_differ":
+        pieces = [_plain(_mixed_table(40)),
+                  _plain(_mixed_table(30, wide=True)),
+                  _plain(_mixed_table(20))]
+        assert len({p.column("s").width for p in pieces}) == 2
+    else:
+        pieces = [_plain(_mixed_table(10), capacity=64),
+                  _plain(_mixed_table(33)),
+                  _plain(_mixed_table(7), capacity=8)]
+    assert B.concat_declined(pieces) == ""
+    before = dict(B.CONCAT_STATS)
+    out = ColumnarBatch.concat(pieces)
+    live = [p for p in pieces if p.num_rows_int]
+    if len(live) == 1:
+        assert out is live[0]                   # no launch, no copy
+        assert B.CONCAT_STATS == before
+        return
+    assert B.CONCAT_STATS["programs"] == before["programs"] + 1
+    assert B.CONCAT_STATS["eager"] == before["eager"]
+    expected = _per_array_concat(pieces)
+    assert out._nrows_host == expected.num_rows_int == int(out.num_rows)
+    if case == "fills_the_bucket":
+        assert out.capacity == out.num_rows_int == 256
+    if case == "string_widths_differ":    # re-aligned, never truncated
+        assert out.column("s").width == max(p.column("s").width
+                                            for p in pieces)
+    _same_arrays(out, expected)
+    assert device_to_arrow(out).equals(
+        pa.concat_tables([device_to_arrow(p) for p in live]))
+
+
+def test_concat_program_aligns_array_slot_widths():
+    """Array columns of different slot widths: the children are re-laid
+    to the widest inside the program, as the per-array path does."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.columnar import batch as B
+    from spark_rapids_tpu.columnar.column import (make_array_column,
+                                                  make_fixed_column)
+
+    def arrays(rows, width, cap=8):
+        flat = np.zeros(cap * width, dtype=np.int64)
+        lengths = np.zeros(cap, dtype=np.int32)
+        for r, vals in enumerate(rows):
+            flat[r * width:r * width + len(vals)] = vals
+            lengths[r] = len(vals)
+        valid = (np.arange(cap * width) % width) < np.repeat(lengths, width)
+        col = make_array_column(
+            T.ArrayType(T.LONG), jnp.asarray(lengths),
+            (make_fixed_column(T.LONG, jnp.asarray(flat),
+                               jnp.asarray(valid)),),
+            jnp.asarray(np.arange(cap) < len(rows)))
+        return ColumnarBatch.make(["a"], [col], len(rows))
+
+    pieces = [arrays([[1], [2, 3]], 4), arrays([[4, 5, 6, 7, 8, 9]], 8),
+              arrays([[], [10]], 4)]
+    assert B.concat_declined(pieces) == ""
+    out = ColumnarBatch.concat(pieces)
+    assert out.column("a").array_width == 8
+    _same_arrays(out, _per_array_concat(pieces))
+
+
+@pytest.mark.parametrize("kind", ["object", "numpy", "encoded"])
+def test_concat_declines_what_the_program_cannot_express(kind):
+    """Chosen from the columns themselves; the answer is still right."""
+    from spark_rapids_tpu.columnar import batch as B
+    if kind == "object":
+        from spark_rapids_tpu import types as T
+        from spark_rapids_tpu.columnar.column import DeviceColumn
+
+        def host_nested(vals):
+            data = np.empty(8, dtype=object)
+            data[:len(vals)] = vals
+            col = DeviceColumn(T.BINARY, data, np.arange(8) < len(vals))
+            return ColumnarBatch.make(["l"], [col], len(vals))
+        pieces = [host_nested([[1, 2], None]), host_nested([[3], [4, 5]])]
+        out = ColumnarBatch.concat(pieces)
+        assert B.concat_declined(pieces) == kind
+        assert list(out.column("l").data[:4]) == [[1, 2], None, [3], [4, 5]]
+        assert np.asarray(out.column("l").validity).tolist() == \
+            [True] * 4 + [False] * 4
+        return
+    elif kind == "numpy":
+        import jax
+        b = arrow_to_device(pa.table({"x": pa.array(range(10),
+                                                    type=pa.int64())}))
+        host = jax.tree_util.tree_map(np.asarray, b).with_known_rows(10)
+        pieces, want = [host, host], {"x": list(range(10)) * 2}
+    else:
+        pieces = [arrow_to_device(pa.table({"s": [f"{tag}{k % 2}"
+                                                  for k in range(40)]}))
+                  for tag in ("a", "b")]
+        want = {"s": [f"{tag}{k % 2}" for tag in ("a", "b")
+                      for k in range(40)]}
+    assert B.concat_declined(pieces) == kind
+    before = dict(B.CONCAT_STATS)
+    out = ColumnarBatch.concat(pieces)
+    assert B.CONCAT_STATS["eager"] == before["eager"] + 1
+    assert B.CONCAT_STATS["programs"] == before["programs"]
+    assert device_to_arrow(out).to_pydict() == want
+
+
+def test_concat_program_is_keyed_by_shapes_not_by_counts():
+    """The row counts are an operand: other counts in the same buckets
+    launch the same compiled program and trace nothing."""
+    from spark_rapids_tpu.sql.physical import kernel_cache as KC
+    whole = _plain(_mixed_table(120))
+    ColumnarBatch.concat([whole.sliced(0, 50), whole.sliced(50, 60)])
+    stats = KC.cache_stats()
+    out = ColumnarBatch.concat([whole.sliced(0, 40), whole.sliced(70, 50)])
+    after = KC.cache_stats()
+    assert after["retraces"] == stats["retraces"]
+    assert after["misses"] == stats["misses"]
+    assert after["dispatches"] == stats["dispatches"] + 1
+    assert any(name.startswith("srt_ColumnarBatch_concat_")
+               for name in KC.dispatch_stats_by_key())
+    assert device_to_arrow(out).equals(pa.concat_tables(
+        [_mixed_table(120).slice(0, 40), _mixed_table(120).slice(70, 50)]))
+
+
+# ---------------------------------------------------------------------------
 # ragged-string width-class splitting
 # ---------------------------------------------------------------------------
 

@@ -441,3 +441,168 @@ def test_torn_frame_stream_raises():
         split_frames(blob[:-1])          # torn final frame
     with pytest.raises(FrameCorrupt):
         split_frames(blob + b"\x01")     # torn length prefix
+
+
+# --------------------------------------------------------------------------
+# the local plane as cached programs: one per map, one to shrink, one per
+# merge of pieces
+# --------------------------------------------------------------------------
+
+def _map_outputs(attrs, parts):
+    """A leaf exec whose partitions are the device batches handed to it."""
+    from spark_rapids_tpu.sql.physical.base import TPU, PhysicalPlan
+
+    class Leaf(PhysicalPlan):
+        backend = TPU
+        output = attrs
+
+        def num_partitions(self):
+            return len(parts)
+
+        def execute(self, pid, tctx):
+            yield from parts[pid]
+    return Leaf()
+
+
+def _exchange_input(kind, n_maps=4):
+    """Map outputs with int64, double, string and nullable columns; over
+    4,096 rows each, so the pieces shrink.  ``dict``: the string column is
+    low-cardinality (the scan keeps it dict-encoded) and every map draws
+    from other values, so the dictionaries differ."""
+    from spark_rapids_tpu.sql.expressions.core import AttributeReference
+    parts = []
+    for m in range(n_maps):
+        n = 5000 + 700 * m
+        rng = np.random.default_rng(m)
+        if kind == "dict":
+            s = [f"v{m}-{k % 3}" for k in range(n)]
+        else:
+            s = [None if k % 7 == 0 else "s" * (k % 13) + str(k)
+                 for k in range(n)]
+        parts.append([arrow_to_device(pa.table({
+            "i": pa.array([None if k % 11 == 0 else int(v) for k, v in
+                           enumerate(rng.integers(-9999, 9999, n))],
+                          type=pa.int64()),
+            "f": pa.array(rng.random(n), type=pa.float64()),
+            "s": pa.array(s, type=pa.string()),
+        }))])
+    b0 = parts[0][0]
+    attrs = [AttributeReference(n, c.dtype, True)
+             for n, c in zip(b0.names, b0.columns)]
+    return attrs, parts
+
+
+def _old_path_partitions(ex, parts, nt):
+    """What the exchange returned before it ran as programs: an eager
+    partitioner, one stable compaction per target, ``shrunk()`` per
+    piece and the per-array concat that is still the fallback."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.columnar import batch as B
+    from spark_rapids_tpu.sql.expressions.core import EvalContext
+    from spark_rapids_tpu.sql.physical.basic import compact_batch
+    pieces = [[] for _ in range(nt)]
+    for m, (merged,) in enumerate(parts):
+        pids = ex.partitioning.partition_ids(EvalContext(merged), merged, m)
+        for t in range(nt):
+            p = compact_batch(jnp, merged,
+                              (pids == t) & merged.row_mask()).shrunk()
+            if p.num_rows_int:
+                pieces[t].append(p)
+    out = []
+    for ps in pieces:
+        counts = [p.num_rows_int for p in ps]
+        rows = B._EagerRows(counts, B.bucket_capacity(sum(counts)))
+        cols = [B._concat_columns([p.columns[ci] for p in ps], rows)
+                for ci in range(ps[0].num_cols)]
+        out.append(ColumnarBatch.make(ps[0].names, cols, sum(counts)))
+    return out
+
+
+@pytest.mark.parametrize("kind,partitioner", [
+    ("plain", "hash"), ("plain", "roundrobin"), ("dict", "hash")])
+def test_local_exchange_runs_as_programs(sess, kind, partitioner):
+    """4 maps x 4 targets: (a) every reduce partition holds the rows the
+    old path returns, in its order; (b) a second materialization traces
+    nothing; (c) plain columns never take the per-array path, pieces over
+    dictionaries that differ do, and still give the right rows."""
+    from spark_rapids_tpu.parallel.partitioning import (
+        HashPartitioning, RoundRobinPartitioning)
+    from spark_rapids_tpu.sql.physical import exchange as X
+    from spark_rapids_tpu.sql.physical import kernel_cache as KC
+    from spark_rapids_tpu.sql.physical.base import TaskContext
+    nt = 4
+    attrs, parts = _exchange_input(kind)
+    part = (HashPartitioning([attrs[0]], nt) if partitioner == "hash"
+            else RoundRobinPartitioning(nt))
+
+    def materialize():
+        ex = X.ShuffleExchangeExec(part, _map_outputs(attrs, parts),
+                                   coalescible=False)
+        before = dict(X.STATS)
+        got = [list(ex.execute(t, TaskContext(0, sess.conf)))
+               for t in range(nt)]
+        return ex, got, {k: X.STATS[k] - before[k] for k in before}
+
+    ex, got, stats = materialize()
+    assert stats["map_programs"] == stats["shrink_programs"] == 4
+    if kind == "plain":
+        assert stats["eager_fallbacks"] == 0
+        assert stats["concat_programs"] == nt
+    else:
+        assert stats["eager_fallbacks"] == nt       # the four merges
+        assert stats["concat_programs"] == 0
+    expected = _old_path_partitions(ex, parts, nt)
+    for (g,), e in zip(got, expected):
+        assert g.capacity == e.capacity
+        assert g._nrows_host == e.num_rows_int
+        assert device_to_arrow(g).equals(device_to_arrow(e))
+
+    retraces = KC.cache_stats()["retraces"]
+    _, again, stats2 = materialize()
+    assert KC.cache_stats()["retraces"] == retraces
+    assert "wrapped" not in KC.retraces_by_name()
+    assert stats2 == stats
+    for (g,), (a,) in zip(got, again):
+        assert device_to_arrow(g).equals(device_to_arrow(a))
+
+
+def test_one_dictionary_merges_in_the_program(sess):
+    """Pieces dict-encoded over ONE dictionary are no reason to decline:
+    their codes concatenate like any int32 column, the dictionary object
+    is shared and stays outside the program."""
+    from spark_rapids_tpu.columnar import batch as B
+    from spark_rapids_tpu.columnar.encoded import DictEncodedColumn
+    t = pa.table({"k": pa.array(range(6000), type=pa.int64()),
+                  "s": pa.array([f"v{k % 3}" for k in range(6000)])})
+    whole = arrow_to_device(t)
+    assert isinstance(whole.column("s"), DictEncodedColumn)
+    pieces = [whole.sliced(0, 2500), whole.sliced(2500, 3500)]
+    assert B.concat_declined(pieces) == ""
+    before = dict(B.CONCAT_STATS)
+    out = ColumnarBatch.concat(pieces)
+    assert B.CONCAT_STATS["programs"] - before["programs"] == 1
+    assert B.CONCAT_STATS["eager"] == before["eager"]
+    assert out.column("s").dictionary is whole.column("s").dictionary
+    assert device_to_arrow(out).equals(t)
+    from spark_rapids_tpu.memory import retention
+    assert not retention.is_transient(out)   # the dictionary is shared
+
+
+def test_range_exchange_keeps_the_eager_partitioner(sess):
+    """A range exchange's bounds are data of one materialization: its
+    maps keep the eager partitioner and count as fallbacks; the global
+    sort above it is still right."""
+    from spark_rapids_tpu.sql import functions as F
+    from spark_rapids_tpu.sql.physical import exchange as X
+    rng = np.random.default_rng(3)
+    t = pa.table({"k": rng.integers(0, 10**6, 20000),
+                  "v": rng.random(20000)})
+    s = srt.session(**{"spark.sql.shuffle.partitions": 4,
+                       "spark.sql.adaptive.enabled": False})
+    before = dict(X.STATS)
+    out = (s.create_dataframe(t, num_partitions=3).orderBy(F.col("k"))
+           .collect().to_pandas())
+    assert X.STATS["eager_fallbacks"] - before["eager_fallbacks"] >= 3
+    assert X.STATS["map_programs"] == before["map_programs"]
+    assert (np.diff(out["k"].values) >= 0).all() and len(out) == 20000

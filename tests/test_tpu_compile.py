@@ -234,3 +234,99 @@ def test_q1_aggregate_programs_compile_for_v5e(one_chip, monkeypatch):
         assert " sort(" not in text, \
             "q1's dictionary keys are statically compact: no sort fallback"
     assert pallas_calls > 0, "no aggregate program called the Pallas seg_sum"
+
+
+# --------------------------------------------------------------------------
+# the local exchange's three programs, non-CPU branches steered on
+# --------------------------------------------------------------------------
+
+def test_local_exchange_programs_compile_for_v5e(one_chip, monkeypatch):
+    """A 4-map x 8-target hash exchange at Q3's map size (2^18-row
+    batches: int64 key, double, a dict-encoded string) through the exec
+    on the CPU, recording the map, shrink and concat programs the kernel
+    cache builds; each is then lowered for the v5e with the backend
+    answering "tpu", so the Pallas murmur3 call sits INSIDE the map
+    program, as it does on the chip."""
+    import pyarrow as pa
+
+    from spark_rapids_tpu.columnar.convert import arrow_to_device
+    from spark_rapids_tpu.parallel.partitioning import HashPartitioning
+    from spark_rapids_tpu.sql.expressions.core import AttributeReference
+    from spark_rapids_tpu.sql.physical import exchange as X
+    from spark_rapids_tpu.sql.physical import kernel_cache as KC
+    from spark_rapids_tpu.sql.physical.base import (TPU, PhysicalPlan,
+                                                    TaskContext)
+
+    n, nt = 200_000, 8
+    rng = np.random.default_rng(0)
+    flags = np.array(["A", "N", "R"])
+    parts = [[arrow_to_device(pa.table({
+        "k": rng.integers(0, 1 << 40, n),
+        "v": rng.random(n),
+        "s": pa.array(flags[rng.integers(0, 3, n)])}))] for _ in range(4)]
+    b0 = parts[0][0]
+    assert b0.capacity == 1 << 18
+    attrs = [AttributeReference(name, c.dtype, True)
+             for name, c in zip(b0.names, b0.columns)]
+
+    class Leaf(PhysicalPlan):
+        backend = TPU
+        output = attrs
+
+        def num_partitions(self):
+            return len(parts)
+
+        def execute(self, pid, tctx):
+            yield from parts[pid]
+
+    recorded = []
+    real_cached_jit = KC.cached_jit
+
+    def recording_cached_jit(key, fn, donate_argnums=None):
+        inner = real_cached_jit(key, fn, donate_argnums=donate_argnums)
+        if key[1] not in ("map", "shrink", "concat"):
+            return inner
+
+        def call(*args):
+            recorded.append((key[1], fn, args))
+            return inner(*args)
+        return call
+
+    KC.clear_cache()
+    monkeypatch.setattr(KC, "cached_jit", recording_cached_jit)
+    ex = X.ShuffleExchangeExec(HashPartitioning([attrs[0]], nt), Leaf(),
+                               coalescible=False)
+    rows = sum(b.num_rows_int for t in range(nt)
+               for b in ex.execute(t, TaskContext(0)))
+    monkeypatch.undo()
+    KC.clear_cache()
+    assert rows == 4 * n
+    assert {what for what, _, _ in recorded} == {"map", "shrink", "concat"}
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(PK, "_PROBE_OK", {"murmur3": True, "seg_sum": True})
+
+    def abstract(x):
+        if isinstance(x, (jax.Array, np.ndarray, np.generic)):
+            return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                        sharding=one_chip)
+        return x
+
+    seen = set()
+    for what, fn, args in recorded:
+        shapes = jax.tree_util.tree_map(abstract, args)
+        sig = (what, str(jax.tree_util.tree_structure(shapes)),
+               tuple((s.shape, str(s.dtype))
+                     for s in jax.tree_util.tree_leaves(shapes)))
+        if sig in seen:
+            continue
+        seen.add(sig)
+        compiled, text = _compile(lambda *a, fn=fn: fn(*a), *shapes)
+        if what == "map":
+            assert text.count("tpu_custom_call") == 1
+            # one pass: the output is the input re-ordered, not a copy
+            # per target
+            ma = compiled.memory_analysis()
+            assert ma.output_size_in_bytes < 2 * ma.argument_size_in_bytes
+        if what == "concat":
+            assert "dynamic-update-slice" in text and " gather(" not in text

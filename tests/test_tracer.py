@@ -484,6 +484,50 @@ def test_parquet_read_shows_scan_spans(tmp_path):
     assert not any(d[1] <= u[1] < d[2] for d in decode for u in uploads)
 
 
+def test_one_materialization_shows_its_four_phases(tmp_path):
+    """The exchange's phases keep their names now that a map is one
+    program: per map ``partition_ids`` (trace/dispatch of the map
+    program: its one launch lies inside), ``split`` (the count read and
+    the shrink) and ``write``; per reduce partition ``read``, with the
+    concat program's launch inside."""
+    import jax.profiler
+    rng = np.random.default_rng(2)
+    n = 24000
+    t = pa.table({"k": rng.integers(0, 10**9, n), "v": rng.random(n)})
+    sess = srt.session(**{"spark.rapids.tpu.trace.enabled": True,
+                          "spark.sql.adaptive.enabled": False,
+                          "spark.sql.shuffle.partitions": 4})
+    q = (sess.create_dataframe(t, num_partitions=3).groupBy("k")
+         .agg(F.count("*").alias("c")))
+    q.collect()
+    with jax.profiler.trace(str(tmp_path), profiler_options=_no_python()):
+        q.collect()
+    events = [ev for line in _host_lines(str(tmp_path)) for ev in line]
+
+    def named(name):
+        return [ev for ev in events if ev[0] == name]
+
+    (mat,) = named("srt:shuffle:exchange.materialize")
+    pids, split, write, read = (
+        named("srt:shuffle:exchange." + phase)
+        for phase in ("partition_ids", "split", "write", "read"))
+    assert len(pids) == len(split) == len(write) == 3     # one per map
+    assert len(read) == 4                      # one per reduce partition
+    for ev in pids + split + write + read:
+        assert mat[1] <= ev[1] and ev[2] <= mat[2]
+        assert "declined" not in ev[3]
+    launches = [ev for ev in events if ev[0].startswith("srt:dispatch:")]
+
+    def inside(spans, program):
+        return [sum(s[1] <= d[1] and d[2] <= s[2] and program in d[0]
+                    for d in launches) for s in spans]
+
+    assert inside(pids, "ShuffleExchangeExec_map_") == [1, 1, 1]
+    assert inside(split, "ShuffleExchangeExec_shrink_") == [1, 1, 1]
+    assert inside(read, "ColumnarBatch_concat_") == [1, 1, 1, 1]
+    assert inside([mat], "ShuffleExchangeExec_split_") == [0]
+
+
 # --------------------------------------------------------------------------
 # program names and the retrace counters
 # --------------------------------------------------------------------------

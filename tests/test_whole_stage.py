@@ -272,6 +272,63 @@ def test_donated_batch_never_reachable_from_retainers():
     assert retention.may_donate(bcast) == (False, "pinned")
 
 
+@pytest.mark.parametrize("pieces", ["fresh", "pinned"])
+def test_concat_program_result_is_a_fresh_sole_owner(pieces):
+    """A multi-piece concat runs as one program into fresh buffers: the
+    result is marked transient whatever retains the pieces, is not
+    pinned, shares no buffer with them, and leaves them usable (the
+    program donates nothing)."""
+    import jax
+    from spark_rapids_tpu.columnar.batch import CONCAT_STATS, ColumnarBatch
+    a, b = _device_batch(64), _device_batch(32)
+    if pieces == "pinned":
+        retention.pin_batch(a)
+        retention.pin_batch(b)
+    programs = CONCAT_STATS["programs"]
+    out = ColumnarBatch.concat([a, b])
+    assert CONCAT_STATS["programs"] == programs + 1
+    assert retention.is_transient(out) and not retention.is_pinned(out)
+    assert retention.may_donate(out)[0]
+    assert not retention.is_transient(a)      # the pieces are as they were
+    ptrs = {leaf.unsafe_buffer_pointer()
+            for leaf in jax.tree_util.tree_leaves((a, b))}
+    assert not ptrs & {leaf.unsafe_buffer_pointer()
+                       for leaf in jax.tree_util.tree_leaves(out)}
+    assert np.asarray(a.columns[0].data).tolist() == list(range(64))
+    assert np.asarray(out.columns[0].data)[:96].tolist() == \
+        list(range(64)) + list(range(32))
+    # one live piece: the piece itself comes back, with its own marks
+    assert ColumnarBatch.concat([a, b.sliced(0, 0)]) is a
+    if pieces == "pinned":
+        retention.unpin_batch(a)
+        retention.unpin_batch(b)
+
+
+def test_materialized_exchange_partitions_are_pinned():
+    """What the reduce side's concat program returns is retained by the
+    exchange and re-served: pinned, so never donated, though transient."""
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.parallel.partitioning import HashPartitioning
+    from spark_rapids_tpu.sql.expressions.core import AttributeReference
+    from spark_rapids_tpu.sql.physical import exchange as X
+    from spark_rapids_tpu.sql.physical.base import TaskContext
+    from spark_rapids_tpu.sql.physical.basic import InMemoryScanExec
+    attrs = [AttributeReference("k", T.LONG, False),
+             AttributeReference("v", T.DOUBLE, True)]
+    fact, n = FACT.select(["k", "v"]), FACT.num_rows
+    halves = [fact.slice(0, n // 2), fact.slice(n // 2)]
+    ex = X.ShuffleExchangeExec(HashPartitioning([attrs[0]], 2),
+                               InMemoryScanExec(attrs, halves),
+                               coalescible=False)
+    before = X.STATS["concat_programs"]
+    served = [b for t in range(2) for b in ex.execute(t, TaskContext(0))]
+    assert X.STATS["concat_programs"] == before + 2
+    assert sum(b.num_rows_int for b in served) == n
+    for b in served:
+        assert retention.is_transient(b)
+        assert retention.may_donate(b) == (False, "pinned")
+
+
 def test_scan_cached_uploads_are_pinned_and_declined():
     """A fused stage directly above an in-memory scan must never donate
     the relation's resident batches."""
