@@ -377,11 +377,16 @@ def mesh_phase(meter: CompileMeter, rows: int, seed: int, n_dev: int,
                      F.sum(lf.q).alias("sq"), F.max(rt.w).alias("w"))
                 .orderBy("k"))
 
+    from spark_rapids_tpu.parallel import placement
     before = dict(M.STATS)
+    copies_before = placement.STATS["cross_chip_copies"]
     M.RECENT_EXCHANGES.clear()
-    sess = srt.session(**{"spark.rapids.shuffle.mode": "ICI"}, **common)
+    # n_dev executors, one chip each (all of the four-chip host; the first
+    # four of a rehearsal's virtual devices): the layout picks the plane
+    sess = srt.session(**{"spark.executor.instances": n_dev}, **common)
     q = query(sess)
     got, rec = _timed_twice(meter, lambda: q.collect().to_pandas())
+    copies = placement.STATS["cross_chip_copies"] - copies_before
     off = _placement(sess, q)
     stats = {k: M.STATS[k] - before[k] for k in M.STATS}
     # what the exchange itself recorded: the four devices' memory right
@@ -390,10 +395,10 @@ def mesh_phase(meter: CompileMeter, rows: int, seed: int, n_dev: int,
     after_exchange = list(M.RECENT_EXCHANGES)
     emit({"phase": "mesh", "plane": "ICI", "fact_rows": rows,
           "devices": n_dev, **rec, "mesh_stats": stats,
+          "cross_chip_copies": copies,
           "after_exchange": after_exchange, "not_on_tpu": off})
 
-    sess2 = srt.session(**{"spark.rapids.shuffle.mode": "MULTITHREADED"},
-                        **common)
+    sess2 = srt.session(**{"spark.executor.instances": 1}, **common)
     q2 = query(sess2)
     mark = dict(M.STATS)
     want, rec2 = _timed_twice(meter, lambda: q2.collect().to_pandas())
@@ -420,8 +425,11 @@ def mesh_phase(meter: CompileMeter, rows: int, seed: int, n_dev: int,
         "the local-plane run rode the mesh"
     assert not off, f"operators off the TPU: {off}"
     assert len(after_exchange) == stats["mesh_exchanges"], after_exchange
+    assert copies == 0, f"{copies} batches copied from chip to chip"
     for snap in after_exchange:
         assert len(snap["program_outputs_live_on"]) == n_dev, snap
+        assert len(snap["batches_handed_on_live_on"]) == n_dev, \
+            f"a chip was handed nothing: {snap}"
         if on_tpu:
             assert all(b and b > 0 for b in snap["bytes_in_use"]), \
                 f"a device held nothing after the exchange: {snap}"

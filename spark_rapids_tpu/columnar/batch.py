@@ -178,10 +178,12 @@ class ColumnarBatch:
         n = self.num_rows_int
         length = max(0, min(length, n - start))
         cap = bucket_capacity(length)
-        idx = jnp.arange(cap, dtype=jnp.int32) + start
-        valid = jnp.arange(cap, dtype=jnp.int32) < length
-        cols = tuple(c.gather(idx, valid) for c in self.columns)
-        return ColumnarBatch.make(self.names, cols, length)
+        from ..parallel import placement
+        with placement.beside(self.columns):
+            idx = jnp.arange(cap, dtype=jnp.int32) + start
+            valid = jnp.arange(cap, dtype=jnp.int32) < length
+            cols = tuple(c.gather(idx, valid) for c in self.columns)
+            return ColumnarBatch.make(self.names, cols, length)
 
     def gather(self, idx: jnp.ndarray, idx_valid: Optional[jnp.ndarray],
                out_rows) -> "ColumnarBatch":
@@ -200,6 +202,10 @@ class ColumnarBatch:
         batches = [b for b in batches if b.num_rows_int > 0] or list(batches[:1])
         if len(batches) == 1:
             return batches[0]
+        from ..parallel import placement
+        # pieces of several chips: a caller that gathers by its nature has
+        # brought them together already; anything else is counted
+        batches = placement.gather(batches, terminal=False)
         counts = [b.num_rows_int for b in batches]
         total = sum(counts)
         cap = bucket_capacity(total)

@@ -213,6 +213,16 @@ SESSION_TIMEZONE = register(
     "mirroring the reference's UTC-only timezone check).", "UTC")
 SHUFFLE_PARTITIONS = register(
     "spark.sql.shuffle.partitions", "Default shuffle partition count.", 8)
+EXECUTOR_INSTANCES = register(
+    "spark.executor.instances",
+    "Spark's own key: how many executors the application runs.  This "
+    "process is one host; with n > 1 on a host that shows at least n "
+    "chips it is n executors, one chip each: partition t of a relation "
+    "and reduce partition t of an exchange live on chip t % n, a task "
+    "runs on its partition's chip, and an exchange between them is one "
+    "all_to_all program over the chips' interconnect "
+    "(parallel/placement.py, docs/distributed.md).  1 (default) or a "
+    "host with fewer chips: one executor on chip 0.", 1)
 AUTO_BROADCAST_THRESHOLD = register(
     "spark.rapids.sql.autoBroadcastJoinThreshold",
     "Maximum build-side size in bytes for which an equi-join uses a "

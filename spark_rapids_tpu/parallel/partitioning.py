@@ -105,12 +105,27 @@ class RangePartitioning(Partitioning):
         key_cols = [o.child.eval(ctx) for o in self.orders]
         nb = self._bounds_batch.num_rows_int
         pid_out = xp.zeros(batch.capacity, dtype=xp.int32)
+        # a string key's byte matrix and its bounds' at one width: the
+        # bounds come from a sample of every map output, this batch from
+        # one of them
+        bounds = list(self._bounds_batch.columns[:len(self.orders)])
+        for ci, bc in enumerate(bounds):
+            col = key_cols[ci]
+            if bc.data is None or bc.data.ndim < 2 \
+                    or bc.data.shape[1] == col.data.shape[1]:
+                continue
+            w = max(int(bc.data.shape[1]), int(col.data.shape[1]))
+            key_cols[ci], bounds[ci] = (
+                DeviceColumn(c.dtype, xp.pad(
+                    c.data, ((0, 0), (0, w - int(c.data.shape[1])))),
+                    c.validity, c.lengths, c.aux, c.children)
+                for c in (col, bc))
         for b in range(nb):
             gt = xp.zeros(batch.capacity, dtype=bool)
             decided = xp.zeros(batch.capacity, dtype=bool)
             for ci, o in enumerate(self.orders):
                 col = key_cols[ci]
-                bc = self._bounds_batch.columns[ci]
+                bc = bounds[ci]
                 bval = DeviceColumn(
                     bc.dtype,
                     None if bc.data is None else

@@ -6,13 +6,16 @@ host round-trips.
 
 Mechanics (static shapes throughout):
 
-* each shard buckets its rows by target chip and packs them into a
-  ``[n_dev, quota]`` tile (quota = local capacity, the worst case of every
-  row routing to one target);
+* each shard orders its rows by target chip with the local plane's
+  partition pass (``ops/join.py:partition_indices``: a cumsum per target,
+  no sort), gathers every array ONCE into that order, and cuts the
+  ``[n_dev, quota]`` tile out of it with contiguous slices (quota = local
+  capacity, the worst case of every row routing to one target);
 * one tiled ``all_to_all`` flips the tile axis: row-block t of shard s
-  lands on shard t as block s;
-* the receiver compacts the ``n_dev * quota`` candidate rows (valid-mask
-  argsort) back into a single local batch.
+  lands on shard t as block s; the row counts travel the same way;
+* the receiver writes block after block at the running offset of the rows
+  before it (contiguous copies again), so its rows come out at the front,
+  in source order, and everything past them zeroed.
 
 Works for any pytree of row-major arrays (1-D fixed columns, 2-D byte
 matrices), which is exactly the device column layout.
@@ -20,18 +23,23 @@ matrices), which is exactly the device column layout.
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Dict, Tuple
+from typing import Dict
 
 import numpy as np
 
 
-def build_ici_shuffle(mesh, axis_name: str, n_dev: int, quota: int):
+def build_ici_shuffle(mesh, axis_name: str, n_dev: int, quota: int,
+                      with_counts: bool = False):
     """Returns a function usable inside shard_map:
     (arrays: dict[str, [rows(,k)]], valid: [rows], pids: [rows]) ->
-    (arrays received, valid received) with capacity n_dev*quota."""
+    (arrays received, valid received) with capacity n_dev*quota; the
+    received rows lie at the front (``valid received`` is a prefix).
+    ``with_counts`` adds a third result, int32[n_dev]: the rows received
+    from each shard (the counts the exchange moves anyway)."""
     import jax
     import jax.numpy as jnp
+
+    from ..ops.join import partition_indices
 
     def exchange(arrays: Dict[str, "jnp.ndarray"], valid, pids):
         rows = valid.shape[0]
@@ -40,43 +48,38 @@ def build_ici_shuffle(mesh, axis_name: str, n_dev: int, quota: int):
             raise ValueError(
                 f"ici shuffle quota {quota} < shard rows {rows}: a skewed "
                 "bucket would overflow; size quota to the shard capacity")
-        # rank rows within their target bucket (stable order); int64 key —
-        # int32 would overflow at large shard*device counts
-        order = jnp.argsort(
-            jnp.where(valid, pids, n_dev).astype(jnp.int64) * (rows + 1)
-            + jnp.arange(rows, dtype=jnp.int64), stable=True)
-        pids_s = pids[order]
-        valid_s = valid[order]
-        # position within bucket
-        same = jnp.concatenate(
-            [jnp.zeros(1, bool), pids_s[1:] == pids_s[:-1]])
-        seg_pos = jnp.arange(rows) - jax.lax.associative_scan(
-            jnp.maximum,
-            jnp.where(~same, jnp.arange(rows), -1))
-        # scatter each row into tile [n_dev, quota]
-        slot = jnp.where(valid_s & (seg_pos < quota),
-                         pids_s.astype(jnp.int32) * quota + seg_pos,
-                         n_dev * quota)  # trash slot
+        # rows ordered by target chip, each chip's in their original
+        # order, dead rows last: the partition pass of the local plane
+        perm, counts = partition_indices(
+            jnp, jnp.where(valid, pids, n_dev).astype(jnp.int32), n_dev + 1)
+        sent = counts[:n_dev]
+        starts = jnp.cumsum(sent, dtype=jnp.int32) - sent
+        got = jax.lax.all_to_all(sent, axis_name, 0, 0, tiled=True)
+        offsets = jnp.cumsum(got, dtype=jnp.int32) - got
+        rvalid = jnp.arange(n_dev * quota, dtype=jnp.int32) < jnp.sum(got)
 
-        def pack(a):
-            a_s = a[order]
-            shape = (n_dev * quota + 1,) + a.shape[1:]
-            out = jnp.zeros(shape, dtype=a.dtype)
-            return out.at[slot].set(a_s)[:-1].reshape(
-                (n_dev, quota) + a.shape[1:])
+        def route(a):
+            tail = a.shape[1:]
+            # room past the end: a block cut or written at its offset
+            # never clamps
+            ordered = jnp.concatenate(
+                [a[perm], jnp.zeros((quota,) + tail, a.dtype)])
+            tile = jnp.stack([
+                jax.lax.dynamic_slice_in_dim(ordered, starts[t], quota)
+                for t in range(n_dev)])
+            recv = jax.lax.all_to_all(tile, axis_name, 0, 0, tiled=True)
+            # block s holds ``got[s]`` rows and then rows meant for other
+            # chips: the next block overwrites those, the mask the last
+            buf = jnp.zeros(((n_dev + 1) * quota,) + tail, a.dtype)
+            for s in range(n_dev):
+                buf = jax.lax.dynamic_update_slice_in_dim(
+                    buf, recv[s], offsets[s], axis=0)
+            live = rvalid.reshape((-1,) + (1,) * len(tail))
+            return jnp.where(live, buf[:n_dev * quota],
+                             jnp.zeros((), a.dtype))
 
-        tiles = {k: pack(a) for k, a in arrays.items()}
-        # NB: pack() permutes internally — feed the UNSORTED validity like
-        # every data array (valid_s here would be permuted twice)
-        vtile = pack(valid.astype(jnp.int8)).astype(bool)
-
-        recv = {k: jax.lax.all_to_all(t, axis_name, 0, 0, tiled=True)
-                for k, t in tiles.items()}
-        rvalid = jax.lax.all_to_all(vtile, axis_name, 0, 0, tiled=True)
-
-        out = {k: t.reshape((n_dev * quota,) + t.shape[2:])
-               for k, t in recv.items()}
-        return out, rvalid.reshape(n_dev * quota)
+        out = {k: route(a) for k, a in arrays.items()}
+        return (out, rvalid, got) if with_counts else (out, rvalid)
 
     return exchange
 

@@ -298,8 +298,15 @@ class PhysicalPlan:
             arm_oom_injection(int(tctx.conf.get(TEST_INJECT_RETRY_OOM)),
                               int(tctx.conf.get(TEST_INJECT_SPLIT_OOM)))
             sem.acquire_if_necessary(pid, tctx)
+            where = {}
+            if _trace.TRACING["on"] or _trace.TRACING["profiler"]:
+                from ...parallel import placement
+                chip = placement.home_chip(pid, tctx.conf)
+                if chip is not None:    # several executors on this host
+                    where["device"] = placement.label(chip)
             with np.errstate(all="ignore"), _trace.span(
-                    "task", f"{self.node_name()}:task{pid}", partition=pid):
+                    "task", f"{self.node_name()}:task{pid}", partition=pid,
+                    **where):
                 _drain(self.execute(pid, tctx))
         except BaseException as e:
             failed = True

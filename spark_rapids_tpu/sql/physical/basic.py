@@ -9,6 +9,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ...columnar.batch import ColumnarBatch
+from ...observability import tracer as _trace
 from ...columnar.column import DeviceColumn
 from ... import types as T
 from ..expressions.core import (Alias, AttributeReference, BoundReference,
@@ -58,7 +59,7 @@ class _PendingUpload:
         self.error = None
 
 
-def _cached_upload(table, backend: str, conf=None) -> list:
+def _cached_upload(table, backend: str, conf=None, chip=None) -> list:
     """Decode+pad+upload a pyarrow table once per (table, backend); repeat
     scans of the same in-memory relation reuse the resident batches (the
     engine-side analog of Spark's InMemoryRelation staying cached — and the
@@ -67,7 +68,10 @@ def _cached_upload(table, backend: str, conf=None) -> list:
     must not make every row pay its padded width).  Thread-safe: the
     entry keyed by (table identity, backend, split/encode params) is
     claimed under a lock and built outside it; concurrent scanners of the
-    same relation wait on the builder instead of uploading twice."""
+    same relation wait on the builder instead of uploading twice.
+    ``chip`` (``parallel/placement.py``: the partition's home chip where
+    partitions are spread) is where the batches are uploaded to and kept:
+    it is part of the entry's key."""
     import weakref
     from ...config import RAGGED_STRING_SPLIT_BYTES, RapidsConf
     from ...columnar.convert import arrow_to_device, split_for_upload
@@ -80,7 +84,8 @@ def _cached_upload(table, backend: str, conf=None) -> list:
     # takes effect on already-scanned relations
     from ...columnar.encoded import encode_params
     key = id(table)
-    ck = (backend, thr, encode_params(conf))
+    ck = (backend, thr, encode_params(conf)) + (
+        () if chip is None else (chip.id,))
     with _UPLOAD_LOCK:
         ent = _UPLOAD_CACHE.get(key)
         if ent is None or ent[0]() is not table:
@@ -103,9 +108,21 @@ def _cached_upload(table, backend: str, conf=None) -> list:
             with _UPLOAD_LOCK:
                 return per_backend[ck]
         try:
-            batches = [
-                _to_backend_batch(arrow_to_device(p, conf=conf), backend)
-                for p in split_for_upload(table, conf)]
+            if chip is not None and backend == TPU:
+                import jax
+                from ...parallel import placement
+                # built on the chip, then committed to it (no copy), so
+                # every program over these batches runs there
+                with jax.default_device(chip), _trace.span(
+                        "placement", "upload", chip=placement.label(chip),
+                        bytes=table.nbytes):
+                    batches = [placement.put(arrow_to_device(p, conf=conf),
+                                             chip)
+                               for p in split_for_upload(table, conf)]
+            else:
+                batches = [
+                    _to_backend_batch(arrow_to_device(p, conf=conf), backend)
+                    for p in split_for_upload(table, conf)]
         except BaseException as e:
             # failed build must not wedge waiters or poison the entry
             with _UPLOAD_LOCK:
@@ -148,7 +165,10 @@ class InMemoryScanExec(PhysicalPlan):
         return sum(t.nbytes for t in self._parts)
 
     def execute(self, pid: int, tctx: TaskContext):
-        yield from _cached_upload(self._parts[pid], self.backend, tctx.conf)
+        from ...parallel.placement import home_chip
+        yield from _cached_upload(
+            self._parts[pid], self.backend, tctx.conf,
+            chip=home_chip(pid, tctx.conf) if self.backend == TPU else None)
 
     def simple_string(self):
         return f"{self.node_name()} [{', '.join(a.name for a in self._attrs)}]"

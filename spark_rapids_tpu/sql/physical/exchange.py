@@ -33,6 +33,34 @@ def empty_batch_for(attrs) -> ColumnarBatch:
         T.StructField(a.name, a.dtype, True) for a in attrs)))
 
 
+def _recolumned(batch: ColumnarBatch, columns) -> ColumnarBatch:
+    """``batch`` over other columns of the same rows, its host-known row
+    count kept."""
+    return ColumnarBatch(batch.names, tuple(columns), batch.num_rows
+                         ).with_known_rows(batch.num_rows_int)
+
+
+def _empty_like(batch: ColumnarBatch, chip) -> ColumnarBatch:
+    """A batch of ``batch``'s shape with no rows, on ``chip``: the shard
+    of a chip whose maps produced nothing."""
+    import jax
+    from ...columnar.encoded import DictEncodedColumn
+    from ...parallel import placement
+
+    def zeros(tree):
+        return jax.tree_util.tree_map(
+            lambda x: np.zeros(x.shape, x.dtype), tree)
+
+    # a dictionary is no row data: the chip gets a copy of it as it is
+    cols = tuple(
+        DictEncodedColumn(c.dtype, zeros(c.codes), c.dictionary,
+                          zeros(c.validity))
+        if isinstance(c, DictEncodedColumn) else zeros(c)
+        for c in batch.columns)
+    return ColumnarBatch(batch.names, placement.put(cols, chip),
+                         placement.put(np.int32(0), chip)).with_known_rows(0)
+
+
 #: observability (tests assert on these): the AQE skew-split reader, and
 #: how the local plane launched its work — per map output one ``map``
 #: program (rows ordered by target) and one ``shrink`` program (the pieces
@@ -127,13 +155,9 @@ class ShuffleExchangeExec(PhysicalPlan):
             return "range"
         return "" if self.backend == TPU else "numpy"
 
-    def _split_map_output(self, merged: ColumnarBatch, cpid: int
-                          ) -> List[Optional[ColumnarBatch]]:
-        """The pieces of one map output, by target, each at its row
-        count's bucket and carrying its host-known count; ``None`` for a
-        target that got no row.  Two launches: the map program, then the
-        program that cuts the pieces (off the TPU backend the same
-        functions run un-jitted)."""
+    def _order_map_output(self, merged: ColumnarBatch, cpid: int):
+        """One map output ordered by target, with the pieces' row counts
+        (still on the device): the map program's launch, no read."""
         nt = self.num_partitions()
         declined = self._map_declined()
         why = {"declined": declined} if declined else {}
@@ -144,16 +168,26 @@ class ShuffleExchangeExec(PhysicalPlan):
             if declined == "range":
                 pids = part.partition_ids(
                     EvalContext(merged, xp=self.xp), merged, cpid)
-                ordered, counts = self._jit(
+                return self._jit(
                     self._split_all, key=("split", nt))(merged, pids)
-            else:
-                ordered, counts = self._jit(self._map_all, key=(
-                    "map", type(part).__name__,
-                    exprs_key(part.exprs)
-                    if isinstance(part, HashPartitioning) else (), nt))(
-                        merged, np.int32(cpid))
+            return self._jit(self._map_all, key=(
+                "map", type(part).__name__,
+                exprs_key(part.exprs)
+                if isinstance(part, HashPartitioning) else (), nt))(
+                    merged, np.int32(cpid))
+
+    def _split_map_output(self, merged: ColumnarBatch, cpid: int
+                          ) -> List[Optional[ColumnarBatch]]:
+        """The pieces of one map output, by target, each at its row
+        count's bucket and carrying its host-known count; ``None`` for a
+        target that got no row.  Two launches: the map program, then the
+        program that cuts the pieces (off the TPU backend the same
+        functions run un-jitted)."""
+        ordered, counts = self._order_map_output(merged, cpid)
+        declined = self._map_declined()
+        why = {"declined": declined} if declined else {}
         with _trace.span("shuffle", "exchange.split", map=cpid,
-                         partitions=nt, **why):
+                         partitions=self.num_partitions(), **why):
             return self._cut_pieces(ordered, counts)
 
     def _cut_pieces(self, ordered: ColumnarBatch, counts
@@ -207,10 +241,10 @@ class ShuffleExchangeExec(PhysicalPlan):
         plane); reduce side then fetches + host-concats per partition
         (SURVEY §3.4 write/read paths).
 
-        ICI mode with a live multi-device mesh routes the whole exchange
-        through ONE compiled all_to_all program instead
-        (parallel/mesh.py) — the planned-query analog of the reference's
-        UCX device-direct path."""
+        Where the map outputs lie on several chips (several executors on
+        this host: ``parallel/placement.py``) the whole exchange is ONE
+        compiled all_to_all program instead (parallel/mesh.py) — the
+        planned-query analog of the reference's UCX device-direct path."""
         from ...shuffle import get_shuffle_manager
         child = self.children[0]
         nt = self.num_partitions()
@@ -261,21 +295,52 @@ class ShuffleExchangeExec(PhysicalPlan):
                     <= int(tctx.conf.get(ADAPTIVE_COALESCE_ROWS)))
 
         if isinstance(self.partitioning, RangePartitioning) and not coalesce:
-            self._compute_range_bounds(map_out)
+            self._compute_range_bounds(map_out, tctx)
 
         topo = mgr.topology
         multi = topo is not None and topo.multi_slice
 
-        if (mgr.mode == "ICI" and self.backend == TPU and nt > 1
-                and not coalesce and not multi):
-            # multi-slice MUST take the block path: the mesh plane would
-            # assemble all nt partitions from this slice's maps alone and
-            # publish nothing for the peer slices to pull
-            if self._try_mesh_materialize(map_out, nt):
-                tctx.inc_metric("meshExchanges")
-                self._maybe_skew_split(tctx)
-                return
-            tctx.inc_metric("meshFallbacks")
+        # The layout decides the plane, no switch does: where this host is
+        # several executors (``spark.executor.instances``, one chip each)
+        # the map outputs lie on several chips.  An exchange to ONE
+        # partition gathers by its nature: they come to chip 0 and are
+        # merged there, with no block written or read.  Any other exchange
+        # is one all_to_all program over the chips and leaves reduce
+        # partition t on chip t % n; if the plane declines, that raises —
+        # the wire plane below would bring every map output to one chip
+        # and serialize it (``meshFallbacks``; never more than one in a
+        # collect that ends).  Several slices never get here
+        # (``placement.chips`` is then one chip): the mesh plane would
+        # assemble all nt partitions from this slice's maps alone and
+        # publish nothing for the peer slices to pull.
+        from ...parallel import placement
+        spread = (placement.chips(tctx.conf) if self.backend == TPU
+                  else ())
+        if len(spread) > 1 and (nt == 1 or coalesce):
+            live = placement.gather([b for b in map_out if b is not None],
+                                    spread[0])
+            with _counting_concats():
+                merged = ([ColumnarBatch.concat(live)] if len(live) > 1
+                          else live)
+            self._materialized = [merged] + [[] for _ in range(nt - 1)]
+            return
+        if len(spread) > 1:
+            from ...parallel.mesh import MeshShuffleUnsupported
+            try:
+                self._mesh_materialize(map_out, nt, tctx)
+            except MeshShuffleUnsupported as e:
+                from ...parallel.mesh import STATS as MESH_STATS
+                MESH_STATS["fallbacks"] += 1
+                tctx.inc_metric("meshFallbacks")
+                raise RuntimeError(
+                    f"{self.simple_string()}: the mesh plane declined an "
+                    f"exchange between {len(spread)} executors on their "
+                    f"own chips ({e}); there is no other plane between "
+                    f"chips (spark.executor.instances="
+                    f"{len(spread)})") from e
+            tctx.inc_metric("meshExchanges")
+            self._maybe_skew_split(tctx)
+            return
 
         # multi-slice: namespace map ids per slice so the peer slices'
         # blocks never collide with ours (symmetric deployments: every
@@ -406,77 +471,135 @@ class ShuffleExchangeExec(PhysicalPlan):
     def _empty_batch(self) -> ColumnarBatch:
         return empty_batch_for(self.output)
 
-    def _try_mesh_materialize(self, map_out: List[Optional[ColumnarBatch]],
-                              nt: int) -> bool:
-        """Run the exchange through the compiled mesh all_to_all plane.
-        Returns False (clean fallback to the local plane) when no multi-
-        device mesh exists or the batch layout cannot ride it.
+    def _mesh_pids(self, batch: ColumnarBatch, shard: int, n_dev: int):
+        """Target CHIP of every row of one shard (target ``t`` lives on
+        chip ``t % n_dev``), on the shard's chip: one cached program, or
+        the partitioner's own eager pass where it reads its bounds."""
+        part = self.partitioning
 
-        ``nt`` may exceed the device count when it is a multiple of it:
-        rows route over ICI to their OWNER device (target % n_dev) and
-        each device's received batch splits locally into the `group`
-        partitions it owns — so partition counts no longer have to match
-        the mesh exactly."""
+        def chip_ids(b, map_id):
+            ids = part.partition_ids(EvalContext(b, xp=self.xp), b, map_id)
+            return (ids % n_dev).astype(self.xp.int32)
+
+        if self._map_declined():
+            return chip_ids(batch, shard)
+        return self._jit(chip_ids, key=(
+            "meshpids", type(part).__name__,
+            exprs_key(part.exprs) if isinstance(part, HashPartitioning)
+            else (), self.num_partitions(), n_dev))(batch, np.int32(shard))
+
+    def _mesh_materialize(self, map_out: List[Optional[ColumnarBatch]],
+                          nt: int, tctx: TaskContext) -> None:
+        """The exchange between executors on their own chips: one compiled
+        all_to_all program (``parallel/mesh.py``).  Raises
+        ``MeshShuffleUnsupported`` where the batch layout cannot ride it.
+
+        Rows route over ICI to their OWNER chip (target % n_dev), taken
+        from the chip their map ran on; where a chip owns several targets
+        (``nt`` above the chip count) its received batch is split there,
+        with the local plane's map and shrink programs.  Reduce partition
+        ``t`` is handed on living on chip ``t % n_dev``.  Spans, inside
+        ``srt:shuffle:mesh_exchange``: ``.map`` (what is launched per
+        chip before the collective: merges, partition ids), ``.collective``
+        (the program's launch), ``.counts`` (the exchange's one read) and
+        ``.shrink`` (each chip's shard cut to its row count's bucket)."""
+        from ...columnar.batch import _codes_column, _dict_column
+        from ...columnar.encoded import (DictEncodedColumn, RLEColumn,
+                                         same_dictionary)
+        from ...parallel import placement
         from ...parallel.mesh import (MeshShuffleUnsupported, align_batches,
                                       device_mesh, mesh_shuffle_batches)
-        from ...parallel.partitioning import (HashPartitioning,
-                                              RangePartitioning)
-        mesh = device_mesh(nt)
-        group = 1
-        if mesh is None:
-            import jax
-            nd = len(jax.devices())
-            # content-determined partitionings only: the second-stage
-            # split recomputes partition ids on the RECEIVED batch, which
-            # round-robin (source-position-dependent) cannot survive
-            if (nd >= 2 and nt % nd == 0
-                    and isinstance(self.partitioning,
-                                   (HashPartitioning, RangePartitioning))):
-                mesh = device_mesh(nd)
-                group = nt // nd
-        if mesh is None:
-            return False
-        n_dev = nt // group
+        chips = placement.chips(tctx.conf)
+        n_dev = len(chips)
+        # content-determined partitionings only where a chip owns several
+        # targets: the second-stage split recomputes partition ids on the
+        # RECEIVED batch, which round-robin (source-position-dependent)
+        # cannot survive
+        if nt > n_dev and not isinstance(
+                self.partitioning, (HashPartitioning, RangePartitioning)):
+            raise MeshShuffleUnsupported(
+                f"{type(self.partitioning).__name__} into {nt} partitions "
+                f"on {n_dev} chips")
+        mesh = device_mesh(devices=chips)
 
-        # group map outputs onto the n_dev shards (m -> m % n_dev)
+        # shard d = the map outputs that lie on chip d (m % n_dev where a
+        # map output says nothing of where it lies)
         shard_batches: List[List[ColumnarBatch]] = [[] for _ in range(n_dev)]
         for cpid, b in enumerate(map_out):
-            if b is not None:
-                shard_batches[cpid % n_dev].append(b)
-        merged = [ColumnarBatch.concat(bs) if len(bs) > 1
-                  else (bs[0] if bs else self._empty_batch())
-                  for bs in shard_batches]
-        try:
-            aligned = align_batches(merged)
-            pids = []
-            for i, b in enumerate(aligned):
-                ctx = EvalContext(b, xp=self.xp)
-                p = self.partitioning.partition_ids(ctx, b, i)
-                if group > 1:
-                    p = p % n_dev  # ICI stage routes to the owner device
-                pids.append(p)
-            out = mesh_shuffle_batches(mesh, aligned, pids, n_dev)
-        except MeshShuffleUnsupported:
-            from ...parallel.mesh import STATS
-            STATS["fallbacks"] += 1
-            return False
-        if group == 1:
-            self._materialized = [[b] if b.num_rows_int > 0 else []
-                                  for b in out]
-            return True
-        # second stage: device d owns targets {d, d+n_dev, ...} — split
-        # its received batch by the full partition id, locally
-        mat: List[List[ColumnarBatch]] = [[] for _ in range(nt)]
-        for d, b in enumerate(out):
-            if b.num_rows_int == 0:
-                continue
-            for t, piece in enumerate(self._split_map_output(b, d)):
-                if piece is not None:
-                    mat[t].append(piece)
-        self._materialized = mat
-        return True
+            if b is not None and b.num_rows_int > 0:
+                at = placement.chip_of(b)
+                shard_batches[chips.index(at) if at in chips
+                              else cpid % n_dev].append(b)
+        if not any(shard_batches):
+            self._materialized = [[] for _ in range(nt)]
+            return
+        with _trace.span("shuffle", "mesh_exchange", partitions=nt,
+                         devices=n_dev):
+            with _trace.span("shuffle", "mesh_exchange.map"), \
+                    _counting_concats():
+                merged = [ColumnarBatch.concat(bs) if len(bs) > 1
+                          else (bs[0] if bs else None)
+                          for bs in shard_batches]
+                template = next(b for b in merged if b is not None)
+                merged = [b if b is not None
+                          else _empty_like(template, chips[d])
+                          for d, b in enumerate(merged)]
+                # a dictionary is not row data: where every shard's column
+                # is encoded over one dictionary the codes ride the
+                # exchange and each chip keeps its own copy; any other
+                # encoding is decoded
+                keep = [same_dictionary([b.columns[ci] for b in merged])
+                        for ci in range(template.num_cols)]
+                aligned = align_batches([_recolumned(b, (
+                    c if keep[ci] or not isinstance(
+                        c, (DictEncodedColumn, RLEColumn))
+                    else c.materialized()
+                    for ci, c in enumerate(b.columns))) for b in merged])
+                pids = [self._mesh_pids(b, d, n_dev)
+                        for d, b in enumerate(aligned)]
+                plain = [_recolumned(b, map(_codes_column, b.columns))
+                         for b in aligned]
+            out, record = mesh_shuffle_batches(mesh, plain, pids, n_dev)
+            tctx.inc_metric("meshExchangeBytes", record["exchanged_bytes"])
+            tctx.inc_metric("meshCrossChipBytes", record["sent_bytes"])
+            tctx.inc_metric("meshFallbacks", 0)  # beside meshExchanges
 
-    def _compute_range_bounds(self, map_out: List[Optional[ColumnarBatch]]):
+            # what chip d received, at its row count's bucket, over chip
+            # d's own dictionaries again
+            received: List[Optional[ColumnarBatch]] = []
+            with _trace.span("shuffle", "mesh_exchange.shrink"):
+                for d, b in enumerate(out):
+                    n = b.num_rows_int
+                    if n == 0:
+                        received.append(None)
+                        continue
+                    b = self._cut_pieces(
+                        b, np.asarray([n], dtype=np.int32))[0]
+                    received.append(_recolumned(b, map(
+                        _dict_column, aligned[d].columns, b.columns)))
+        if nt <= n_dev:
+            mat = [[b] if b is not None else [] for b in received[:nt]]
+        else:
+            # second stage: chip d owns targets {d, d+n_dev, ...} — split
+            # what it received by the full partition id, there: every
+            # chip's map program is launched before the first count read
+            mat = [[] for _ in range(nt)]
+            staged = [(d, self._order_map_output(b, d))
+                      for d, b in enumerate(received) if b is not None]
+            for d, (ordered, counts) in staged:
+                with _trace.span("shuffle", "exchange.split", map=d,
+                                 partitions=nt):
+                    pieces = self._cut_pieces(ordered, counts)
+                for t, piece in enumerate(pieces):
+                    if piece is not None:
+                        mat[t].append(piece)
+        record["batches_handed_on_live_on"] = sorted({
+            placement.label(placement.chip_of(b))
+            for part in mat for b in part})
+        self._materialized = mat
+
+    def _compute_range_bounds(self, map_out: List[Optional[ColumnarBatch]],
+                              tctx: TaskContext):
         """Sample the collected map outputs, sort the sample by the orders,
         take quantile rows as bounds (reference
         GpuRangePartitioner.createRangeBounds)."""
@@ -493,6 +616,8 @@ class ShuffleExchangeExec(PhysicalPlan):
         if not samples:
             part.set_bounds(self._empty_batch())
             return
+        from ...parallel import placement
+        samples = placement.gather(samples)     # a sample gathers by nature
         merged = ColumnarBatch.concat(samples) if len(samples) > 1 else samples[0]
         sorter = SortExec(part.orders, self.children[0], self.backend)
         merged = sorter._fn(merged)
@@ -508,6 +633,14 @@ class ShuffleExchangeExec(PhysicalPlan):
         rows = [keys_batch.sliced(i, 1) for i in idxs]
         bounds = ColumnarBatch.concat(rows) if len(rows) > 1 else (
             rows[0] if rows else keys_batch.sliced(0, 0))
+        if self.backend == TPU and len(placement.chips(tctx.conf)) > 1:
+            # every chip's partitioner reads the bounds: through the host
+            # they are committed to no chip and follow their reader
+            import jax
+            from .basic import _to_backend_batch
+            bounds = _to_backend_batch(
+                jax.device_get(bounds), TPU
+            ).with_known_rows(bounds.num_rows_int)
         part.set_bounds(bounds)
 
     def execute(self, pid, tctx):
@@ -535,6 +668,10 @@ class BroadcastExchangeExec(PhysicalPlan):
         super().__init__(child)
         self.backend = backend
         self._cached: Optional[ColumnarBatch] = None
+        #: where partitions are spread over several chips: the copy each
+        #: chip's probe partitions join against (a broadcast crosses
+        #: chips by its nature), with build-side artifacts of its own
+        self._replicas: Dict[object, ColumnarBatch] = {}
         #: parallel consumer partitions race into the first
         #: broadcast_batch; the build must run exactly once
         self._mat_lock = threading.Lock()
@@ -547,10 +684,25 @@ class BroadcastExchangeExec(PhysicalPlan):
         return 1
 
     def broadcast_batch(self, tctx: TaskContext) -> ColumnarBatch:
-        if self._cached is not None:
-            return self._cached
+        from ...parallel import placement
+        chip = (placement.home_chip(tctx.partition_id, tctx.conf)
+                if self.backend == TPU else None)
+        if chip is None:
+            if self._cached is not None:
+                return self._cached
+            with self._mat_lock:
+                return self._broadcast_batch_locked(tctx)
         with self._mat_lock:
-            return self._broadcast_batch_locked(tctx)
+            got = self._replicas.get(chip)
+            if got is None:
+                whole = self._broadcast_batch_locked(tctx)
+                got = placement.move(whole, chip, terminal=True)
+                if got is not whole:
+                    got._join_build_sides = {}
+                    from ...memory import retention as _ret
+                    _ret.pin_batch(got)
+                self._replicas[chip] = got
+            return got
 
     def _broadcast_batch_locked(self, tctx: TaskContext) -> ColumnarBatch:
         if self._cached is None:
@@ -582,6 +734,8 @@ class BroadcastExchangeExec(PhysicalPlan):
                     with ctctx.as_current():
                         batches.extend(
                             self.children[0].execute(cpid, ctctx))
+            from ...parallel import placement
+            batches = placement.gather(batches)
             if not batches:
                 self._cached = empty_batch_for(self.output)
             else:

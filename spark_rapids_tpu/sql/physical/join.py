@@ -19,6 +19,8 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from ... import types as T
 from ...columnar.batch import ColumnarBatch
 from ...observability import tracer as _tracer
@@ -880,9 +882,26 @@ class ShuffledHashJoinExec(BaseJoinExec):
 
         bkey = ("bloomb", m, k, exprs_key(self._bound_bkeys))
         step = self._jit(build_step, key=bkey)
-        bits = xp.zeros(m, dtype=bool)
-        for b in parts:
-            bits = step(bits, b)
+        from ...parallel import placement
+        spread = self.backend == TPU and len(placement.chips(tctx.conf)) > 1
+        if not spread:
+            bits = xp.zeros(m, dtype=bool)
+            for b in parts:
+                bits = step(bits, b)
+        else:
+            # the build partitions lie on several chips: each chip folds
+            # its own into a bitset there, and every chip gets the OR of
+            # them all (a runtime filter is broadcast by its nature)
+            partial: dict = {}
+            for b in parts:
+                chip = placement.chip_of(b)
+                partial[chip] = step(
+                    partial.get(chip, np.zeros(m, dtype=bool)), b)
+            union = self._jit(lambda *bs: xp.stack(bs).any(axis=0),
+                              key=("bloomor", m, len(partial)))
+            bits_on = {chip: union(*(placement.put(p, chip)
+                                     for p in partial.values()))
+                       for chip in placement.chips(tctx.conf)}
 
         # bits is an ARGUMENT, not a closure: the kernel cache shares
         # compiled programs by key across joins, so baking the bitset in
@@ -898,7 +917,8 @@ class ShuffledHashJoinExec(BaseJoinExec):
         filt = self._jit(probe_filter, key=fkey)
 
         def map_filter(batch):
-            out = filt(bits, batch).shrunk()
+            mine = bits_on[placement.chip_of(batch)] if spread else bits
+            out = filt(mine, batch).shrunk()
             B.STATS["probe_rows_in"] += batch.num_rows_int
             B.STATS["probe_rows_kept"] += out.num_rows_int
             tctx.inc_metric("bloomFilteredRows",

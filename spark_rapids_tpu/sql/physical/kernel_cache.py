@@ -418,6 +418,103 @@ def release_compiled_programs() -> None:
     jax.clear_caches()
 
 
+#: one executable a program for every chip of a multi-executor host
+#: (:func:`share_executables`): the chip the persistent cache's keys name
+#: while sharing is on, jax's own key function and cache floor to put
+#: back, and whether the loader re-targets (None: not tried yet)
+_SHARED: Dict[str, Any] = {"first": None, "keyed": None, "floor": None,
+                           "works": None}
+_SHARED_LOCK = threading.Lock()
+
+
+def share_executables(chips) -> Optional[bool]:
+    """One executable a program for all of ``chips`` (the executors' chips
+    of this host; fewer than two: sharing is taken off again and jax's own
+    settings are back).  A stage program is the same on every chip, but
+    jax compiles it once per chip: the device it is assigned to is part of
+    the key of its persistent cache.  While sharing is on, a one-chip
+    program's key names the first chip whichever chip it is for, and every
+    compile is written to the cache, however short: the first chip to need
+    a program compiles it, the others load that executable for themselves
+    (jax hands the cache's loader the real device assignment).  Tried once
+    on a program of one line: where the second chip does not get the right
+    answer on itself, or this jax has no such key function, nothing is
+    changed and each chip compiles its own.  The probe shows that the
+    loader re-targets an executable, not that every custom call in one
+    survives it.  Returns whether sharing is on (None: never asked for)."""
+    first = chips[0] if len(chips) > 1 else None
+    if _SHARED["first"] == first or first is not None \
+            and _SHARED["works"] is False:
+        return _SHARED["works"]     # as it is already: no lock on this path
+    with _SHARED_LOCK:
+        _unshare()
+        if first is None:
+            return _SHARED["works"]
+        try:
+            _share(first)
+            ok = _SHARED["works"] or _retargets(chips[:2])
+            why = "the second chip's answer was wrong or not its own"
+        except Exception as e:  # noqa: BLE001 — whatever jax raises
+            ok, why = False, f"{type(e).__name__}: {e}"
+        _SHARED["works"] = ok
+        if not ok:
+            _unshare()
+            import warnings
+            warnings.warn("an executable compiled for one chip does not "
+                          f"load for another ({why}): every chip compiles "
+                          "its own")
+        return ok
+
+
+def _share(first) -> None:
+    import copy
+
+    import jax
+    import numpy as np
+    from jax._src import compiler
+    from jax._src.lib import xla_client as xc
+    keyed = compiler._get_cache_key
+
+    def chip_agnostic(options, backend, computation, devices, *args, **kw):
+        if devices.size == 1 and devices.flat[0] != first \
+                and devices.flat[0].client is first.client:
+            options = copy.deepcopy(options)
+            options.device_assignment = xc.DeviceAssignment.create(
+                np.array([[first.id]]))
+            devices = np.array([first])
+        return keyed(options, backend, computation, devices, *args, **kw)
+
+    _SHARED.update(
+        first=first, keyed=keyed,
+        floor=jax.config.jax_persistent_cache_min_compile_time_secs)
+    compiler._get_cache_key = chip_agnostic
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
+def _unshare() -> None:
+    if _SHARED["first"] is None:
+        return
+    import jax
+    from jax._src import compiler
+    compiler._get_cache_key = _SHARED["keyed"]
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      _SHARED["floor"])
+    _SHARED.update(first=None, keyed=None, floor=None)
+
+
+def _retargets(two_chips) -> bool:
+    import jax
+    import numpy as np
+
+    def srt_one_executable_probe(x):
+        return x * 3 + 1
+    probe = jax.jit(srt_one_executable_probe)
+    x = np.arange(8, dtype=np.int32)
+    got = [probe(jax.device_put(x, c)) for c in two_chips]
+    return (set(got[1].devices()) == {two_chips[1]}
+            and np.array_equal(np.asarray(got[0]), np.asarray(got[1])))
+
+
 def expr_key(e) -> Tuple:
     """Stable structural key for a bound expression (or SortOrder)."""
     from ..plan import SortOrder

@@ -323,6 +323,9 @@ class TakeOrderedAndProjectExec(PhysicalPlan):
                 tops.append(b.sliced(0, min(self.n, b.num_rows_int)))
         if not tops:
             return
+        # every partition's top rows come to one chip: a limit's nature
+        from ...parallel import placement
+        tops = placement.gather(tops)
         merged = ColumnarBatch.concat(tops) if len(tops) > 1 else tops[0]
         final = self._sort._fn(merged)
         final = final.sliced(0, min(self.n, final.num_rows_int))

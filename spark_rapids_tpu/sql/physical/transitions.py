@@ -114,6 +114,9 @@ class HostToDeviceExec(PhysicalPlan):
         from ...robustness import faults as _faults
 
         from ...memory.retention import mark_transient
+        from ...parallel.placement import home_chip, put
+        # where partitions are spread, to this partition's home chip
+        chip = home_chip(pid, tctx.conf)
 
         def upload(batch):
             nb = batch_nbytes(batch)
@@ -124,7 +127,9 @@ class HostToDeviceExec(PhysicalPlan):
             # consumption of the yielded batch
             with _trace.span("h2d", "HostToDevice.upload", bytes=nb):
                 # fresh single-owner device buffers: donation-eligible
-                return mark_transient(tree_map(jnp.asarray, batch))
+                return mark_transient(
+                    tree_map(jnp.asarray, batch) if chip is None
+                    else put(batch, chip))
 
         it = self.children[0].execute(pid, tctx)
         if bool(tctx.conf.get(TRANSFER_DOUBLE_BUFFER)):

@@ -98,6 +98,11 @@ QUICK_MODULES = {
     # phases run in-process on the CPU — what keeps `chip_smoke.py`
     # starting on the chip between chip runs
     "test_tpu_compile", "test_chip_smoke",
+    # several executors on one host (spark.executor.instances): partitions
+    # on their own chip, stages where their data lies, exchanges over the
+    # all_to_all — against pandas with placement asserted, and the
+    # planned-query mesh tests that ride it
+    "test_mesh_placement", "test_mesh_shuffle",
 }
 
 

@@ -37,11 +37,16 @@ def _run(argv):
 
 @pytest.fixture(scope="module", autouse=True)
 def _fresh_default_session():
-    """The four-chip phase makes sessions with forced-shuffle confs; the
-    next module's bare ``srt.session()`` must not inherit one."""
+    """The four-chip phase makes sessions with forced-shuffle confs and
+    spreads partitions over four devices; the next module's bare
+    ``srt.session()`` must inherit neither."""
     yield
+    from spark_rapids_tpu.memory.device import DeviceManager
+    from spark_rapids_tpu.sql.physical import kernel_cache
     from spark_rapids_tpu.sql.session import TpuSession
     TpuSession._active = None
+    DeviceManager.shutdown()
+    kernel_cache.share_executables(())
 
 
 @pytest.fixture(scope="module")
@@ -146,13 +151,16 @@ def test_four_chip_phase_on_virtual_devices():
     assert ici["mesh_stats"]["fallbacks"] == 0
     assert ici["mesh_stats"]["collective_timeouts"] == 0
     assert ici["not_on_tpu"] == []
+    assert ici["cross_chip_copies"] == 0
     # the exchange's own record: one entry per exchange, outputs over the
-    # four devices, the batches handed on brought to the home device
+    # four devices, the batches handed on living where the exchange left
+    # them
     assert len(ici["after_exchange"]) == ici["mesh_stats"]["mesh_exchanges"]
     for snap in ici["after_exchange"]:
         assert len(snap["bytes_in_use"]) == 4
         assert len(snap["program_outputs_live_on"]) == 4
-        assert snap["batches_handed_on_live_on"] == ["cpu:0"]
+        assert snap["batches_handed_on_live_on"] == [
+            "cpu:0", "cpu:1", "cpu:2", "cpu:3"]
     local = [r for r in recs if r.get("plane") == "local"][0]
     assert local["mesh_exchanges_on_local_plane"] == 0
     assert [r for r in recs if r.get("equal")] and \

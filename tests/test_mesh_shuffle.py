@@ -14,11 +14,26 @@ import spark_rapids_tpu as srt
 from spark_rapids_tpu.parallel import mesh as M
 from spark_rapids_tpu.sql import functions as F
 
-ICI_CONF = {"spark.rapids.shuffle.mode": "ICI",
+#: eight executors on the eight virtual devices: the layout, not a shuffle
+#: mode, puts every exchange on the mesh plane (parallel/placement.py)
+ICI_CONF = {"spark.executor.instances": 8,
             "spark.sql.shuffle.partitions": 8,
             # small test shapes must still exercise the mesh data plane
             # (AQE would rightly coalesce them to one partition)
             "spark.sql.adaptive.coalescePartitions.minRows": 0}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _one_executor_afterwards():
+    """The next module's bare ``srt.session()`` must not inherit eight
+    executors, nor the process a spread layout."""
+    yield
+    from spark_rapids_tpu.memory.device import DeviceManager
+    from spark_rapids_tpu.sql.physical import kernel_cache
+    from spark_rapids_tpu.sql.session import TpuSession
+    TpuSession._active = None
+    DeviceManager.shutdown()
+    kernel_cache.share_executables(())
 
 
 @pytest.fixture()
@@ -136,6 +151,7 @@ def tpcds_rig():
     return ST, t, sess
 
 
+@pytest.mark.slow    # 16-57 s each on the CPU: the TPC-DS rig and its joins
 @pytest.mark.parametrize("qname", ["tpcds_q3_star_join",
                                    "tpcds_q19_brand_rev",
                                    "tpcds_q42_cat_rev"])
@@ -202,7 +218,7 @@ def test_mesh_rides_when_partitions_exceed_devices(session):
     import spark_rapids_tpu as srt
     from spark_rapids_tpu.sql import functions as F
     sess = srt.session(**{
-        "spark.rapids.shuffle.mode": "ICI",
+        "spark.executor.instances": 8,
         "spark.sql.shuffle.partitions": 16,
         "spark.sql.adaptive.enabled": False})
     try:
@@ -222,6 +238,6 @@ def test_mesh_rides_when_partitions_exceed_devices(session):
         assert np.array_equal(got["k"].values, exp["k"].values)
         assert np.allclose(got["s"].values, exp["s"].values)
     finally:
-        srt.session(**{"spark.rapids.shuffle.mode": "MULTITHREADED",
+        srt.session(**{"spark.executor.instances": 1,
                        "spark.sql.shuffle.partitions": 8,
                        "spark.sql.adaptive.enabled": True})

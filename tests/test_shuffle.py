@@ -606,3 +606,19 @@ def test_range_exchange_keeps_the_eager_partitioner(sess):
     assert X.STATS["eager_fallbacks"] - before["eager_fallbacks"] >= 3
     assert X.STATS["map_programs"] == before["map_programs"]
     assert (np.diff(out["k"].values) >= 0).all() and len(out) == 20000
+
+
+def test_range_exchange_over_string_keys_of_uneven_width(sess):
+    """The bounds' byte matrix is as wide as the widest key sampled over
+    every map output, a map output's only as wide as its own keys: the
+    partitioner compares them at one width."""
+    from spark_rapids_tpu.sql import functions as F
+    keys = ["a" * (1 + i % 3) if i < 500 else "b" * (1 + i % 20)
+            for i in range(2000)]
+    t = pa.table({"s": keys, "v": np.arange(2000, dtype=np.float64)})
+    s = srt.session(**{"spark.sql.shuffle.partitions": 4,
+                       "spark.sql.adaptive.enabled": False})
+    out = (s.create_dataframe(t, num_partitions=4).orderBy(F.col("s"))
+           .collect().to_pandas())
+    assert list(out["s"]) == sorted(keys)
+    assert sorted(out["v"]) == list(range(2000))

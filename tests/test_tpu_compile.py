@@ -54,8 +54,14 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _compile(fn, *shapes):
-    compiled = jax.jit(fn).lower(*shapes).compile()
+def _compile(fn, *shapes, sortless=None):
+    """``sortless``: why the program must hold no sort — checked on the
+    lowered text BEFORE compiling, because the chip's compiler takes
+    minutes per sort program and a test that met one would only hang."""
+    lowered = jax.jit(fn).lower(*shapes)
+    if sortless:
+        assert "stablehlo.sort" not in lowered.as_text(), sortless
+    compiled = lowered.compile()
     return compiled, compiled.as_text()
 
 
@@ -145,26 +151,38 @@ def test_probe_answers_false_off_tpu_without_trying(monkeypatch):
 # the four-device mesh exchange program
 # --------------------------------------------------------------------------
 
-def test_mesh_exchange_program_compiles_for_four_chips(topo):
-    """The very step ``mesh_shuffle_batches`` jits (all_to_all exchange +
-    on-chip compaction) on a Mesh of the described devices, with an
-    (int64, float64, bool) batch.  2^12 rows per shard keeps the routing
-    argsort under the size where the TPU compiler's sort gets slow (the
-    same program at 2^18 rows per shard compiles too, in ~2 minutes:
-    PERF.md)."""
+@pytest.mark.parametrize("cap,columns", [
+    (1 << 12, "small"),
+    # a shard of the four-chip cell's LINEITEM exchange: four maps of 2^18
+    # rows merged, all 16 columns (tpch-1m-join-q3-mesh4)
+    (1 << 20, "lineitem"),
+])
+def test_mesh_exchange_program_compiles_for_four_chips(topo, cap, columns):
+    """The very step ``mesh_shuffle_batches`` jits (the partition pass,
+    the all_to_all, the received rows at the front) on a Mesh of the
+    described devices.  It holds no sort, so it compiles in seconds at the
+    cell's real size too, and its per-device footprint there must fit one
+    v5e chip beside the resident tables."""
     from jax.sharding import Mesh
     from spark_rapids_tpu.parallel.mesh import exchange_program
-    n_dev, cap = 4, 1 << 12
+    n_dev = 4
     mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",))
     sh = NamedSharding(mesh, P("data"))
 
-    def g(shape, dt):
-        return jax.ShapeDtypeStruct((n_dev * cap,) + shape, dt, sharding=sh)
+    def g(shape, dt, rows=cap):
+        return jax.ShapeDtypeStruct((n_dev * rows,) + shape, dt, sharding=sh)
 
+    if columns == "small":
+        leaves = [g((), jnp.int64), g((), jnp.float64), g((), jnp.bool_)]
+    else:
+        fixed = [jnp.int64] * 3 + [jnp.int32] + [jnp.float64] * 4 \
+            + [jnp.int32] * 7
+        leaves = [g((), dt) for dt in fixed] + [g((), jnp.bool_)] * 16 \
+            + [g((48,), jnp.uint8), g((), jnp.int32)]
     compiled, text = _compile(
-        exchange_program(mesh, n_dev, cap, 3),
-        g((), jnp.bool_), g((), jnp.int32),
-        g((1,), jnp.int64), g((1,), jnp.float64), g((1,), jnp.bool_))
+        exchange_program(mesh, n_dev, cap, len(leaves)),
+        g((), jnp.int32, rows=1), g((), jnp.int32), *leaves,
+        sortless="the pack is a partition pass, not a sort")
     assert "all-to-all" in text
     # per-device footprint must fit one v5e chip with room to spare
     ma = compiled.memory_analysis()
@@ -201,7 +219,11 @@ def test_q1_aggregate_programs_compile_for_v5e(one_chip, monkeypatch):
     KC.clear_cache()
     monkeypatch.setattr(KC, "cached_jit", recording_cached_jit)
     lineitem = ST.build_tpch_tables(20_000)["lineitem"]
-    sess = srt.session()
+    # a session of its own: a bare ``srt.session()`` hands back whatever
+    # the module before left active, and with encoding off q1's string
+    # keys take the sort fallback, which the chip's compiler takes many
+    # minutes over
+    sess = srt.session(**{"spark.rapids.tpu.sql.encoded.enabled": True})
     sess.create_dataframe(lineitem, num_partitions=1) \
         .createOrReplaceTempView("lineitem")
     got = sess.sql(ST._TPCH_Q1_SQL).collect().to_pandas()
@@ -229,10 +251,10 @@ def test_q1_aggregate_programs_compile_for_v5e(one_chip, monkeypatch):
         seen.add(sig)
         # a fresh wrapper: jit caches traces by function identity, and
         # the CPU run already traced ``fn`` down its CPU branches
-        _, text = _compile(lambda *a, fn=fn: fn(*a), *shapes)
+        _, text = _compile(
+            lambda *a, fn=fn: fn(*a), *shapes, sortless="q1's dictionary "
+            "keys are statically compact: no sort fallback")
         pallas_calls += text.count("tpu_custom_call")
-        assert " sort(" not in text, \
-            "q1's dictionary keys are statically compact: no sort fallback"
     assert pallas_calls > 0, "no aggregate program called the Pallas seg_sum"
 
 
@@ -321,7 +343,9 @@ def test_local_exchange_programs_compile_for_v5e(one_chip, monkeypatch):
         if sig in seen:
             continue
         seen.add(sig)
-        compiled, text = _compile(lambda *a, fn=fn: fn(*a), *shapes)
+        compiled, text = _compile(
+            lambda *a, fn=fn: fn(*a), *shapes,
+            sortless="the local exchange orders rows by a partition pass")
         if what == "map":
             assert text.count("tpu_custom_call") == 1
             # one pass: the output is the input re-ordered, not a copy
