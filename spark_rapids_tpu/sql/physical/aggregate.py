@@ -3,7 +3,8 @@
 TPU algorithm — no hash table, all static shapes:
 1. group keys -> exact dense ranks (ops/ranks.py: integer sorts + pair
    densification); the rank IS the segment id;
-2. every aggregate buffer slot scatter-reduces by rank (ops/segmented.py);
+2. every aggregate buffer slot reduces by rank (ops/segmented.py): a dense
+   masked reduction into a small group table, a scatter into a large one;
 3. group key values are gathered from each group's first row;
 4. output batch keeps the input capacity, ``num_rows`` = #groups (traced).
 
@@ -111,13 +112,54 @@ def _use_batched_reduce(xp) -> bool:
     return jax.default_backend() not in ("cpu",)
 
 
+#: largest group table the chip reduces densely (ops/segmented.py); above
+#: it the scatter stays.  From one v5e run at 2^21 rows (PERF.md §6 PR 30):
+#: 7 float64 slots reduce densely in 2.1 / 7.3 / 24 / 48 / 93 / 186 ms into
+#: 8 / 64 / 256 / 512 / 1024 / 2048 groups where the scatter takes 185-196
+#: ms at every size, so 64-bit slots cross over near 2048; 2 int32 slots,
+#: whose scatter is a native 16.6 ms, take 4.2 / 7.6 / 22 ms at 256 / 512 /
+#: 1024 and cross near 800.  256 is well inside both (every dtype measured
+#: wins there by 4x or more) and is where the one-hot matmul's envelope
+#: ends too: it bounds the [groups, rows] predicate, which the compiler
+#: materialises (2 MiB a group at 2^21 rows), to 512 MiB.
+_DENSE_MAX_GROUPS = 256
+
+
+def _use_dense_reduce(xp, n_seg: int) -> bool:
+    """Which form a reduction into an ``n_seg``-row group table takes, from
+    what trace time can see: the platform (XLA CPU keeps its scatters, fast
+    there) and the static table size.  The execs ask the same question on
+    the host to count batches by form."""
+    return n_seg <= _DENSE_MAX_GROUPS and _use_batched_reduce(xp)
+
+
+def reduce_form_metric(xp, n_seg: int) -> str:
+    """The task metric a batch reduced into an ``n_seg``-row table counts
+    under."""
+    return ("aggDenseReduceBatches" if _use_dense_reduce(xp, n_seg)
+            else "aggScatterReduceBatches")
+
+
+def group_table_floor(xp, grouped: bool) -> int:
+    """Smallest group table a device aggregate is sized to.  Grouped
+    aggregates keep a floor so fluctuating group counts share one compiled
+    program (the size is in the program's key; a first compile on the chip
+    is 20-40 s): 64 rows where the table is scattered into, whose cost does
+    not depend on the size, and 8 (one sublane tile) where it is reduced
+    densely, whose cost grows with it.  A global aggregate has exactly one
+    group."""
+    if not grouped:
+        return 1
+    return 8 if _use_dense_reduce(xp, 8) else 64
+
+
 def group_phase(xp, key_cols: Sequence[DeviceColumn], row_mask,
                 expected_groups: Optional[int] = None):
     """Phase A of the two-phase device aggregate: group ids + count.
     Splitting this from the reductions lets the host size the output
-    table to the OBSERVED group count — scatters into a 64-4096-slot
-    table are ~5x cheaper on TPU than capacity-sized ones, and small
-    tables unlock the one-hot-matmul (MXU) reduction path.
+    table to the OBSERVED group count: a small table is reduced into
+    densely (and float32/flag sums by a one-hot matmul on the MXU), which
+    the chip does 25-90x faster than a float64 scatter of the same rows.
 
     ``expected_groups`` (the speculated table size) switches the id
     kernel to a small-table bounded probe whose overflow inflates the
@@ -187,28 +229,31 @@ def groupby_reduce(xp, key_cols: Sequence[DeviceColumn],
     """Core groupby: returns (grouped_key_cols, reduced_slot_cols, n_groups).
     Output arrays are ``out_size``-sized (default: input capacity); group g
     lives at index g.  ``rank64``/``n_groups`` may be precomputed by
-    :func:`group_phase` (two-phase device path); jnp scatters silently drop
-    out-of-bounds dead-row ranks, which is exactly the semantics needed
-    when ``out_size`` < capacity."""
+    :func:`group_phase` (two-phase device path); both reduction forms
+    (ops/segmented.py) silently drop out-of-bounds dead-row ranks, which is
+    exactly the semantics needed when ``out_size`` < capacity."""
     cap = row_mask.shape[0]
-    # int32 indices: TPU int64 is emulated (pairs of int32 ops) — every
-    # 64-bit scatter costs roughly double
+    # int32 ids and row numbers: TPU int64 is emulated (pairs of int32
+    # ops), so every 64-bit compare or scatter costs roughly double
     row_idx = xp.arange(cap, dtype=xp.int32)
     if rank64 is None:
         rank64, n_groups = group_phase(xp, key_cols, row_mask)
     rank = rank64.astype(xp.int32)
     OUT = out_size or cap
+    # one form for every reduction of this program, chosen by table size
+    dense = _use_dense_reduce(xp, OUT)
 
     first_idx = seg_min(xp, xp.where(row_mask, row_idx, cap), rank, OUT,
-                        np.int32(cap))
+                        np.int32(cap), dense=dense)
     first_idx = xp.clip(first_idx, 0, cap - 1).astype(xp.int32)
     group_ok = xp.arange(OUT, dtype=xp.int32) < n_groups
     out_keys = [_gather_col(k, first_idx, group_ok) for k in key_cols]
 
     # Split slots into "simple" (plain 1-D numeric data + batchable op) and
-    # the general path.  Simple slots of one (op-kind, dtype) reduce with a
-    # SINGLE 2-D scatter kernel — s slots per pass instead of 2 scatters per
-    # slot (one kernel launch per op per batch, SURVEY §3.3).
+    # the general path.  Simple slots of one (op-kind, dtype) reduce as ONE
+    # [rows, s] matrix — s slots per pass instead of 2 scatters per slot
+    # (one kernel launch per op per batch, SURVEY §3.3): densely into a
+    # table of at most _DENSE_MAX_GROUPS rows, by a 2-D scatter above.
     from ...ops.segmented import seg_max2, seg_min2, seg_sum2
     n_slots = len(slot_cols)
     out_slots: List = [None] * n_slots
@@ -226,11 +271,14 @@ def groupby_reduce(xp, key_cols: Sequence[DeviceColumn],
             out_slots[i] = r.with_validity(r.validity & group_ok)
 
     # MXU fast path: with a host-sized small group table, additive
-    # reductions become ONE one-hot matmul (f32 accumulation) — an order
-    # of magnitude cheaper than scatter-add on TPU.  ONLY f32 sums (same
-    # error class as any float sum order) and 0/1 FLAG sums bounded by
-    # cap < 2^24 (exact in f32) may ride it; integer SUM data is
-    # arbitrary-magnitude and must stay on the exact scatter path.
+    # reductions become ONE one-hot matmul (f32 accumulation).  ONLY f32
+    # sums (same error class as any float sum order) and 0/1 FLAG sums
+    # bounded by cap < 2^24 (exact in f32) may ride it.  Everything else —
+    # float64 sums (TPC-H's: the chip's double-float pair), integer SUM
+    # data of arbitrary magnitude — keeps its own dtype as the accumulator
+    # and is exact or float64: reduced densely into a small table, and by
+    # the scatter into a large one (on the chip a scatter over a 32-bit
+    # pair, applied one update after another).
     # MXU path only where a matmul engine exists: on XLA CPU the [rows, OUT]
     # one-hot is materialized (no fusion into the GEMM), costing OUT/8 bytes
     # of traffic per row — measured 0.37s vs 0.02s scatter at 1M rows x 64
@@ -259,7 +307,7 @@ def groupby_reduce(xp, key_cols: Sequence[DeviceColumn],
             stacked = xp.stack([c.astype(xp.float32) for c in cols2],
                                axis=1)
             return (onehot.T @ stacked).astype(dt)
-        return seg_sum2(xp, xp.stack(cols2, axis=1), rank, OUT)
+        return seg_sum2(xp, xp.stack(cols2, axis=1), rank, OUT, dense=dense)
 
     if simple:
         contrib_mat = [c.astype(xp.int32) for (_, _, _, c) in simple]
@@ -295,7 +343,7 @@ def groupby_reduce(xp, key_cols: Sequence[DeviceColumn],
                          for (_, _, op, col, contrib) in items]
                 stacked = xp.stack(cols2, axis=1)
                 red = (seg_min2 if is_min else seg_max2)(
-                    xp, stacked, rank, OUT, sent)
+                    xp, stacked, rank, OUT, sent, dense=dense)
             for out_col, (j, i, op, col, contrib) in enumerate(items):
                 if op == COUNT:
                     out_slots[i] = DeviceColumn(
@@ -715,7 +763,7 @@ class HashAggregateExec(PhysicalPlan):
         except SplitAndRetryOOM:
             return None  # memory pressure: take the spillable exact path
         spec_key = self._spec_key
-        minimum = 64 if self.grouping else 1
+        minimum = self._table_floor()
         SPEC.register(spec, ng,
                       lambda ng_host, sk=spec_key, m=minimum:
                       record_speculation(sk, ng_host, m))
@@ -725,7 +773,9 @@ class HashAggregateExec(PhysicalPlan):
         """One input batch -> partial [keys..., slots...].  On the device
         backend this is the two-phase path: group ids first, ONE host sync
         for the observed group count, then reductions into a group table
-        sized to it (5x cheaper scatters; matmul path for small tables).
+        sized to it.  On the chip a table of at most _DENSE_MAX_GROUPS rows
+        (TPC-H Q1: 8, Q6: 1) is reduced into densely, float32 and flag sums
+        by the one-hot matmul; a larger one (Q3: 2^14) by scatters.
         Once a query has observed its group count, later batches SPECULATE
         that size and run group+reduce as ONE program with ONE sync —
         every extra program boundary and sync is a host<->device round
@@ -755,7 +805,8 @@ class HashAggregateExec(PhysicalPlan):
         with _trace.span("sync", "agg.group_count"):
             ng_host = int(ng)
         n = max(ng_host, 1)
-        out_size = min(bucket_capacity(n, minimum=64), batch2.capacity)
+        out_size = min(bucket_capacity(n, minimum=self._table_floor()),
+                       batch2.capacity)
         # max-join: a small tail batch must not clobber the spec a large
         # batch needs (that would make every later large batch
         # mis-speculate and execute twice, forever)
@@ -770,6 +821,9 @@ class HashAggregateExec(PhysicalPlan):
         # so downstream num_rows_int (spill registration, sort sizing)
         # doesn't pay another device sync
         return out.with_known_rows(ng_host)
+
+    def _table_floor(self) -> int:
+        return group_table_floor(self.xp, bool(self.grouping))
 
     def _merge_finalize_fn(self):
         if getattr(self, "_mf_jit", None) is None:
@@ -838,13 +892,14 @@ class HashAggregateExec(PhysicalPlan):
 
     _finalize_jit = None
 
-    def _merge_spillables(self, spillables, fanin=8):
+    def _merge_spillables(self, spillables, fanin=8, tctx=None):
         """Tree-merge partial layouts under the retry framework, bounding
         peak device residency to ``fanin`` batches per attempt — the TPU
         answer to the reference's incremental merge with sort/repartition
         fallbacks (``aggregate.scala:711-792``).  A SplitAndRetryOOM halves
         the failing group (or the batch itself when the group is one batch),
-        so recovery degrades gracefully down to two-row merges."""
+        so recovery degrades gracefully down to two-row merges.  With a
+        ``tctx`` (merge mode) every pass counts under its reduction form."""
         from ...memory.retry import split_spillable_in_half, with_retry
         from ...memory.spill import (ACTIVE_BATCHING_PRIORITY,
                                      SpillableColumnarBatch)
@@ -865,6 +920,9 @@ class HashAggregateExec(PhysicalPlan):
             batches = [p.get() for p in g.parts]
             merged = batches[0] if len(batches) == 1 else \
                 ColumnarBatch.concat(batches)
+            if tctx is not None:
+                # a merge reduces into a table as large as its input
+                tctx.inc_metric(reduce_form_metric(self.xp, merged.capacity))
             return self._get_merge_fn()(merged).shrunk()
 
         def split_group(g: "_Group"):
@@ -1020,7 +1078,7 @@ class HashAggregateExec(PhysicalPlan):
             ng0 = int(ng)
             total_groups += max(ng0, 1)
             OUT = min(bucket_capacity(max(ng0, 1),
-                                      minimum=64 if self.grouping else 1),
+                                      minimum=self._table_floor()),
                       batch2.capacity)
             key = ("tdigest-batch", OUT, C, self._partial_key,
                    tuple(f._key_extras() for f in funcs))
@@ -1029,7 +1087,7 @@ class HashAggregateExec(PhysicalPlan):
         big = ColumnarBatch.concat(pseudo)
         # merge: total distinct groups is bounded by the per-batch sum
         OUTM = min(bucket_capacity(max(total_groups, 1),
-                                   minimum=64 if self.grouping else 1),
+                                   minimum=self._table_floor()),
                    big.capacity)
 
         def merge_kernel(bigb):
@@ -1139,11 +1197,10 @@ class HashAggregateExec(PhysicalPlan):
         ng0 = int(ng)  # ONE sync; global aggregates already floored to 1
         maxc = self._max_group_count(self.xp, rank64, mask,
                                      batch2.capacity)
-        # grouped queries keep the 64-group floor so fluctuating group
-        # counts share one compiled program (OUT is in the jit key; TPU
-        # first-compile is 20-40s); the global path sizes exactly
+        # grouped queries keep a floor (group_table_floor); the global
+        # path sizes exactly
         OUT = min(bucket_capacity(max(ng0, 1),
-                                  minimum=64 if self.grouping else 1),
+                                  minimum=self._table_floor()),
                   batch2.capacity)
         widths = {fi: bucket_width(
             max(self._agg_funcs[fi].max_width(maxc), 1))
@@ -1199,7 +1256,8 @@ class HashAggregateExec(PhysicalPlan):
                 # merge-only (the mixed-DISTINCT middle stage): group the
                 # partial layout by its keys, KEEPING slots mergeable —
                 # every (keys...) tuple becomes unique in this partition
-                yield self._merge_spillables(partials).get_and_close()
+                yield self._merge_spillables(partials,
+                                             tctx=tctx).get_and_close()
                 return
             if len(partials) == 1:
                 # single partial (the common post-AQE-coalesce shape):
@@ -1236,6 +1294,8 @@ class HashAggregateExec(PhysicalPlan):
                 fast = self._try_deferred_complete([first])
                 if fast is not None:
                     tctx.inc_metric("aggDeferredComplete")
+                    tctx.inc_metric(reduce_form_metric(self.xp,
+                                                       fast.capacity))
                     yield fast
                     return
             from itertools import chain
@@ -1251,6 +1311,9 @@ class HashAggregateExec(PhysicalPlan):
                                       lambda s: self._run_partial(s.get()),
                                       split=split_spillable_in_half):
                     tctx.inc_metric("aggPartialBatches")
+                    # the partial's capacity is its group-table size
+                    tctx.inc_metric(reduce_form_metric(self.xp,
+                                                       out.capacity))
                     partials.append(SpillableColumnarBatch.create(
                         out.shrunk(), ACTIVE_BATCHING_PRIORITY))
         except BaseException:

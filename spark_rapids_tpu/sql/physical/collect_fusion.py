@@ -28,7 +28,7 @@ import numpy as np
 
 from ...columnar.batch import ColumnarBatch
 from .aggregate import (HashAggregateExec, lookup_speculation,
-                        record_speculation)
+                        record_speculation, reduce_form_metric)
 from .base import CPU, PhysicalPlan
 from .sortlimit import SortExec
 from .transitions import DeviceToHostExec, batch_nbytes
@@ -313,7 +313,7 @@ class FusedCollectExec(PhysicalPlan):
         if not is_final:
             # record/validate the speculation through the standard registry
             # so the session's post-run validation and re-run loop apply
-            minimum = 64 if agg.grouping else 1
+            minimum = agg._table_floor()
             SPEC.register(spec, None,
                           lambda ng, sk=agg._spec_key, m=minimum:
                           record_speculation(sk, ng, m)).resolve(ng_host)
@@ -321,6 +321,10 @@ class FusedCollectExec(PhysicalPlan):
                 return  # wrong result discarded; session re-runs
         STATS["fused_collects"] += 1
         tctx.inc_metric("fusedCollects")
+        if not is_final:
+            # the complete aggregate's one input batch, reduced inside the
+            # tail program into a ``spec``-row table
+            tctx.inc_metric(reduce_form_metric(agg.xp, spec))
         from ...shims import tree_unflatten
         out = tree_unflatten(treedef, leaves[:-1])
         tctx.inc_metric("d2h_bytes", batch_nbytes(out))

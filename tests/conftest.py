@@ -103,6 +103,10 @@ QUICK_MODULES = {
     # all_to_all — against pandas with placement asserted, and the
     # planned-query mesh tests that ride it
     "test_mesh_placement", "test_mesh_shuffle",
+    # the dense form of the group-table reductions (ISSUE 30): what the
+    # chip runs for small tables and no other CPU test executes, against
+    # the scatter form — a wrong sum here is a silent wrong answer
+    "test_dense_group_reduce",
 }
 
 

@@ -13,6 +13,7 @@ them: an AOT TPU entry cannot be read back without a chip.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -87,6 +88,23 @@ def test_seg_sum_kernel_compiles_for_v5e(one_chip, s, out, n):
         jax.ShapeDtypeStruct((s, n), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("s,out", [(7, 64), (1, 1)])
+def test_dense_group_reduce_compiles_for_v5e(one_chip, s, out):
+    """The dense form of a float64 group-table sum at the resident cell's
+    batch size (Q1: 7 slots, Q6: 1): no scatter left in the optimised
+    program — the X64 rewrite turns a float64 scatter-add into a scatter
+    over a float32 pair that applies its updates one after another — and
+    nothing rows x groups x slots wide among its temporaries."""
+    from spark_rapids_tpu.ops import segmented as S
+    n = 1 << 21
+    compiled, text = _compile(
+        lambda v, r: S.seg_sum2(jnp, v, r, out, dense=True),
+        jax.ShapeDtypeStruct((n, s), jnp.float64, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip))
+    assert "scatter" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
 # --------------------------------------------------------------------------
@@ -197,8 +215,11 @@ def test_q1_aggregate_programs_compile_for_v5e(one_chip, monkeypatch):
     """TPC-H q1 through the session on the CPU at a small size, recording
     every aggregate program the kernel cache builds; each is then lowered
     again for the v5e with ``jax.default_backend()`` answering "tpu" — so
-    the batched reduce, the one-hot-matmul aggregate and the Pallas
-    ``seg_sum`` call (branches no CPU test executes) are what compiles."""
+    the batched reduce, the dense group-table reductions, the
+    one-hot-matmul aggregate and the Pallas ``seg_sum`` call (branches no
+    CPU test executes) are what compiles.  None of them may hold a scatter
+    over a float32 pair: the X64 form of a float64 scatter-add, which the
+    chip applies one update after another (PERF.md §6 PR 30)."""
     import spark_rapids_tpu as srt
     from spark_rapids_tpu.sql.physical import kernel_cache as KC
     from spark_rapids_tpu.testing import scaletest as ST
@@ -255,6 +276,9 @@ def test_q1_aggregate_programs_compile_for_v5e(one_chip, monkeypatch):
             lambda *a, fn=fn: fn(*a), *shapes, sortless="q1's dictionary "
             "keys are statically compact: no sort fallback")
         pallas_calls += text.count("tpu_custom_call")
+        pair_scatters = re.findall(
+            r"= \(f32\[[^=]*\) scatter\(", text)
+        assert not pair_scatters, pair_scatters
     assert pallas_calls > 0, "no aggregate program called the Pallas seg_sum"
 
 
