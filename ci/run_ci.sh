@@ -12,7 +12,7 @@ cd "$(dirname "$0")/.."
 
 MODE="${1:-full}"
 
-echo "=== [1/20] native libraries ==="
+echo "=== [1/19] native libraries ==="
 python - <<'PYEOF'
 # both libraries build from the tracked .cpp through the loader the
 # engine itself uses; a missing toolchain fails CI here, loudly
@@ -23,17 +23,17 @@ assert native.available() and native_tcp.available(), _loader.LOADED
 print(_loader.LOADED)
 PYEOF
 
-echo "=== [2/20] API contract validation ==="
+echo "=== [2/19] API contract validation ==="
 JAX_PLATFORMS=cpu timeout 300 python tools/api_validation.py
 
-echo "=== [3/20] docgen drift check ==="
+echo "=== [3/19] docgen drift check ==="
 timeout 300 python -m spark_rapids_tpu.docgen
 if ! git diff --quiet -- docs tools/generated_files 2>/dev/null; then
     echo "WARNING: generated docs drifted from the committed copies:"
     git --no-pager diff --stat -- docs tools/generated_files || true
 fi
 
-echo "=== [4/20] traced query + chrome-trace schema check ==="
+echo "=== [4/19] traced query + chrome-trace schema check ==="
 SRT_TRACE_OUT=$(mktemp -d)/trace.json
 JAX_PLATFORMS=cpu timeout 300 python - "$SRT_TRACE_OUT" <<'PYEOF'
 import sys
@@ -60,15 +60,13 @@ sess.export_chrome_trace(sys.argv[1])
 PYEOF
 timeout 60 python tools/check_trace.py --min-events 10 "$SRT_TRACE_OUT"
 
-echo "=== [5/20] performance flight recorder: metrics + history + doctor + bench_diff ==="
+echo "=== [5/19] performance flight recorder: metrics + history + doctor ==="
 # ISSUE 8 acceptance: a traced query with the metrics registry and the
 # flight recorder enabled must produce (a) a Prometheus export that
 # passes the exposition-contract check, (b) a doctor diagnosis whose
 # JSON passes the srt-doctor/1 schema check with a named verdict, and
 # (c) a query_history record carrying the plan fingerprint + trace
-# summary.  bench_diff then diffs the two banked round artifacts as a
-# sentinel smoke test (same evidence class: both stale replays), and
-# must REFUSE a live-vs-stale comparison without --allow-stale.
+# summary.
 SRT_FR_DIR=$(mktemp -d)
 JAX_PLATFORMS=cpu timeout 300 python - "$SRT_FR_DIR" <<'PYEOF'
 import sys, json, os
@@ -110,22 +108,8 @@ PYEOF
 timeout 60 python tools/check_trace.py \
     --prometheus "$SRT_FR_DIR/metrics.prom" \
     --doctor "$SRT_FR_DIR/doctor.json"
-# sentinel smoke: two generated stale replays (same evidence class,
-# allowed) diff cleanly; then prove the live-vs-stale gate refuses
-printf '{"metric":"x","value":8,"rows":1,"platform":"tpu","captured_at":"2026-08-01T00:00:00Z"}' \
-    > "$SRT_FR_DIR/stale_a.json"
-printf '{"metric":"x","value":9,"rows":1,"platform":"tpu","captured_at":"2026-08-02T00:00:00Z"}' \
-    > "$SRT_FR_DIR/stale_b.json"
-timeout 60 python tools/bench_diff.py "$SRT_FR_DIR/stale_a.json" \
-    "$SRT_FR_DIR/stale_b.json"
-printf '{"metric":"x","value":9,"rows":1,"platform":"tpu","evidence":"live"}' \
-    > "$SRT_FR_DIR/live.json"
-if python tools/bench_diff.py "$SRT_FR_DIR/live.json" "$SRT_FR_DIR/stale_b.json" \
-        >/dev/null 2>&1; then
-    echo "ERROR: bench_diff failed to refuse live-vs-stale"; exit 1
-fi
 
-echo "=== [6/20] chaos soak: seeded faults, bit-identical results ==="
+echo "=== [6/19] chaos soak: seeded faults, bit-identical results ==="
 # Short seeded soak (docs/robustness.md): shuffle.fetch + spill.disk_read
 # (and the other recoverable sites) armed over the TPC-H-ish suite; the
 # harness itself asserts bit-identical results vs the clean run and that
@@ -137,7 +121,7 @@ JAX_PLATFORMS=cpu timeout 600 python -m spark_rapids_tpu.testing.chaos \
 timeout 60 python tools/check_trace.py --require-cat fault \
     "$SRT_CHAOS_TRACE"
 
-echo "=== [7/20] pipelined chaos soak: parallelism=4 + prefetch, bit-identical ==="
+echo "=== [7/19] pipelined chaos soak: parallelism=4 + prefetch, bit-identical ==="
 # The async execution layer (docs/async_pipeline.md) under seeded faults:
 # the chaos session runs with task.parallelism=4 + prefetch queues +
 # double-buffered transfers while the clean reference run stays serial —
@@ -151,7 +135,7 @@ JAX_PLATFORMS=cpu timeout 600 python -m spark_rapids_tpu.testing.chaos \
 timeout 60 python tools/check_trace.py --require-cat sem_wait \
     "$SRT_PIPE_TRACE"
 
-echo "=== [8/20] encoded chaos soak: encoding x parallelism 4 x prefetch ==="
+echo "=== [8/19] encoded chaos soak: encoding x parallelism 4 x prefetch ==="
 # Encoded columnar execution (docs/encoded_columns.md) under seeded
 # faults AND the async pipeline matrix: the chaos session keeps
 # dictionary/RLE columns encoded through filters/joins/group-bys and
@@ -171,7 +155,7 @@ timeout 60 python tools/check_trace.py --require-cat encode \
 JAX_PLATFORMS=cpu timeout 600 python -m spark_rapids_tpu.testing.chaos \
     8000 --seed 11 --encoded
 
-echo "=== [9/20] whole-stage fusion: plan shape + donation chaos soak ==="
+echo "=== [9/19] whole-stage fusion: plan shape + donation chaos soak ==="
 # Whole-stage XLA compilation (docs/whole_stage.md): (a) the TPC-H-ish
 # suite's plans must contain fused whole-stage nodes — an aggregate
 # terminal (FusedStageExec wrapping the partial agg) and a probe-absorbed
@@ -228,7 +212,7 @@ JAX_PLATFORMS=cpu timeout 600 python -m spark_rapids_tpu.testing.chaos \
 timeout 60 python tools/check_trace.py --require-cat stage \
     "$SRT_WS_TRACE"
 
-echo "=== [10/20] dispatch pipeline: sort/window terminals + fused probe + coalescer ==="
+echo "=== [10/19] dispatch pipeline: sort/window terminals + fused probe + coalescer ==="
 # ISSUE 14 acceptance: (a) plans form sort/window STAGE TERMINALS (the
 # sort absorbs the map chain; a window over a matching sort absorbs the
 # sort) and the broadcast join still absorbs its probe chain with the
@@ -358,7 +342,7 @@ timeout 60 python tools/check_trace.py --require-cat stage \
     "$SRT_CON_TRACE"
 grep -q coalesced_n "$SRT_CON_TRACE"
 
-echo "=== [11/20] multi-tenant serving: concurrent sessions smoke ==="
+echo "=== [11/19] multi-tenant serving: concurrent sessions smoke ==="
 # ISSUE 9 acceptance: N tenant sessions against one ServingEngine —
 # (a) weighted-fair admission: a heavy flood cannot starve a light
 # tenant (bounded wait, grant-order assertion at the controller);
@@ -451,21 +435,17 @@ timeout 60 python tools/check_trace.py --require-cat admission \
 JAX_PLATFORMS=cpu timeout 600 python -m spark_rapids_tpu.testing.chaos \
     10000 --seed 11 --multi-session
 
-echo "=== [12/20] query lifecycle: leak sentinel + cancel semantics ==="
+echo "=== [12/19] query lifecycle: leak sentinel + cancel semantics ==="
 # ISSUE 10 acceptance: (a) the bounded leak sentinel — 2 tenants of
 # mixed traffic with cancel races, per-query deadlines and fatal
 # injection armed — must bank a CLEAN verdict (retention pins, catalog
 # handles and registry cardinality return to the healthy baseline after
 # the armed waves); (b) a deadline-cancelled traced query must export
-# `cancel`-category spans (the issue->drained evidence the bench
-# lifecycle phase banks as p50/p99) and leave zero held semaphore
-# permits or live query contexts.
+# `cancel`-category spans (the issue->drained evidence) and leave zero
+# held semaphore permits or live query contexts.
 SRT_LC_DIR=$(mktemp -d)
-# --sentry rides along (ISSUE 18): a fast-cadence sentry runs its full
-# probe->bench->diff->ledger cycle beside the tenant soak and must
-# leave no srt-sentry threads or probe QueryContexts after stop
 JAX_PLATFORMS=cpu timeout 600 python tools/leak_sentinel.py \
-    --seconds 45 --tenants 2 --rows 6000 --sentry \
+    --seconds 45 --tenants 2 --rows 6000 \
     --out "$SRT_LC_DIR/leak.json"
 JAX_PLATFORMS=cpu timeout 300 python - "$SRT_LC_DIR" <<'PYEOF'
 import sys, threading, time
@@ -508,7 +488,7 @@ PYEOF
 timeout 60 python tools/check_trace.py --require-cat cancel \
     "$SRT_LC_DIR/cancel_trace.json"
 
-echo "=== [13/20] pod-scale fault domain: process-kill chaos cluster ==="
+echo "=== [13/19] pod-scale fault domain: process-kill chaos cluster ==="
 # ISSUE 19 acceptance: a REAL 3-process shuffle topology survives a
 # seeded SIGKILL mid-query (failure detection -> immediate failover ->
 # lineage recompute, bit-identical to the no-fault digest) AND the
@@ -531,7 +511,7 @@ JAX_PLATFORMS=cpu timeout 300 python tools/leak_sentinel.py \
     --seconds 6 --rows 2000 --cluster \
     --out "$SRT_CHAOS_DIR/cluster_leak.json"
 
-echo "=== [14/20] live telemetry plane: scrape + trace stitching over the shuffle wire ==="
+echo "=== [14/19] live telemetry plane: scrape + trace stitching over the shuffle wire ==="
 # ISSUE 12 acceptance: (a) the embedded telemetry server answers
 # /metrics (Prometheus contract with the tenant label, validated both
 # from the scraped body and live via check_trace --endpoint) and
@@ -681,71 +661,7 @@ timeout 60 python tools/trace_merge.py "$SRT_TP_DIR/merged.json" \
 timeout 60 python tools/check_trace.py --flow "$SRT_TP_DIR/merged.json" \
     --min-events 2 "$SRT_TP_DIR/merged.json"
 
-echo "=== [15/20] perf sentry: simulated-window e2e + evidence ledger ==="
-# ISSUE 18 acceptance: the self-driving sentry, run unattended from
-# tools/perf_sentry.py in simulated-window mode, must (a) append
-# well-formed srt-ledger/1 records — artifact path on disk, evidence
-# live, a bench_diff verdict against the auto-resolved live baseline,
-# the doctor's ranked verdict and a machine-named follow-up with
-# quantified lever evidence; (b) serve /sentry (srt-sentry/1) through
-# the telemetry server and export srt_sentry_* registry series; (c)
-# bench_diff --ledger must resolve the same live baseline from the CLI.
-SRT_SENTRY_DIR=$(mktemp -d)
-JAX_PLATFORMS=cpu timeout 600 python tools/perf_sentry.py \
-    --simulate-window --windows 2 --shapes sort --rows 4000 \
-    --budget-s 120 --ledger "$SRT_SENTRY_DIR/ledger.jsonl" --json
-JAX_PLATFORMS=cpu timeout 300 python - "$SRT_SENTRY_DIR" <<'PYEOF'
-import json, os, sys, urllib.request
-import jax; jax.config.update("jax_platforms", "cpu")
-from spark_rapids_tpu.observability import sentry as S
-from spark_rapids_tpu.observability.metrics import get_registry
-from spark_rapids_tpu.observability.server import TelemetryServer
-out = sys.argv[1]
-led = S.EvidenceLedger(os.path.join(out, "ledger.jsonl"))
-entries = led.entries()
-assert len(entries) == 2, len(entries)
-for e in entries:
-    assert e["schema"] == "srt-ledger/1"
-    assert e["evidence"] == "live", e
-    assert os.path.exists(e["artifact"]), e["artifact"]
-    assert e["doctor"]["verdict"], e
-    assert e["followup"], e
-# the second window's diff baseline is the FIRST window's artifact,
-# auto-resolved from the ledger as the newest live-evidence entry
-assert entries[1]["diff"]["baseline"] == entries[0]["artifact"], \
-    entries[1]["diff"]
-assert entries[1]["diff"]["verdict"] in ("ok", "regressed")
-fu = entries[1]["followup"]
-assert fu.startswith("STALE-EVIDENCE") or "; lever: " in fu, fu
-# /sentry route contract + srt_sentry_* registry series, served live
-s = S.PerfSentry(probe=lambda: {"outcome": "refused", "elapsed_ms": 0.1},
-                 ledger=led.path)
-s.run_once()   # closed window: banks probe telemetry, no capture
-S.set_active(s)
-srv = TelemetryServer(
-    metrics_text=lambda: get_registry().prometheus_text(),
-    healthz=lambda: (True, {}), queries=lambda: [],
-    doctor=lambda: {}, slo=lambda: {})
-sys.path.insert(0, "tools")
-import check_trace
-assert check_trace.main(["--endpoint", srv.endpoint + "/sentry"]) == 0
-doc = json.loads(urllib.request.urlopen(
-    srv.endpoint + "/sentry", timeout=10).read())
-assert doc["schema"] == "srt-sentry/1"
-assert doc["ledger"]["entries"] == 2, doc["ledger"]
-assert doc["last_live_age_s"] is not None
-assert "srt_sentry_probe_attempts_total" in get_registry().prometheus_text()
-srv.close(); S.set_active(None)
-print("sentry e2e OK:", led.path)
-PYEOF
-SRT_SENTRY_FRESH=$(JAX_PLATFORMS=cpu timeout 60 python -c "
-import json, sys
-lines = open('$SRT_SENTRY_DIR/ledger.jsonl').readlines()
-print(json.loads(lines[-1])['artifact'])")
-timeout 60 python tools/bench_diff.py \
-    --ledger "$SRT_SENTRY_DIR/ledger.jsonl" "$SRT_SENTRY_FRESH"
-
-echo "=== [16/20] test suite (virtual 8-device CPU mesh) ==="
+echo "=== [15/19] test suite (virtual 8-device CPU mesh) ==="
 if [ "$MODE" = quick ]; then
     # the <3-minute smoke tier (markers assigned in tests/conftest.py)
     python -m pytest tests/ -m quick -x -q
@@ -766,14 +682,14 @@ else
 fi
 
 if [ "$MODE" != quick ]; then
-    echo "=== [17/20] scale rig ==="
+    echo "=== [16/19] scale rig ==="
     JAX_PLATFORMS=cpu timeout 3600 \
         python -m spark_rapids_tpu.testing.scaletest 100000
 else
-    echo "=== [17/20] scale rig skipped (quick) ==="
+    echo "=== [16/19] scale rig skipped (quick) ==="
 fi
 
-echo "=== [18/20] packaging: wheel builds and installs ==="
+echo "=== [17/19] packaging: wheel builds and installs ==="
 WHEELDIR=$(mktemp -d)
 timeout 600 python -m pip wheel . --no-deps --no-build-isolation \
     -w "$WHEELDIR" -q
@@ -803,17 +719,17 @@ assert sorted(r['count'] for r in t.to_pylist()) == [1, 2]
 print('wheel OK', spark_rapids_tpu.__version__)
 "
 
-echo "=== [19/20] driver entry checks ==="
+echo "=== [18/19] driver entry checks ==="
 XLA_FLAGS="--xla_force_host_platform_device_count=8" timeout 900 \
     python __graft_entry__.py
 
 if [ "$MODE" = quick ]; then
-    echo "=== [20/20] second-jax shim world skipped (quick) ==="
+    echo "=== [19/19] second-jax shim world skipped (quick) ==="
     echo "CI PASSED"
     exit 0
 fi
 
-echo "=== [20/20] second-jax shim world (gated) ==="
+echo "=== [19/19] second-jax shim world (gated) ==="
 # The parallel-world leg the reference proves with its 14-version shim
 # matrix (ShimLoader probing, SURVEY §2.11).  This image ships exactly
 # one jaxlib and pip has zero egress (docs/perf_notes.md), so the leg

@@ -74,21 +74,10 @@ EXPLAIN = register(
     "spark.rapids.sql.explain",
     "NONE | NOT_ON_GPU | ALL: log why operators are or are not placed on the "
     "accelerator.", "NOT_ON_GPU", commonly_used=True)
-BATCH_SIZE_BYTES = register(
-    "spark.rapids.sql.batchSizeBytes",
-    "Target size in bytes for accelerator columnar batches "
-    "(reference default 1 GiB; TPU default tuned for HBM slices).",
-    1 << 30, commonly_used=True)
 BATCH_SIZE_ROWS = register(
     "spark.rapids.sql.batchSizeRows",
     "Target row count cap per columnar batch (shape-bucketing granularity).",
     1 << 20)
-MAX_READER_BATCH_SIZE_ROWS = register(
-    "spark.rapids.sql.reader.batchSizeRows",
-    "Soft cap on rows per batch produced by readers.", (1 << 31) - 1)
-MAX_READER_BATCH_SIZE_BYTES = register(
-    "spark.rapids.sql.reader.batchSizeBytes",
-    "Soft cap on bytes per batch produced by readers.", (1 << 31) - 1)
 SORT_OOC_TARGET_ROWS = register(
     "spark.rapids.sql.sort.outOfCore.targetRows",
     "Row budget per device-resident chunk in the out-of-core sort "
@@ -131,9 +120,6 @@ CONCURRENT_TASKS = register(
     "spark.rapids.sql.concurrentGpuTasks",
     "Number of tasks that may hold the device semaphore concurrently "
     "(reference GpuSemaphore, RapidsConf.scala:535).", 1, commonly_used=True)
-TIERED_PROJECT = register(
-    "spark.rapids.sql.tiered.project.enabled",
-    "Dedup common subexpressions via tiered projection.", True)
 FUSION_ENABLED = register(
     "spark.rapids.tpu.sql.fusion.enabled",
     "Fuse filter/project chains (and their terminal hash aggregate) into "
@@ -197,12 +183,6 @@ DISPATCH_COALESCE_MAX_ROWS = register(
     "Only batches whose padded capacity is at or below this many rows "
     "are eligible for dispatch coalescing — large batches already "
     "amortize their launch overhead.", 1 << 16)
-IMPROVED_FLOAT = register(
-    "spark.rapids.sql.improvedFloatOps.enabled",
-    "Allow float ops whose results may differ from CPU in ULPs.", True)
-HAS_NANS = register(
-    "spark.rapids.sql.hasNans",
-    "Assume floating point data may contain NaNs.", True)
 ANSI_ENABLED = register(
     "spark.sql.ansi.enabled",
     "ANSI mode: overflow/invalid-cast raise instead of null/wrap.", False)
@@ -330,9 +310,6 @@ OOM_SYNC_WATERMARK = register(
 HOST_SPILL_STORAGE_SIZE = register(
     "spark.rapids.memory.host.spillStorageSize",
     "Host memory budget for spilled device buffers.", 1 << 30)
-PINNED_POOL_SIZE = register(
-    "spark.rapids.memory.pinnedPool.size",
-    "Pinned host pool size for H2D/D2H staging.", 0)
 SPILL_DIR = register(
     "spark.rapids.memory.spillDir", "Directory for the disk spill tier.",
     "/tmp/rapids_tpu_spill")
@@ -342,9 +319,6 @@ GPU_DEBUG = register(
     "site — the reference's RMM debug allocation logging analog "
     "(RapidsConf.scala:366); leak_report() names still-registered "
     "handles and their origins.", False)
-OOM_RETRY_ENABLED = register(
-    "spark.rapids.sql.oomRetry.enabled",
-    "Enable the retry-on-OOM state machine (withRetry framework).", True)
 TEST_INJECT_RETRY_OOM = register(
     "spark.rapids.sql.test.injectRetryOOM",
     "Test hook: make the Nth retryable block throw a synthetic RetryOOM "
@@ -510,9 +484,6 @@ SHUFFLE_CHECKSUM = register(
     "Frame integrity checksum: auto (only when the native xxhash64 "
     "library is available — the pure-Python fallback is too slow for the "
     "hot path), true (always), false (never).", "auto")
-SHUFFLE_MAX_BYTES_IN_FLIGHT = register(
-    "spark.rapids.shuffle.maxBytesInFlight",
-    "Cap on in-flight fetched shuffle bytes.", 128 << 20)
 SHUFFLE_TCP_CONNECT_TIMEOUT_MS = register(
     "spark.rapids.shuffle.tcp.connectTimeoutMs",
     "Connect timeout for TCP shuffle block fetches and the driver "
@@ -622,14 +593,6 @@ CHAOS_PROBABILITY = register(
     "spark.rapids.tpu.chaos.probability",
     "Default injection probability per armed-site traversal.", 0.05)
 
-SORT_RADIX = register(
-    "spark.rapids.sql.sort.radix",
-    "auto|on|off: stable LSD radix argsort (1-bit cumsum+scatter passes "
-    "— linear VPU work instead of lax.sort's bitonic O(n log^2 n) "
-    "compare-exchange network on TPU).  auto runs a one-time bake-off "
-    "per backend and keeps the winner (ops/radix_sort.py; the reference "
-    "leans on cuDF's GPU radix sort for the same reason).", "auto")
-
 # --- I/O -------------------------------------------------------------------
 PARQUET_READER_TYPE = register(
     "spark.rapids.sql.format.parquet.reader.type",
@@ -638,16 +601,6 @@ PARQUET_READER_TYPE = register(
 MULTITHREAD_READ_NUM_THREADS = register(
     "spark.rapids.sql.multiThreadedRead.numThreads",
     "Thread pool size for multithreaded file reads.", 20)
-PARQUET_ENABLED = register(
-    "spark.rapids.sql.format.parquet.enabled", "Accelerate Parquet.", True)
-ORC_ENABLED = register(
-    "spark.rapids.sql.format.orc.enabled", "Accelerate ORC.", True)
-CSV_ENABLED = register(
-    "spark.rapids.sql.format.csv.enabled", "Accelerate CSV.", True)
-JSON_ENABLED = register(
-    "spark.rapids.sql.format.json.enabled", "Accelerate JSON.", False)
-AVRO_ENABLED = register(
-    "spark.rapids.sql.format.avro.enabled", "Accelerate Avro.", False)
 CSV_DEVICE_DECODE = register(
     "spark.rapids.sql.format.csv.deviceDecode.enabled",
     "Parse CSV on the device: the host scans only newline/delimiter "
@@ -746,18 +699,6 @@ IO_REPLACE_PATHS = register(
     "paths before reading — the Alluxio path-replacement analog "
     "(reference AlluxioUtils.scala:671 spark.rapids.alluxio.pathsToReplace).",
     "")
-
-# --- optimizer -------------------------------------------------------------
-OPTIMIZER_ENABLED = register(
-    "spark.rapids.sql.optimizer.enabled",
-    "Cost-based CPU-vs-TPU optimizer (off by default like the reference).",
-    False)
-OPTIMIZER_DEFAULT_CPU_COST = register(
-    "spark.rapids.sql.optimizer.cpu.exec.default",
-    "Default CPU cost per row per op (seconds).", 0.0002)
-OPTIMIZER_DEFAULT_GPU_COST = register(
-    "spark.rapids.sql.optimizer.gpu.exec.default",
-    "Default accelerator cost per row per op (seconds).", 0.0001)
 
 # --- pipelined async execution ----------------------------------------------
 TASK_PARALLELISM = register(
@@ -861,8 +802,6 @@ DUMP_ON_ERROR_PATH = register(
     "spark.rapids.sql.debug.dumpPath",
     "If set, dump failing batches to parquet here (DumpUtils equivalent).",
     "")
-STABLE_SORT = register(
-    "spark.rapids.sql.stableSort.enabled", "Force stable device sorts.", False)
 
 # --- multi-tenant serving (serving/, docs/serving.md) -----------------------
 SERVING_TENANT = register(
@@ -1033,58 +972,6 @@ SLO_WINDOWS_S = register(
     "shortest window is 'burning' (slo-burn doctor verdict).",
     "300,3600", type_=str)
 
-# --- self-driving perf sentry (observability/sentry.py) ---------------------
-SENTRY_ENABLED = register(
-    "spark.rapids.tpu.sentry.enabled",
-    "Master switch for the self-driving perf sentry "
-    "(observability/sentry.py): an autonomous daemon that probes for a "
-    "live device with cancellable bounded-timeout device probes, "
-    "runs the bench shape set on detection, diffs against the last "
-    "live-evidence baseline and appends the verdict to the evidence "
-    "ledger.  Consulted by tools/perf_sentry.py and "
-    "sentry.maybe_start_from_conf(); nothing starts one implicitly — "
-    "off (default) means the CLI exits without probing, so a conf push "
-    "stops every sentry in the fleet.", False, commonly_used=True)
-SENTRY_PROBE_INTERVAL_MS = register(
-    "spark.rapids.tpu.sentry.probeIntervalMs",
-    "Base interval between device probes while no window is open; "
-    "failed probes back off exponentially from this interval (capped "
-    "at 8x), a live window resets it.", 480_000, commonly_used=True)
-SENTRY_PROBE_TIMEOUT_MS = register(
-    "spark.rapids.tpu.sentry.probeTimeoutMs",
-    "Hard per-probe budget: a probe still unanswered at the deadline "
-    "is cancelled (QueryContext deadline machinery) and banked as "
-    "outcome=timeout — a wedged device can never hang the sentry.",
-    30_000, commonly_used=True)
-SENTRY_LEDGER_PATH = register(
-    "spark.rapids.tpu.sentry.ledgerPath",
-    "Append-only evidence ledger (srt-ledger/1 JSONL): one record per "
-    "captured window with artifact path, evidence class, bench_diff "
-    "verdict vs the last live baseline, doctor verdict and the "
-    "machine-named next-bottleneck follow-up.  Empty (default) uses "
-    "<repo>/.bench_capture/ledger.jsonl.", "", type_=str)
-SENTRY_SHAPES = register(
-    "spark.rapids.tpu.sentry.shapes",
-    "Comma list of bench shapes the sentry runs on a live window "
-    "(bench.run_shape_set vocabulary: join, sort, window, coalesce, "
-    "encoded).", "join,sort,window,coalesce,encoded", type_=str)
-
-# --- TPU-specific ----------------------------------------------------------
-BUCKET_MIN_ROWS = register(
-    "spark.rapids.tpu.shapeBucket.minRows",
-    "Smallest shape bucket; batches are padded up to power-of-two row "
-    "capacities so XLA compiles one program per (schema, bucket).", 16)
-STRING_MAX_BYTES = register(
-    "spark.rapids.tpu.string.maxBytes",
-    "Per-bucket cap on padded string width (bytes per row).", 8192)
-DEVICE_MESH_AXES = register(
-    "spark.rapids.tpu.mesh.axes",
-    "Comma list of mesh axis names for distributed exchange.", "data")
-EXPLAIN_ONLY_PLATFORM = register(
-    "spark.rapids.tpu.explainOnly.platform",
-    "Platform assumed when planning in explainOnly mode without a TPU.",
-    "tpu", internal=True)
-
 
 class RapidsConf:
     """Immutable-ish snapshot of config values, resolved from defaults +
@@ -1145,22 +1032,6 @@ class RapidsConf:
     @property
     def explain(self) -> str:
         return str(self.get(EXPLAIN)).upper()
-
-    @property
-    def batch_size_bytes(self) -> int:
-        return int(self.get(BATCH_SIZE_BYTES))
-
-    @property
-    def batch_size_rows(self) -> int:
-        return int(self.get(BATCH_SIZE_ROWS))
-
-    @property
-    def ansi_enabled(self) -> bool:
-        return bool(self.get(ANSI_ENABLED))
-
-    @property
-    def concurrent_tasks(self) -> int:
-        return int(self.get(CONCURRENT_TASKS))
 
     @property
     def shuffle_partitions(self) -> int:

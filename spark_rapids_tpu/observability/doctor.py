@@ -22,7 +22,7 @@ The verdict classes (docs/observability.md):
 
 :func:`diagnose` consumes raw tracer events (best fidelity: exec-level
 evidence spans ride each verdict); :func:`diagnose_summary` degrades to a
-compact ``trace_summary`` (bench artifacts, replay captures).  Both emit
+compact ``trace_summary`` (flight-recorder records).  Both emit
 the same schema, validated by ``tools/check_trace.py --doctor``:
 
 .. code-block:: json
@@ -78,11 +78,9 @@ VERDICTS = ("sync-bound", "compile-bound", "h2d-d2h-bound",
             # failure_detector.py quantify the evidence)
             "peer-failure")
 
-#: verdict -> the remedial lever the follow-up names.  Every verdict
-#: kind carries quantified lever evidence (``evidence.levers``) with the
-#: same precision dispatch-bound always had, so the sentry's
-#: machine-named follow-ups (observability/sentry.py) are actionable for
-#: any bottleneck, not just launch counts.
+#: verdict -> the remedial lever.  Every verdict kind carries quantified
+#: lever evidence (``evidence.levers``) with the same precision
+#: dispatch-bound always had.
 LEVERS = {
     "sync-bound": "fuse/pipeline the blocking readbacks (async d2h)",
     "compile-bound": "warm the persistent kernel cache / shape buckets",
@@ -394,22 +392,11 @@ def diagnose(events: List[Dict[str, Any]],
 
 
 def diagnose_summary(summary: Dict[str, Any],
-                     metrics: Optional[Dict[str, Any]] = None,
-                     wall_ms: Optional[float] = None,
-                     evidence: Optional[str] = None,
-                     evidence_age_s: Optional[float] = None
-                     ) -> Dict[str, Any]:
+                     wall_ms: Optional[float] = None) -> Dict[str, Any]:
     """Degraded-fidelity diagnosis from a compact ``trace_summary``
-    (bench artifacts / replay captures — no per-exec evidence; note the
-    summary's ``sync_ms`` already folds blocking d2h time in, so the
-    transfer verdict here rides byte counts + the residual).
-
-    ``evidence``/``evidence_age_s`` stamp the measurement's provenance
-    (bench.py evidence classes).  A non-live class is marked loudly —
-    :func:`followup` refuses to name a next bottleneck from it: a
-    replay's bottleneck was true hours ago and chasing it wastes the
-    next live window (ISSUE 18)."""
-    metrics = metrics or {}
+    (flight-recorder records — no per-exec evidence; note the summary's
+    ``sync_ms`` already folds blocking d2h time in, so the transfer
+    verdict here rides byte counts + the residual)."""
     ranked: List[Dict[str, Any]] = []
 
     def add(category: str, ms: float, count: int, **ev: Any) -> None:
@@ -430,13 +417,12 @@ def diagnose_summary(summary: Dict[str, Any],
             "h2d-d2h-bound", 0.0, 0,
             {"h2d_bytes": h2d, "d2h_bytes": d2h,
              "note": "bytes only: summary carries no transfer ms"}))
-    dispatches = int(summary.get("device_dispatches",
-                                 metrics.get("deviceDispatches", 0)) or 0)
+    dispatches = int(summary.get("device_dispatches", 0) or 0)
     if dispatches >= DISPATCH_FLOOR:
-        # summaries may bank the per-key launch table (bench artifacts);
-        # when absent, evidence degrades to totals + probe-batch ratio
+        # summaries may carry the per-key launch table; when absent,
+        # evidence degrades to totals
         ev = _dispatch_evidence(
-            dispatches, metrics,
+            dispatches, {},
             dict(summary.get("dispatch_by_key") or {}))
         ev["estimated"] = True
         add("dispatch-bound", dispatches * DEFAULT_DISPATCH_COST_MS,
@@ -462,108 +448,7 @@ def diagnose_summary(summary: Dict[str, Any],
     }
     if wall_ms is not None:
         out["wall_ms"] = round(float(wall_ms), 3)
-    if evidence is not None:
-        out["evidence"] = str(evidence)
-        if evidence_age_s is not None:
-            out["evidence_age_s"] = round(float(evidence_age_s), 1)
-        if evidence != "live":
-            age = (f" aged {float(evidence_age_s):.0f}s"
-                   if evidence_age_s is not None else "")
-            caveats.append(
-                f"STALE-EVIDENCE: diagnosed from {evidence} "
-                f"evidence{age} — next-bottleneck follow-ups are "
-                f"refused until a live window recaptures")
     return out
-
-
-def evidence_age_s(captured_at: Any,
-                   now: Optional[float] = None) -> Optional[float]:
-    """Seconds since a capture's UTC ``captured_at`` stamp
-    (``%Y-%m-%dT%H:%M:%SZ``), or None when unparseable."""
-    import calendar
-    import time as _t
-    try:
-        then = calendar.timegm(
-            _t.strptime(str(captured_at), "%Y-%m-%dT%H:%M:%SZ"))
-    except (ValueError, TypeError, OverflowError):
-        return None
-    return max(0.0, (now if now is not None else _t.time()) - then)
-
-
-def diagnose_artifact(rec: Dict[str, Any],
-                      now: Optional[float] = None) -> Dict[str, Any]:
-    """Degraded diagnosis over one whole bench artifact: every
-    ``*trace_summary`` dict in it (q1 + each shape) aggregates into one
-    summary, and the artifact's evidence class + replay age stamp the
-    output so the stale-evidence gate applies (ISSUE 18 — the sentry's
-    ledger verdicts ride this)."""
-    agg: Dict[str, float] = {}
-
-    def walk(obj: Any) -> None:
-        if not isinstance(obj, dict):
-            return
-        for k, v in obj.items():
-            if k.endswith("trace_summary") and isinstance(v, dict):
-                for sk, sv in v.items():
-                    if isinstance(sv, (int, float)) \
-                            and not isinstance(sv, bool):
-                        agg[sk] = agg.get(sk, 0.0) + sv
-            elif isinstance(v, dict):
-                walk(v)
-
-    walk(rec)
-    ev = rec.get("evidence")
-    if not ev:
-        if "captured_at" in rec:
-            ev = "stale-replay"
-        elif rec.get("platform") in (None, "cpu"):
-            ev = "cpu-fallback"
-        else:
-            ev = "live"
-    age = (evidence_age_s(rec.get("captured_at"), now=now)
-           if "captured_at" in rec else None)
-    return diagnose_summary(agg, evidence=str(ev), evidence_age_s=age)
-
-
-def followup(diag: Dict[str, Any],
-             evidence: Optional[str] = None,
-             evidence_age_s: Optional[float] = None) -> str:
-    """Machine-named next-bottleneck follow-up with quantified lever
-    evidence, e.g. ``sync-bound: readbacks=18, ms_per_readback=6.7,
-    top_exec=ShuffleExchangeExec; lever: fuse/pipeline the blocking
-    readbacks``.  Provenance defaults to the diagnosis's own
-    ``evidence`` stamps; anything non-live gets a loud STALE-EVIDENCE
-    marker instead of a follow-up — a bottleneck measured on a replay
-    is not a bottleneck to chase now."""
-    if evidence is None:
-        evidence = str(diag.get("evidence") or "live")
-    if evidence_age_s is None:
-        evidence_age_s = diag.get("evidence_age_s")
-    verdict = diag.get("verdict", "no-bottleneck")
-    if evidence != "live":
-        age = (f" aged {float(evidence_age_s):.0f}s"
-               if evidence_age_s is not None else "")
-        return (f"STALE-EVIDENCE: verdict '{verdict}' from {evidence} "
-                f"evidence{age} — follow-up refused; recapture on a "
-                f"live window")
-    ranked = diag.get("ranked") or []
-    if verdict == "no-bottleneck" or not ranked:
-        return "no-bottleneck: nothing to chase"
-    top = ranked[0]
-    lv = dict((top.get("evidence") or {}).get("levers") or {})
-    if not lv:
-        # compact() rows inline their quantified keys instead
-        for k in ("readbacks_per_stage", "device_dispatches",
-                  "launches_per_probe_batch", "bytes", "h2d_bytes",
-                  "d2h_bytes", "top_exec"):
-            if top.get(k) is not None:
-                lv[k] = top[k]
-        if not lv:
-            lv = {"ms": top.get("ms"), "count": top.get("count")}
-    parts = ", ".join(f"{k}={v}" for k, v in lv.items())
-    lever = LEVERS.get(verdict, "")
-    return (f"{verdict}: {parts}"
-            + (f"; lever: {lever}" if lever else ""))
 
 
 def diagnose_tenants(records: List[Dict[str, Any]]) -> Dict[str, Any]:
@@ -626,8 +511,9 @@ def diagnose_tenants(records: List[Dict[str, Any]]) -> Dict[str, Any]:
 
 
 def compact(diag: Dict[str, Any], top: int = 3) -> Dict[str, Any]:
-    """Bench-artifact form: verdict + top-N {category, ms, share, count}
-    (evidence trimmed to its counters; bench banks this per shape)."""
+    """Compact form: verdict + top-N {category, ms, share, count}
+    (evidence trimmed to its counters; ``diagnose_tenants`` serves this
+    per tenant)."""
     rows = []
     for e in diag.get("ranked", [])[:top]:
         row = {"category": e["category"], "ms": e["ms"],

@@ -61,8 +61,9 @@ def aggregate_by_exec(events: List[Dict[str, Any]]
 def trace_summary(events: List[Dict[str, Any]],
                   counters: Optional[Dict[str, float]] = None,
                   dropped: int = 0) -> Dict[str, Any]:
-    """Compact whole-query summary for bench artifacts: blocking sync
-    count/ms, kernel trace+compile ms, bytes on the wire."""
+    """Compact whole-query summary (flight-recorder records, the
+    doctor's degraded mode): blocking sync count/ms, kernel
+    trace+compile ms, bytes on the wire."""
     agg = aggregate_by_exec(events)
     tot = dict(_ZERO)
     for row in agg.values():
@@ -88,7 +89,7 @@ def trace_summary(events: List[Dict[str, Any]],
         out["device_dispatches"] = int(counters["deviceDispatches"])
     if tot["fault_n"]:
         out["fault_count"] = int(tot["fault_n"])
-    # truncation is first-class: a doctor/bench consumer must never have
+    # truncation is first-class: a consumer (the doctor) must never have
     # to infer from an absent key that the ring did NOT overflow
     out["trace_truncated"] = bool(dropped)
     if dropped:

@@ -19,13 +19,6 @@ adding any dependency:
                 including the ``slo-burn`` verdict when a tenant burns
 ``/slo``        per-tenant multi-window SLO burn rates
                 (observability/slo.py)
-``/sentry``     perf-sentry status (observability/sentry.py,
-                ``srt-sentry/1``): current phase, probe telemetry,
-                evidence-ledger tail and last-live-evidence age.  By
-                default served from the process's active sentry (a
-                'none' payload that still reports ledger staleness when
-                no sentry runs here); owners may inject their own
-                source.
 ==============  ===========================================================
 
 Ownership and lifecycle: the ServingEngine starts one server in
@@ -65,11 +58,9 @@ class TelemetryServer:
                  queries: Callable[[], Any],
                  doctor: Callable[[], Any],
                  slo: Callable[[], Any],
-                 host: str = "127.0.0.1", port: int = 0,
-                 sentry: Optional[Callable[[], Any]] = None):
+                 host: str = "127.0.0.1", port: int = 0):
         self._routes: Dict[str, Callable[[], Any]] = {
-            "/queries": queries, "/doctor": doctor, "/slo": slo,
-            "/sentry": sentry or _default_sentry_source}
+            "/queries": queries, "/doctor": doctor, "/slo": slo}
         self._metrics_text = metrics_text
         self._healthz = healthz
         self._httpd: Optional[ThreadingHTTPServer] = ThreadingHTTPServer(
@@ -124,8 +115,7 @@ class TelemetryServer:
                         body = _to_json(
                             {"error": f"no route {path!r}",
                              "routes": ["/metrics", "/healthz",
-                                        "/queries", "/doctor", "/slo",
-                                        "/sentry"]})
+                                        "/queries", "/doctor", "/slo"]})
                         ctype = "application/json"
                         status = 404
                 except Exception as e:  # noqa: BLE001 — route isolation
@@ -146,12 +136,6 @@ class TelemetryServer:
                 pass  # no per-request stderr chatter
 
         return _Handler
-
-
-def _default_sentry_source() -> Any:
-    # lazy: the sentry module is only imported when /sentry is hit
-    from . import sentry as _sentry
-    return _sentry.status_payload()
 
 
 def _to_json(obj: Any) -> bytes:

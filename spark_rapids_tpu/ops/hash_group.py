@@ -160,8 +160,8 @@ def _sorted_ids(jnp, keys, row_mask):
     from .ranks import _ranks_from_lex, lex_sort
     cap = int(row_mask.shape[0])
     # liveness leads the sort key: live rows sort first, so live ranks are
-    # exactly [0, n_groups).  bool (not int64): a radix-path sort then
-    # pays ONE pass for this flag instead of 64
+    # exactly [0, n_groups).  bool (not int64): one narrow comparator
+    # operand instead of lex_sort's two 32-bit halves of an int64
     sort_keys = [~row_mask] + list(keys)
     perm, skeys = lex_sort(jnp, sort_keys)
     rank = _ranks_from_lex(jnp, perm, skeys)

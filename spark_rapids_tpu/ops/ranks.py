@@ -58,15 +58,6 @@ def lex_sort(xp, keys):
         return perm, [k[perm] for k in keys]
     import jax
 
-    from .radix_sort import (_MAX_PASSES, radix_argsort, radix_wins,
-                             total_passes)
-    passes = total_passes(keys)
-    # the pass budget binds in EVERY mode: mode=on must not unroll a
-    # 300-pass program for a wide string sort (compile-time blowup)
-    if (passes is not None and passes <= _MAX_PASSES
-            and radix_wins(xp, passes)):
-        perm = radix_argsort(xp, keys)
-        return perm, [k[perm] for k in keys]
     n = keys[0].shape[0]
     iota = xp.arange(n, dtype=xp.int32)
     sort_keys = []
@@ -260,7 +251,7 @@ def column_sort_keys(xp, col: DeviceColumn):
     if isinstance(col.dtype, T.StructType):
         keys = []
         for ch in col.children:
-            keys.append(ch.validity)   # bool: one radix pass, not 64
+            keys.append(ch.validity)   # bool: a narrow sort operand
             keys.extend(column_sort_keys(xp, ch))
         return keys
     if col.lengths is not None:
@@ -277,7 +268,7 @@ def dense_rank_columns(xp, cols, num_rows_mask=None):
     keys = []
     if num_rows_mask is not None:
         keys.append(~num_rows_mask)            # bool flags stay narrow:
-    for c in cols:                             # one radix pass, not 64
+    for c in cols:                             # one byte a row, not 8
         keys.append(~c.validity)
         keys.extend(column_sort_keys(xp, c))
     if len(keys) == 1 and num_rows_mask is not None:

@@ -22,8 +22,8 @@ def sort_permutation(xp, specs: Sequence[Tuple[DeviceColumn, bool, bool]],
     """specs: [(column, ascending, nulls_first), ...] in sort-priority order
     (most significant first).  row_mask: bool[capacity] live-row mask.
     Returns int32 permutation putting rows in order, dead rows last."""
-    # flags stay NARROW (bool / int8): under the radix sort path each
-    # key costs one pass per bit, so a 0/1 flag must not be an int64
+    # flags stay NARROW (bool / int8): lex_sort splits every int64 key
+    # into two 32-bit comparator operands, so a 0/1 flag must not be one
     keys = [~row_mask]                     # dead rows last, most significant
     for col, asc, nulls_first in specs:
         null_flag = (~col.validity).astype(xp.int8)

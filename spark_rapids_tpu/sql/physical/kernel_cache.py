@@ -184,40 +184,6 @@ class _TrackedKernel:
         return out
 
 
-def _trace_salt() -> Tuple:
-    """Global knobs that change TRACED PROGRAMS without appearing in any
-    exec's own key (the _jit contract: the key must capture everything
-    that affects the trace).  Today: the radix-sort decision — lex_sort
-    branches on it inside sort kernels, so flipping the conf or a fresh
-    bake-off verdict must not reuse comparator-sort programs.
-
-    The frozen bake-off base measurement is RESOLVED HERE (bakeoff_base
-    probes once per backend) so the salt is stable from the first
-    cached_jit on — a measurement landing mid-session would otherwise
-    flip the salt and invalidate the whole kernel cache.  All pass-count
-    verdicts derive deterministically from that one base."""
-    try:
-        import jax.numpy as jnp
-
-        from ...config import RapidsConf
-
-        mode = str(RapidsConf.get_global().get(
-            "spark.rapids.sql.sort.radix", "auto")).lower()
-        if mode == "auto":
-            from ...ops.radix_sort import bakeoff_base
-            return ("radix-auto", bakeoff_base(jnp))
-        return ("radix", mode)
-    except ImportError:
-        return ()
-    except Exception as e:  # pragma: no cover - transient probe failure
-        # an empty salt can reuse programs traced under a different sort
-        # verdict; make the (rare) degradation visible instead of silent
-        import warnings
-        warnings.warn(f"radix trace-salt resolution failed ({e!r}); "
-                      f"kernel cache proceeds unsalted")
-        return ()
-
-
 def donation_supported() -> bool:
     """XLA:CPU accepts but ignores donate_argnums (and warns per unusable
     buffer); only real device backends reclaim donated HBM.  The donation
@@ -298,11 +264,6 @@ def cached_jit(key: Tuple, fn: Callable,
     from non-donating programs, and donated arguments must be sole-owner
     batches (retention.may_donate) that are never touched after the call.
     """
-    # the name is built from the key WITHOUT the salt: on a TPU the salt
-    # holds the bake-off's measured microseconds, which differ in every
-    # process (a sort verdict that flips changes the program itself)
-    unsalted = key
-    key = key + _trace_salt()
     with _LOCK:
         cached = _CACHE.get(key)
         if cached is not None:
@@ -313,7 +274,7 @@ def cached_jit(key: Tuple, fn: Callable,
         _STATS["misses"] += 1
         _om.inc("kernel_cache_misses_total")
         import jax
-        label = program_name(unsalted, fn)
+        label = program_name(key, fn)
         fn = _named(fn, label)
         if donate_argnums and donation_supported():
             jitted = jax.jit(fn, donate_argnums=tuple(donate_argnums))

@@ -427,7 +427,7 @@ class TpuSession:
         """Unregister the token; when the query was cancelled (or hit
         its deadline), bank the drain latency — cancel issue to worker
         threads unwound — as the ``cancel_latency_ms`` series and a
-        ``cancel`` trace span (the bench `lifecycle` phase's p50/p99)."""
+        ``cancel`` trace span."""
         import time as _time
         from ..observability import metrics as OM
         from ..observability import tracer as OT

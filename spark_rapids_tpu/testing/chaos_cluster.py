@@ -28,9 +28,8 @@ result digest — sorted (k, v) rows hashed — must match the in-process
 
 Recovery latency is measured on the driver (SIGKILL/SIGSTOP ->
 failure-detector dead-declaration) and in the survivors (self-timed
-degraded query + tracer-summed recompute spans) and banked as a
-``fault_recovery`` record that rides the bench artifact contract
-(tools/bench_diff.py diffs it like any other metric group).
+degraded query + tracer-summed recompute spans) and returned as a
+``fault_recovery`` record in the suite's report.
 
 Run standalone:  python tools/chaos_cluster.py --procs 3 --scenario all
 """
@@ -558,8 +557,7 @@ SCENARIOS = {"sigkill": run_sigkill, "zombie": run_zombie,
 def run_suite(scenarios: List[str], nprocs: int = 3, seed: int = 7,
               rows: int = 512, out_dir: Optional[str] = None) -> dict:
     """Run the asked scenarios and fold their latencies into one
-    ``fault_recovery`` record (the bench-artifact phase the perf ledger
-    banks beside the throughput phases)."""
+    ``fault_recovery`` record."""
     results = []
     for name in scenarios:
         sub = os.path.join(out_dir, name) if out_dir else None
@@ -574,12 +572,10 @@ def run_suite(scenarios: List[str], nprocs: int = 3, seed: int = 7,
     detections = [r["detection_ms"] for r in results
                   if "detection_ms" in r]
     return {
-        # a bare bench result record (tools/bench_diff.py load_artifact):
         # the headline value is the WORST failure-detection latency —
         # the bound every recovery path waits behind
         "metric": "fault_recovery_detection_ms",
         "value": max(detections) if detections else 0.0,
-        "extra_metrics": {"fault_recovery": phase},
         "fault_recovery": phase,
         "scenarios": [{k: v for k, v in r.items()
                        if not k.startswith("_")} for r in results],

@@ -7,8 +7,6 @@ on the serial engine, once with the parallel partition scheduler +
 prefetch queues + double-buffered transfers, asserts the results are
 BIT-IDENTICAL, and reports the wall-clock delta.  Used by
 
-* bench.py           — the banked ``pipeline_*`` artifact metrics
-  (pipeline-off vs pipeline-on, ISSUE 5 acceptance evidence),
 * tests/test_async_pipeline.py — the parity matrix, and
 * ad hoc:  python -m spark_rapids_tpu.testing.pipeline [rows]
 
@@ -59,7 +57,7 @@ def measure(rows: int = 120_000, repeats: int = 2,
             parallelism: int = 4,
             tables: Optional[dict] = None) -> dict:
     """Serial vs pipelined wall clock over the suite with a bit-parity
-    assert; returns the banked-artifact record."""
+    assert; returns the two wall clocks and their ratio."""
     import spark_rapids_tpu as srt
     from ..config import RapidsConf
     from .scaletest import build_tables

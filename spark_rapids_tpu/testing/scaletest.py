@@ -629,7 +629,7 @@ def iter_suite(rows: int, queries=None, tables=None, sess=None,
     """Per-query streaming driver over :data:`QUERIES` with amortized
     tables/session: yields each report record as its query completes, or
     an ``{"query", "error"}`` record for a failing query.  The one
-    iteration loop `main()` and bench.py's suite child both consume."""
+    iteration loop `main()` consumes."""
     import spark_rapids_tpu as srt
     tables = tables if tables is not None else build_tables(rows)
     extra = extra_tables if extra_tables is not None else {}

@@ -58,8 +58,8 @@ QUICK_MODULES = {
     "test_whole_stage",
     # performance flight recorder (ISSUE 8): metrics-registry accounting
     # under the parallel scheduler, doctor verdicts on known injected
-    # bottlenecks, and the bench_diff evidence gate are tier-1 — wrong
-    # attribution silently misdirects every perf decision downstream
+    # bottlenecks are tier-1 — wrong attribution silently misdirects
+    # every perf decision downstream
     "test_metrics_registry", "test_doctor",
     # multi-tenant serving (ISSUE 9): weighted-fair admission, tenant
     # budgets, the cross-query result/broadcast sharing tiers and the
@@ -87,11 +87,6 @@ QUICK_MODULES = {
     # generation race are tier-1 — a regression here is silent data
     # loss that only manifests when a peer actually dies
     "test_failure_detector",
-    # perf sentry (ISSUE 18): probe classification, evidence-ledger
-    # append-only/torn-line safety, live-over-stale baseline resolution
-    # and the /sentry route contract are tier-1 — a sentry regression
-    # silently starves every future round of live evidence
-    "test_sentry",
     # first run on the chip (ISSUE 22): the chip-compiler compiles of the
     # two Pallas kernels, the four-device mesh exchange and the q1
     # aggregate at real widths, the loud Pallas gate, and chip_smoke.py's
@@ -107,6 +102,12 @@ QUICK_MODULES = {
     # chip runs for small tables and no other CPU test executes, against
     # the scatter form — a wrong sum here is a silent wrong answer
     "test_dense_group_reduce",
+    # what the benchmark's cells run and the next PRs rework (ISSUE 31):
+    # the device parquet decoder against pyarrow (the parquet cell's
+    # longest device programs, Queue 1 item 1), the prepacked D2H every
+    # collect ends in, and the kernel cache every cell's programs live
+    # in — tier-1 time goes where the cells go
+    "test_device_parquet", "test_prepack", "test_kernel_cache",
 }
 
 

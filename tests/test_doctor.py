@@ -1,10 +1,8 @@
-"""Bottleneck doctor (observability/doctor.py) + bench regression
-sentinel (tools/bench_diff.py): synthetic traces with known injected
-bottlenecks -> expected ranked verdicts (sem_wait-bound and h2d-bound
-fixtures per ISSUE 8), nested-span self-time attribution, truncation
-caveats, summary-mode degradation, and the live/stale evidence gate."""
+"""Bottleneck doctor (observability/doctor.py): synthetic traces with
+known injected bottlenecks -> expected ranked verdicts (sem_wait-bound and
+h2d-bound fixtures per ISSUE 8), nested-span self-time attribution,
+truncation caveats and summary-mode degradation."""
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -17,9 +15,6 @@ import pytest
 import spark_rapids_tpu as srt
 from spark_rapids_tpu.observability import doctor as OD
 from spark_rapids_tpu.sql import functions as F
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 def _ev(cat, name, ms, ts=0.0, tid=1, exec_="TpuJoin", **args):
     """Synthetic tracer event (ts/dur in µs like the real ring)."""
@@ -196,7 +191,7 @@ def test_diagnose_summary_degraded_mode():
     assert any("trace_summary" in c for c in diag["caveats"])
 
 
-def test_compact_form_for_bench():
+def test_compact_form():
     events = [_ev("sync", "r", 50.0), _ev("spill", "s", 10.0, ts=60.0)]
     c = OD.compact(OD.diagnose(events, dropped_events=5), top=1)
     assert c["verdict"] == "sync-bound"
@@ -251,78 +246,3 @@ def test_diagnose_without_trace_raises():
     sess.create_dataframe(pa.table({"k": [1]})).collect()
     with pytest.raises(RuntimeError):
         sess.diagnose_last_query()
-
-
-# --------------------------------------------------------------------------
-# bench_diff: thresholded verdicts + the live/stale evidence gate
-# --------------------------------------------------------------------------
-
-def _bench_diff():
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff", os.path.join(REPO, "tools", "bench_diff.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _artifact(tmp_path, name, **kw):
-    rec = {"metric": "tpch_q1_like_rows_per_sec", "value": 1000,
-           "unit": "rows/s", "rows": 1000, "platform": "tpu"}
-    rec.update(kw)
-    p = tmp_path / name
-    p.write_text(json.dumps({"parsed": rec}))
-    return str(p)
-
-
-def test_bench_diff_verdict_directions(tmp_path):
-    bd = _bench_diff()
-    a = _artifact(tmp_path, "a.json", value=1000, evidence="live",
-                  extra_metrics={"join_rows_per_sec": 100,
-                                 "join_trace_summary": {"sync_count": 50}})
-    b = _artifact(tmp_path, "b.json", value=1300, evidence="live",
-                  extra_metrics={"join_rows_per_sec": 80,
-                                 "join_trace_summary": {"sync_count": 10}})
-    rc, rows = bd.run(a, b, 0.10, allow_stale=False, as_json=False)
-    assert rc == 0
-    by = {r["metric"]: r["verdict"] for r in rows}
-    assert by["tpch_q1_like_rows_per_sec"] == "IMPROVED"   # up = better
-    assert by["join_rows_per_sec"] == "REGRESSED"          # down = worse
-    assert by["join_trace_summary.sync_count"] == "IMPROVED"  # down=better
-
-
-def test_bench_diff_refuses_live_vs_stale(tmp_path):
-    bd = _bench_diff()
-    a = _artifact(tmp_path, "a.json", captured_at="2026-08-01T00:00:00Z")
-    b = _artifact(tmp_path, "b.json", evidence="live")
-    assert bd.evidence_of(json.loads(
-        (tmp_path / "a.json").read_text())["parsed"]) == "stale-replay"
-    rc, _ = bd.run(a, b, 0.10, allow_stale=False, as_json=False)
-    assert rc == 2
-    rc, rows = bd.run(a, b, 0.10, allow_stale=True, as_json=False)
-    assert rc == 0 and rows
-
-
-def test_bench_diff_threshold_band(tmp_path):
-    bd = _bench_diff()
-    a = _artifact(tmp_path, "a.json", value=1000, evidence="live")
-    b = _artifact(tmp_path, "b.json", value=1050, evidence="live")
-    _, rows = bd.run(a, b, 0.10, allow_stale=False, as_json=False)
-    assert {r["metric"]: r["verdict"]
-            for r in rows}["tpch_q1_like_rows_per_sec"] == "OK"
-
-
-def test_bench_diff_same_class_stale_artifacts_smoke(tmp_path):
-    """Two stale replays diff cleanly (the CI smoke): same evidence
-    class, so the gate PASSES without --allow-stale, and a join metric
-    that improves between them is visible."""
-    bd = _bench_diff()
-    a = _artifact(tmp_path, "a.json", captured_at="2026-08-01T00:00:00Z",
-                  extra_metrics={"join_rows_per_sec": 100})
-    b = _artifact(tmp_path, "b.json", captured_at="2026-08-02T00:00:00Z",
-                  extra_metrics={"join_rows_per_sec": 130})
-    ra, rb = bd.load_artifact(a), bd.load_artifact(b)
-    assert bd.evidence_of(ra) == bd.evidence_of(rb) == "stale-replay"
-    rc, rows = bd.run(a, b, 0.10, allow_stale=False, as_json=False)
-    assert rc == 0
-    by = {r["metric"]: r["verdict"] for r in rows}
-    assert by["join_rows_per_sec"] == "IMPROVED"

@@ -439,3 +439,74 @@ class TestLeakDetection:
         finally:
             srt.session(**{"spark.rapids.sql.enabled": True})
             BufferCatalog.reset()
+
+
+class TestConfRegistry:
+    """Every registered key is read by the engine (ISSUE 31): a key that
+    is documented and read by nothing is a promise the docs make and the
+    program does not keep."""
+
+    @staticmethod
+    def orphans(config_source=None):
+        """Registered entries that no module of the package other than
+        config.py and docgen.py references, by entry name or key string.
+        A use inside config.py counts only through what carries it out
+        (a RapidsConf accessor, a module-level table) when the package
+        references that carrier."""
+        import ast
+        import pathlib
+        import re
+        from spark_rapids_tpu import config
+        root = pathlib.Path(config.__file__).parent
+        package = "\n".join(
+            p.read_text() for p in sorted(root.rglob("*.py"))
+            if p.relative_to(root).as_posix() not in ("config.py",
+                                                      "docgen.py"))
+        tree = ast.parse(config_source
+                         or (root / "config.py").read_text())
+        entries = {}    # entry name -> key string
+        carriers = {}   # entry name -> names that use it inside config.py
+        for top in tree.body:
+            call = getattr(top, "value", None)
+            if (isinstance(top, ast.Assign) and isinstance(call, ast.Call)
+                    and getattr(call.func, "id", "") == "register"):
+                entries[top.targets[0].id] = call.args[0].value
+                continue
+            scopes = top.body if isinstance(top, ast.ClassDef) else [top]
+            for scope in scopes:
+                name = getattr(scope, "name", None) or next(
+                    (t.id for t in getattr(scope, "targets", [])
+                     if isinstance(t, ast.Name)), None)
+                for node in ast.walk(scope):
+                    if isinstance(node, ast.Name) and name:
+                        carriers.setdefault(node.id, set()).add(name)
+
+        def used(word):
+            return re.search(rf"\b{re.escape(word)}\b", package) is not None
+
+        return sorted(
+            key for name, key in entries.items()
+            if key not in package and not used(name)
+            and not any(used(c) for c in carriers.get(name, ())))
+
+    def test_every_registered_key_is_read_by_the_engine(self):
+        orphans = self.orphans()
+        assert not orphans, (
+            "registered in config.py, documented, and read by nothing "
+            "in spark_rapids_tpu/: " + ", ".join(orphans))
+
+    def test_guard_sees_a_dead_key(self):
+        import pathlib
+        from spark_rapids_tpu import config
+        source = pathlib.Path(config.__file__).read_text()
+        dead = source + (
+            '\nFORMAT_NOTHING_ENABLED = register(\n'
+            '    "spark.rapids.sql.format.nothing.enabled", "Dead.", True)\n')
+        assert self.orphans(dead) == ["spark.rapids.sql.format.nothing.enabled"]
+
+    def test_unknown_key_is_kept_verbatim(self):
+        # a job that still sets a deleted key behaves as before: nothing
+        # read it then either
+        conf = RapidsConf({"spark.rapids.sql.format.parquet.enabled": False})
+        assert conf.get("spark.rapids.sql.format.parquet.enabled") is False
+        assert srt.session(**{"spark.rapids.sql.hasNans": False}) is not None

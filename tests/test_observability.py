@@ -60,7 +60,7 @@ def test_docgen_writes_files(tmp_path):
     written = generate(str(tmp_path))
     assert len(written) == 5
     cfg = (tmp_path / "docs" / "configs.md").read_text()
-    assert "spark.rapids.sql.batchSizeBytes" in cfg
+    assert "spark.rapids.sql.batchSizeRows" in cfg
     ops = (tmp_path / "docs" / "supported_ops.md").read_text()
     assert "ShuffleExchangeExec" in ops and "RegExpReplace" in ops
     csv = (tmp_path / "tools" / "generated_files"

@@ -573,7 +573,7 @@ def test_program_names_are_the_same_in_every_process():
     assert agg_v and agg_w and agg_v != agg_w
 
 
-def test_program_name_ignores_the_trace_salt_and_tells_keys_apart():
+def test_program_name_is_the_keys_and_tells_keys_apart():
     from spark_rapids_tpu.sql.physical import kernel_cache as KC
 
     def impl(b):

@@ -15,8 +15,8 @@ subprocesses on the TCP plane) and runs the seeded fault scenarios from
               fetches take the dead-skip fast path straight to
               recompute.
 
-Writes ``report.json`` (with the ``fault_recovery`` latency record that
-tools/bench_diff.py can diff) plus per-process trace event logs suitable
+Writes ``report.json`` (with the ``fault_recovery`` latency record)
+plus per-process trace event logs suitable
 for tools/trace_merge.py + check_trace --require-cat fault.
 
 Usage:

@@ -26,11 +26,8 @@ every flow id must have both an "s" start and an "f" finish, each
 anchored inside a real span on the same pid/tid, and every pid with
 spans must carry process_name metadata.
 ``--endpoint`` scrapes a live telemetry server URL
-(observability/server.py) and validates the response body: a
-/metrics-style body is held to the Prometheus exposition contract; a
-JSON body carrying ``schema: srt-sentry/1`` (the /sentry route) is held
-to the sentry status contract (known phase, probe telemetry with
-classified outcomes, ledger tail of valid srt-ledger/1 entries).
+(observability/server.py) and holds the response body to the
+Prometheus exposition contract.
 Exit 0 when every requested check passes, 1 otherwise.
 """
 
@@ -155,73 +152,14 @@ def check_prometheus(path: str, require_label: str = ""):
         return _check_prom_lines(fh, require_label)
 
 
-#: perf-sentry lifecycle phases (observability/sentry.py PHASES) plus
-#: the "none" payload an active-sentry-free process serves
-SENTRY_PHASES = ("idle", "probe", "bench", "diff", "ledger", "stopped",
-                 "none")
-SENTRY_PROBE_OUTCOMES = ("ok", "degraded", "timeout", "refused",
-                         "wedged")
-
-
-def check_sentry(doc) -> str:
-    """Validate a /sentry route payload (srt-sentry/1 schema)."""
-    if not isinstance(doc, dict):
-        raise ValueError("sentry payload is not a JSON object")
-    if doc.get("schema") != "srt-sentry/1":
-        raise ValueError(f"schema is {doc.get('schema')!r}, "
-                         f"expected 'srt-sentry/1'")
-    phase = doc.get("phase")
-    if phase not in SENTRY_PHASES:
-        raise ValueError(f"unknown phase {phase!r}")
-    ledger = doc.get("ledger")
-    if not isinstance(ledger, dict) or not ledger.get("path"):
-        raise ValueError("ledger block missing or without a path")
-    tail = ledger.get("tail", [])
-    if not isinstance(tail, list):
-        raise ValueError("ledger tail is not a list")
-    for i, rec in enumerate(tail):
-        if not isinstance(rec, dict) \
-                or rec.get("schema") != "srt-ledger/1":
-            raise ValueError(f"ledger tail[{i}] is not a valid "
-                             f"srt-ledger/1 record: {rec!r}")
-    if "last_live_age_s" not in doc:
-        raise ValueError("missing last_live_age_s")
-    if phase != "none":
-        probe = doc.get("probe")
-        if not isinstance(probe, dict):
-            raise ValueError("probe block missing")
-        last = probe.get("last")
-        if last is not None and last.get("outcome") \
-                not in SENTRY_PROBE_OUTCOMES:
-            raise ValueError(f"unknown probe outcome "
-                             f"{last.get('outcome')!r}")
-    return (f"sentry phase {phase}, {len(tail)} ledger tail entr"
-            f"{'y' if len(tail) == 1 else 'ies'}, "
-            f"last_live_age_s={doc.get('last_live_age_s')}")
-
-
 def check_endpoint(url: str, require_label: str = "") -> str:
-    """Scrape a live telemetry URL and validate the response body:
-    Prometheus exposition contract for /metrics-style bodies, the
-    srt-sentry/1 status contract for the /sentry route (auto-detected
-    from the payload schema)."""
+    """Scrape a live telemetry URL and hold the response body to the
+    Prometheus exposition contract."""
     import urllib.request
     if not url.startswith(("http://", "https://")):
         url = "http://" + url
     with urllib.request.urlopen(url, timeout=10) as resp:
         body = resp.read().decode("utf-8", "replace")
-    if body.lstrip().startswith("{"):
-        try:
-            doc = json.loads(body)
-        except ValueError:
-            doc = None
-        if isinstance(doc, dict) and doc.get("schema") == "srt-sentry/1":
-            return check_sentry(doc)
-        if doc is not None:
-            schema = (doc.get("schema") if isinstance(doc, dict)
-                      else type(doc).__name__)
-            raise ValueError("JSON endpoint body with unrecognized "
-                             f"schema {schema!r}")
     n, fams = _check_prom_lines(body.splitlines(), require_label)
     return f"{n} samples, {len(fams)} families"
 

@@ -28,14 +28,6 @@ the leg asserts the server left nothing behind — no lingering
 ``srt-telemetry-*`` thread and the port rebindable (the series-cap
 bound already covers scrape-driven cardinality growth).
 
-``--sentry`` runs a perf sentry daemon (observability/sentry.py)
-alongside the soak — real cancellable device probes (which register
-QueryContexts through the lifecycle plane) interleaved with simulated
-window opens feeding a tiny fake bench — and after ``stop()`` asserts
-the daemon drained to baseline: no lingering ``srt-sentry*`` thread, no
-live ``sentry`` query contexts, and at least one valid ledger entry
-appended.
-
 ``--cluster`` runs the pod-scale fault-domain leg: a real N-process
 shuffle cluster (testing/chaos_cluster.py) through kill/recover cycles
 — SIGKILL a peer mid-query, wait out the failure detector's dead
@@ -46,7 +38,7 @@ retained peer-epoch or block-source state on the closed manager.
 
 Usage:  python tools/leak_sentinel.py [--seconds 60] [--tenants 2]
             [--rows 8000] [--arm cancel,deadline,fatal] [--telemetry]
-            [--sentry] [--cluster] [--out FILE]
+            [--cluster] [--out FILE]
 Exit 0 = clean verdict; 1 = leak (per-gauge evidence in the report).
 """
 
@@ -78,10 +70,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--telemetry", action="store_true",
                    help="soak with the telemetry server enabled and "
                         "assert leak-free shutdown (thread + port)")
-    p.add_argument("--sentry", action="store_true",
-                   help="run a perf sentry daemon alongside the soak "
-                        "and assert its thread + probe contexts drain "
-                        "to baseline after stop()")
     p.add_argument("--cluster", action="store_true",
                    help="run N-process kill/recover cycles through the "
                         "chaos cluster harness and assert heartbeat "
@@ -200,7 +188,6 @@ def run_sentinel(seconds: float = 60.0, tenants: int = 2,
                  arm: str = "cancel,deadline,fatal",
                  max_waves: int = 1000,
                  telemetry: bool = False,
-                 sentry: bool = False,
                  cluster: bool = False) -> dict:
     """Returns the report dict; report["verdict"] is "clean" or "leak"."""
     import spark_rapids_tpu as srt  # noqa: F401 - engine init path
@@ -243,38 +230,7 @@ def run_sentinel(seconds: float = 60.0, tenants: int = 2,
     get_shuffle_manager().cleanup_ttl_s = -1.0
     samples = []
     telem: dict = {}
-    sentry_leg: dict = {}
-    sentry_obj = None
     t_host, t_port = "", 0
-    if sentry:
-        from spark_rapids_tpu.observability import sentry as OS
-        sdir = tempfile.mkdtemp(prefix="srt-sentry-leak-")
-        probe_n = {"n": 0}
-
-        def sentry_probe() -> dict:
-            # every third attempt simulates an open window; the others
-            # run the REAL cancellable device probe — on this CPU host
-            # it classifies ``degraded``, exercising the QueryContext
-            # register/poll/unregister path whose drain this leg asserts
-            probe_n["n"] += 1
-            if probe_n["n"] % 3 == 0:
-                return {"outcome": "ok", "platform": "simulated",
-                        "elapsed_ms": 0.1}
-            return OS.device_probe(timeout_s=5.0)
-
-        def sentry_bench(shapes) -> dict:
-            return {"metric": "sentry_shape_set", "value": 1.0,
-                    "unit": "rows/s", "rows": 1,
-                    "platform": "simulated", "evidence": "live",
-                    "shapes": list(shapes)}
-
-        sentry_obj = OS.PerfSentry(
-            probe=sentry_probe, bench=sentry_bench,
-            ledger=os.path.join(sdir, "ledger.jsonl"),
-            interval_s=0.2, probe_timeout_s=5.0,
-            entry_extra={"simulated": True})
-        sentry_obj.start()
-        sentry_leg["ledger"] = sentry_obj.ledger.path
     try:
         if telemetry:
             if eng.telemetry is None:
@@ -430,44 +386,6 @@ def run_sentinel(seconds: float = 60.0, tenants: int = 2,
                              f"after engine close: {e}")
             telem["shutdown"] = "clean" if not any(
                 "telemetry" in leak for leak in leaks) else "leak"
-        if sentry:
-            # the daemon must drain to baseline: stop() joins the loop
-            # thread; probe threads are short-lived daemons and probe
-            # QueryContexts must all be unregistered (a small grace
-            # window lets an in-flight probe land)
-            sentry_obj.stop(timeout=10.0)
-
-            def _sentry_residue():
-                threads = [t.name for t in threading.enumerate()
-                           if t.name.startswith("srt-sentry")]
-                ctxs = [q for q in lc.live_queries()
-                        if q.session_id == "sentry"]
-                return threads, ctxs
-
-            grace = time.monotonic() + 5.0
-            threads_left, ctxs_left = _sentry_residue()
-            while (threads_left or ctxs_left) \
-                    and time.monotonic() < grace:
-                time.sleep(0.1)
-                threads_left, ctxs_left = _sentry_residue()
-            if threads_left:
-                leaks.append(f"sentry thread(s) lingering after "
-                             f"stop(): {threads_left}")
-            if ctxs_left:
-                leaks.append(
-                    f"sentry probe QueryContext(s) still registered "
-                    f"after stop(): "
-                    f"{[(q.session_id, q.query_id) for q in ctxs_left]}")
-            entries = sentry_obj.ledger.entries()
-            if not entries:
-                leaks.append("sentry soak appended no ledger entries")
-            sentry_leg.update({
-                "probe_attempts": probe_n["n"],
-                "windows": sentry_obj.windows,
-                "ledger_entries": len(entries),
-                "shutdown": "clean" if not any(
-                    "sentry" in leak for leak in leaks) else "leak",
-            })
         cluster_leg = None
         if cluster:
             # the fault-domain leg runs after the engine soak (its own
@@ -490,14 +408,10 @@ def run_sentinel(seconds: float = 60.0, tenants: int = 2,
         }
         if telemetry:
             report["telemetry"] = telem
-        if sentry:
-            report["sentry"] = sentry_leg
         if cluster_leg is not None:
             report["cluster"] = cluster_leg
         return report
     finally:
-        if sentry_obj is not None:
-            sentry_obj.stop(timeout=5.0)
         eng.close()
         disarm_chaos()
         BufferCatalog.reset()
@@ -515,7 +429,6 @@ def main() -> int:
                           rows=args.rows, seed=args.seed, arm=args.arm,
                           max_waves=args.max_waves,
                           telemetry=args.telemetry,
-                          sentry=args.sentry,
                           cluster=args.cluster)
     print(json.dumps(report, indent=2))
     if args.out:
