@@ -10,7 +10,9 @@ encodings).  The split here follows the same line:
   yields a run-descriptor table (a handful of entries per page).
 * **Device** (all per-value work, one shape-bucketed XLA program per
   signature): bit-unpacking of packed runs and PLAIN sections via gather +
-  shift arithmetic over uint32 words, RLE broadcast, dictionary-index
+  shift arithmetic over uint32 words (each value's run attributes come
+  from a prefix sum over run-start marks, never from a search per value),
+  RLE broadcast, dictionary-index
   gather, definition-level decode -> validity, non-null scatter (cumsum
   positions), and physical->carrier finishing (two's-complement bitcasts,
   IEEE-754 float64 reconstruction without 64-bit bitcast, timestamp unit
@@ -29,6 +31,7 @@ reference applies at plan level.
 
 from __future__ import annotations
 
+import math
 import struct
 from dataclasses import dataclass, field
 from functools import partial
@@ -370,27 +373,78 @@ def _pad_pow2(n: int, minimum: int = 8) -> int:
     return 1 << (n - 1).bit_length()
 
 
+def _prefix_sum(x):
+    """``jnp.cumsum(x, axis=1)`` in two levels: inside blocks of 1024
+    columns, then the blocks' carries.  Same sums and the same run time on
+    the chip; the TPU compiler takes under a second for this form at 2^21
+    columns where the flat one takes 6-14 s (PERF.md section 6, PR 32)."""
+    k, n = x.shape
+    block = math.gcd(n, 1024)
+    inner = jnp.cumsum(x.reshape(k, n // block, block), axis=2)
+    total = inner[:, :, -1]
+    carry = jnp.cumsum(total, axis=1) - total
+    return (inner + carry[:, :, None]).reshape(k, n)
+
+
+def _spread_runs(out_start, cols, out_cap):
+    """Run-constant int32 columns at every output position, with no search
+    and no gather: ``q[r] - q[r-1]`` is scattered at ``out_start[r]`` (one
+    update per run, never per value) and a prefix sum carries it to the
+    next run's start.  The sums telescope in wrapping int32 arithmetic, so
+    any 32-bit pattern comes back exactly.  Runs sharing an ``out_start``
+    add up to the last one's value (a zero-length run loses to its
+    successor), table padding (``out_start`` past ``out_cap``) is dropped,
+    and positions before the first run read 0.  Returns one
+    ``int32[out_cap]`` per column."""
+    table = jnp.stack(cols)
+    delta = table - jnp.pad(table, ((0, 0), (1, 0)))[:, :-1]
+    marks = jnp.zeros((len(cols), out_cap), jnp.int32
+                      ).at[:, out_start].add(delta, mode="drop")
+    return tuple(_prefix_sum(marks))
+
+
+def _run_origin(out_start, src_bit, width):
+    """Per run, the bit address its value 0 would have if the run began at
+    output 0: inside a run ``bitpos = origin + idx * width``.  May be
+    negative and pass 2^31 (merged chunk buffers exceed 256 MB), so it is
+    taken in int64 over the run table only and handed on as an int32
+    (word, bit-in-word) pair: 64-bit integers are emulated on the chip."""
+    origin = src_bit - out_start.astype(jnp.int64) * width
+    return ((origin >> 5).astype(jnp.int32), (origin & 31).astype(jnp.int32))
+
+
+def _bit_address(word0, bit0, w, extra_bits=0):
+    """(word index, shift) of bit ``origin + idx * w + extra_bits`` for
+    every output ``idx``.  ``idx`` is split at 32 so that no intermediate
+    leaves int32 while the address itself may pass 2^31 bits."""
+    idx = jnp.arange(word0.shape[0], dtype=jnp.int32)
+    t = (idx & 31) * w + bit0 + extra_bits
+    return word0 + (idx >> 5) * w + (t >> 5), (t & 31).astype(jnp.uint32)
+
+
+def _window_u32(words, word0, bit0, w, extra_bits=0):
+    """The 32 bits at :func:`_bit_address`: two word reads, shifted
+    together."""
+    w0, sh = _bit_address(word0, bit0, w, extra_bits)
+    w0 = jnp.clip(w0, 0, words.shape[0] - 2)
+    return (words[w0] >> sh) | jnp.where(
+        sh == 0, jnp.uint32(0), words[w0 + 1] << (jnp.uint32(32) - sh))
+
+
 @partial(jax.jit, static_argnames=("out_cap",))
 def _expand_runs_u32(words, out_start, src_bit, width, rle_val, out_cap):
     """Expand a run-descriptor table into ``uint32[out_cap]`` raw values:
-    bit-packed runs gather+shift from the word buffer, RLE runs broadcast.
-    Out-of-range tail values are garbage — callers mask them."""
-    idx = jnp.arange(out_cap, dtype=jnp.int32)
-    r = jnp.clip(jnp.searchsorted(out_start, idx, side="right") - 1,
-                 0, out_start.shape[0] - 1)
-    local = (idx - out_start[r]).astype(jnp.int64)
-    w = width[r]
-    bitpos = src_bit[r] + local * w
-    w0 = jnp.clip((bitpos >> 5).astype(jnp.int32), 0, words.shape[0] - 2)
-    sh = (bitpos & 31).astype(jnp.uint32)
-    lo = words[w0] >> sh
-    hi = jnp.where(sh == 0, jnp.uint32(0),
-                   words[w0 + 1] << (jnp.uint32(32) - sh))
-    raw = lo | hi
+    bit-packed runs read+shift from the word buffer, RLE runs broadcast.
+    Values before the first run and past the last are garbage — callers
+    mask them."""
+    word0, bit0 = _run_origin(out_start, src_bit, width)
+    word0, bit0, w, rle = _spread_runs(
+        out_start, (word0, bit0, width, rle_val), out_cap)
+    raw = _window_u32(words, word0, bit0, w)
     wu = w.astype(jnp.uint32)
     mask = jnp.where(wu >= 32, jnp.uint32(0xFFFFFFFF),
                      (jnp.uint32(1) << wu) - jnp.uint32(1))
-    return jnp.where(w == 0, rle_val[r].astype(jnp.uint32), raw & mask)
+    return jnp.where(w == 0, rle.astype(jnp.uint32), raw & mask)
 
 
 @partial(jax.jit, static_argnames=("out_cap", "width"))
@@ -398,23 +452,14 @@ def _expand_flba(words, out_start, src_bit, out_cap, width):
     """FIXED_LEN_BYTE_ARRAY expansion: each value is `width` big-endian
     two's-complement bytes (parquet decimal storage) -> sign-extended
     (lo, hi) uint64 words.  Static byte loop (width <= 16)."""
-    idx = jnp.arange(out_cap, dtype=jnp.int32)
-    r = jnp.clip(jnp.searchsorted(out_start, idx, side="right") - 1,
-                 0, out_start.shape[0] - 1)
-    local = (idx - out_start[r]).astype(jnp.int64)
-    base = src_bit[r] + local * (width * 8)
+    word0, bit0 = _spread_runs(
+        out_start, _run_origin(out_start, src_bit, width * 8), out_cap)
     lo = jnp.zeros(out_cap, jnp.uint64)
     hi = jnp.zeros(out_cap, jnp.uint64)
     first_byte = None
     for k in range(width):
-        bitpos = base + k * 8
-        w0 = jnp.clip((bitpos >> 5).astype(jnp.int32), 0,
-                      words.shape[0] - 2)
-        sh = (bitpos & 31).astype(jnp.uint32)
-        b = ((words[w0] >> sh)
-             | jnp.where(sh == 0, jnp.uint32(0),
-                         words[w0 + 1] << (jnp.uint32(32) - sh))
-             ) & jnp.uint32(0xFF)
+        b = _window_u32(words, word0, bit0, width * 8, k * 8
+                        ) & jnp.uint32(0xFF)
         if k == 0:
             first_byte = b
         b64 = b.astype(jnp.uint64)
@@ -470,18 +515,10 @@ def _expand_runs_u64(words, out_start, src_bit, out_cap):
     """64-bit PLAIN expansion: each value is assembled from two 32-bit
     window reads (sections are byte- but not word-aligned, so each window
     may itself span two words)."""
-    idx = jnp.arange(out_cap, dtype=jnp.int32)
-    r = jnp.clip(jnp.searchsorted(out_start, idx, side="right") - 1,
-                 0, out_start.shape[0] - 1)
-    local = (idx - out_start[r]).astype(jnp.int64)
-    bitpos = src_bit[r] + local * 64
-    w0 = jnp.clip((bitpos >> 5).astype(jnp.int32), 0, words.shape[0] - 2)
-    sh = (bitpos & 31).astype(jnp.uint32)
-    lo0 = (words[w0] >> sh) | jnp.where(
-        sh == 0, jnp.uint32(0), words[w0 + 1] << (jnp.uint32(32) - sh))
-    w1 = jnp.clip(w0 + 1, 0, words.shape[0] - 2)
-    hi0 = (words[w1] >> sh) | jnp.where(
-        sh == 0, jnp.uint32(0), words[w1 + 1] << (jnp.uint32(32) - sh))
+    word0, bit0 = _spread_runs(
+        out_start, _run_origin(out_start, src_bit, 64), out_cap)
+    lo0 = _window_u32(words, word0, bit0, 64)
+    hi0 = _window_u32(words, word0, bit0, 64, 32)
     return (hi0.astype(jnp.uint64) << jnp.uint64(32)) | lo0.astype(jnp.uint64)
 
 
@@ -525,12 +562,10 @@ def gather_string_matrix(words, starts, lens, width, cap):
 @jax.jit
 def _remap_indices(idx, group_starts, remap_offsets, remap):
     """Apply per-row-group dictionary remapping: dense value j belongs to
-    group g = searchsorted(group_starts, j); its unioned-dictionary index
-    is remap[remap_offsets[g] + local_idx]."""
-    j = jnp.arange(idx.shape[0], dtype=jnp.int32)
-    g = jnp.clip(jnp.searchsorted(group_starts, j, side="right") - 1,
-                 0, remap_offsets.shape[0] - 1)
-    pos = jnp.clip(remap_offsets[g] + idx, 0, remap.shape[0] - 1)
+    the last group g with group_starts[g] <= j; its unioned-dictionary
+    index is remap[remap_offsets[g] + local_idx]."""
+    (offset,) = _spread_runs(group_starts, (remap_offsets,), idx.shape[0])
+    pos = jnp.clip(offset + idx, 0, remap.shape[0] - 1)
     return remap[pos]
 
 
@@ -569,8 +604,8 @@ class _ChunkPlan:
     # merged-plan only: per-row-group dictionaries usually diverge (each
     # writer chunk builds its own, in first-occurrence order), so indices
     # are remapped ON DEVICE into a unioned global dictionary:
-    # value j of the dense stream belongs to group g = searchsorted(
-    # group_starts, j); its global index is remap[remap_offsets[g] + idx]
+    # value j of the dense stream belongs to the last group g with
+    # group_starts[g] <= j; its global index is remap[remap_offsets[g] + idx]
     remap: Optional[np.ndarray] = None            # int32, concat per group
     remap_offsets: Optional[np.ndarray] = None    # int32[G]
     group_starts: Optional[np.ndarray] = None     # int32[G] dense offsets

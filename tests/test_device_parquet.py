@@ -402,3 +402,277 @@ def test_byte_array_walk_native_matches_python():
     # truncation must raise, not overrun
     with pytest.raises(ValueError):
         native.byte_array_walk(data[:-1], len(vals))
+
+
+# --------------------------------------------------------------------------
+# PR 32: the expanders find each value's run by prefix sums.  The reference
+# below walks the run table in a python loop (no search, no prefix sum).
+# --------------------------------------------------------------------------
+
+_BIG = np.iinfo(np.int32).max
+
+
+def _pad_table(cols, pad_to):
+    """Run-table columns as ``_runs_to_device`` pads them: INT32_MAX starts,
+    zeros elsewhere."""
+    import jax.numpy as jnp
+    out = []
+    for k, c in enumerate(cols):
+        c = np.asarray(c)
+        full = np.full(pad_to, _BIG if k == 0 else 0, c.dtype)
+        full[:len(c)] = c
+        out.append(jnp.asarray(full))
+    return out
+
+
+def _bits_of(buf: np.ndarray) -> np.ndarray:
+    return np.unpackbits(buf.view(np.uint8), bitorder="little")
+
+
+def _ref_runs(out_start, total):
+    """(run, first output, one past its last output) for every run that
+    owns outputs; a run owns [its start, the next run's start)."""
+    ends = list(out_start[1:]) + [total]
+    return [(r, s, e) for r, (s, e) in enumerate(zip(out_start, ends))
+            if e > s]
+
+
+def _ref_expand(buf, out_start, src_bit, width, rle_val, total):
+    bits = _bits_of(buf)
+    out = np.zeros(total, np.uint64)
+    for r, s, e in _ref_runs(out_start, total):
+        w = int(width[r])
+        if w == 0:
+            out[s:e] = np.uint32(rle_val[r])
+            continue
+        pos = int(src_bit[r]) + np.arange(e - s)[:, None] * w + np.arange(w)
+        out[s:e] = (bits[pos].astype(np.uint64)
+                    << np.arange(w, dtype=np.uint64)).sum(axis=1)
+    return out
+
+
+def _random_words(rng, nwords):
+    return rng.integers(0, 2**32, nwords, dtype=np.uint32)
+
+
+def _hybrid_table(rng, spec, total):
+    """spec: list of (count, width) with width 0 = RLE.  Packed runs are
+    laid out back to back from bit 40, as pages lay them."""
+    out_start, src_bit, width, rle_val = [], [], [], []
+    pos, bit = 0, 40
+    for count, w in spec:
+        out_start.append(pos)
+        width.append(w)
+        if w == 0:
+            src_bit.append(0)
+            rle_val.append(int(rng.integers(0, 2**31)))
+        else:
+            src_bit.append(bit)
+            rle_val.append(0)
+            bit += count * w + int(rng.integers(0, 3)) * 8
+        pos += count
+    assert pos == total
+    return (np.asarray(out_start, np.int32), np.asarray(src_bit, np.int64),
+            np.asarray(width, np.int32), np.asarray(rle_val, np.int32), bit)
+
+
+_U32_CASES = {
+    "width1": [(64, 1), (40, 1)],
+    "width7": [(100, 7)],
+    "width12_rle_mixed": [(30, 12), (500, 0), (77, 12), (3, 0), (90, 12)],
+    "width31": [(50, 31), (9, 0), (41, 31)],
+    "width32": [(33, 32), (67, 32)],
+    "single_rle_run": [(128, 0)],
+    "zero_length_run": [(40, 5), (0, 9), (0, 0), (60, 3), (28, 0)],
+    "all_widths": [(8, w) for w in range(1, 33)],
+    "many_runs": [(3, (k * 5) % 13) for k in range(300)],
+}
+
+
+@pytest.mark.parametrize("pad", [False, True], ids=["exact", "padded"])
+@pytest.mark.parametrize("case", sorted(_U32_CASES))
+def test_expand_runs_u32_matches_run_loop(case, pad):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io_.device_parquet import (_expand_runs_u32,
+                                                     _pad_pow2)
+    spec = _U32_CASES[case]
+    total = sum(c for c, _ in spec)
+    rng = _rng(len(case))
+    out_start, src_bit, width, rle_val, bit = _hybrid_table(rng, spec, total)
+    buf = _random_words(rng, bit // 32 + 4)
+    want = _ref_expand(buf, out_start, src_bit, width, rle_val, total)
+    cols = (out_start, src_bit, width, rle_val)
+    out_cap = _pad_pow2(total)
+    if pad:
+        cols = _pad_table(cols, _pad_pow2(len(out_start) + 1, 4))
+        out_cap *= 2
+    got = _expand_runs_u32(jnp.asarray(buf), *map(jnp.asarray, cols),
+                           out_cap=out_cap)
+    assert got.dtype == jnp.uint32 and got.shape == (out_cap,)
+    np.testing.assert_array_equal(np.asarray(got)[:total], want)
+
+
+@pytest.mark.parametrize("case", ["single_run", "zero_length_run",
+                                  "padded_table", "first_run_late",
+                                  "negative_and_wide_values"])
+def test_spread_runs_matches_run_loop(case):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io_.device_parquet import _spread_runs
+    out_cap = 96
+    starts = {"single_run": [0],
+              "zero_length_run": [0, 10, 10, 10, 50],
+              "padded_table": [0, 7, 31, _BIG, _BIG, _BIG, _BIG, _BIG],
+              "first_run_late": [5, 6, 90],
+              "negative_and_wide_values": [0, 1, 2, 64, 95]}[case]
+    rng = _rng(len(case))
+    a = rng.integers(-2**31, 2**31, len(starts)).astype(np.int32)
+    b = rng.integers(0, 33, len(starts)).astype(np.int32)
+    got_a, got_b = _spread_runs(jnp.asarray(starts, jnp.int32),
+                                (jnp.asarray(a), jnp.asarray(b)), out_cap)
+    live = [s for s in starts if s != _BIG]
+    want_a = np.zeros(out_cap, np.int32)     # before the first run: 0
+    want_b = np.zeros(out_cap, np.int32)
+    for r, s, e in _ref_runs(live, out_cap):
+        want_a[s:e], want_b[s:e] = a[r], b[r]
+    np.testing.assert_array_equal(np.asarray(got_a), want_a)
+    np.testing.assert_array_equal(np.asarray(got_b), want_b)
+
+
+@pytest.mark.parametrize("width", [1, 12, 31, 32, 64, 128])
+@pytest.mark.parametrize("src_bit", [2**31 + 40, 2**35 + 8, 17])
+def test_bit_address_past_two_to_the_31(src_bit, width):
+    """A merged chunk buffer can pass 256 MB: ``src_bit`` is int64 and the
+    (word, shift) pair must not wrap.  Checked on the address itself (a
+    buffer that large does not belong in a test)."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io_.device_parquet import (_bit_address,
+                                                     _run_origin,
+                                                     _spread_runs)
+    out_cap = 4096
+    out_start = np.asarray([0, 1000, 1000, 3000], np.int32)
+    bits = np.asarray([src_bit + 64 * k for k in range(4)], np.int64)
+    bits[1] = 0                                   # zero-length: must lose
+    origin = _run_origin(jnp.asarray(out_start), jnp.asarray(bits), width)
+    word0, bit0 = _spread_runs(jnp.asarray(out_start), origin, out_cap)
+    w0, sh = _bit_address(word0, bit0, width, 8)
+    got = np.asarray(w0).astype(object) * 32 + np.asarray(sh).astype(object)
+    for r, s, e in _ref_runs(out_start.tolist(), out_cap):
+        want = [int(bits[r]) + (i - s) * width + 8 for i in range(s, e)]
+        assert list(got[s:e]) == want, (r, s)
+
+
+@pytest.mark.parametrize("case", ["one_section", "three_sections_unaligned",
+                                  "padded_table"])
+def test_expand_runs_u64_matches_run_loop(case):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io_.device_parquet import _expand_runs_u64
+    rng = _rng(64)
+    counts = {"one_section": [100], "three_sections_unaligned": [10, 33, 21],
+              "padded_table": [7, 50]}[case]
+    total = sum(counts)
+    out_start = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
+    # PLAIN sections are byte- but not word-aligned
+    src_bit, bit = [], 8
+    for k, c in enumerate(counts):
+        src_bit.append(bit)
+        bit += c * 64 + 8 * (k + 1)
+    src_bit = np.asarray(src_bit, np.int64)
+    buf = _random_words(rng, bit // 32 + 4)
+    want = _ref_expand(buf, out_start, src_bit, [64] * len(counts),
+                       [0] * len(counts), total)
+    cols = (out_start, src_bit)
+    if case == "padded_table":
+        cols = _pad_table(cols, 8)
+    got = _expand_runs_u64(jnp.asarray(buf), *map(jnp.asarray, cols),
+                           out_cap=128)
+    np.testing.assert_array_equal(np.asarray(got)[:total], want)
+
+
+@pytest.mark.parametrize("width", [1, 4, 8, 11, 16])
+def test_expand_flba_matches_run_loop(width):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io_.device_parquet import _expand_flba
+    rng = _rng(width)
+    counts = [9, 40, 15]
+    total = sum(counts)
+    out_start = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
+    src_byte, at = [], 3
+    for c in counts:
+        src_byte.append(at)
+        at += c * width + 5
+    buf = _random_words(rng, at // 4 + 4)
+    raw = buf.view(np.uint8)
+    lo, hi = _expand_flba(
+        jnp.asarray(buf),
+        *_pad_table((out_start, np.asarray(src_byte, np.int64) * 8), 4),
+        out_cap=64, width=width)
+    lo, hi = np.asarray(lo), np.asarray(hi)
+    for r, s, e in _ref_runs(out_start.tolist(), total):
+        for i in range(s, e):
+            b = raw[src_byte[r] + (i - s) * width:][:width].tobytes()
+            want = int.from_bytes(b, "big", signed=True) & ((1 << 128) - 1)
+            assert (int(hi[i]) << 64 | int(lo[i])) == want, (width, i)
+
+
+@pytest.mark.parametrize("groups", [1, 4])
+def test_remap_indices_matches_run_loop(groups):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io_.device_parquet import _remap_indices
+    rng = _rng(groups)
+    n = 256
+    sizes = rng.integers(3, 40, groups)
+    remap = rng.integers(0, 1000, int(sizes.sum())).astype(np.int32)
+    offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int32)
+    starts = np.sort(np.concatenate(
+        [[0], rng.choice(np.arange(1, 200), groups - 1, replace=False)]
+    )).astype(np.int32)
+    idx = np.zeros(n, np.int32)
+    want = np.zeros(n, np.int32)
+    for g, s, e in _ref_runs(starts.tolist(), n):
+        idx[s:e] = rng.integers(0, sizes[g], e - s)
+        want[s:e] = remap[offsets[g] + idx[s:e]]
+    got = _remap_indices(jnp.asarray(idx), *_pad_table((starts, offsets), 4),
+                         jnp.asarray(remap))
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _count_primitives(jaxpr, counts):
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _count_primitives(inner, counts)
+    return counts
+
+
+@pytest.mark.parametrize("which,word_reads", [
+    ("u32", 2), ("u64", 4), ("flba", 2 * 11), ("remap", 1)])
+def test_expanders_hold_no_search(which, word_reads):
+    """No expander maps an output to its run by a search (a ``while`` of
+    gathers on the chip, 0.3 s a call: PERF.md section 6, PR 32) or a sort,
+    and the only gathers left are the reads of the data itself."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io_ import device_parquet as dp
+    words = jnp.zeros(64, jnp.uint32)
+    out_start = jnp.asarray([0, 10, _BIG, _BIG], jnp.int32)
+    src_bit = jnp.asarray([8, 4000, 0, 0], jnp.int64)
+    i32 = jnp.asarray([12, 0, 0, 0], jnp.int32)
+    jaxpr = {
+        "u32": lambda: jax.make_jaxpr(
+            lambda *a: dp._expand_runs_u32(*a, out_cap=64))(
+                words, out_start, src_bit, i32, i32),
+        "u64": lambda: jax.make_jaxpr(
+            lambda *a: dp._expand_runs_u64(*a, out_cap=64))(
+                words, out_start, src_bit),
+        "flba": lambda: jax.make_jaxpr(
+            lambda *a: dp._expand_flba(*a, out_cap=64, width=11))(
+                words, out_start, src_bit),
+        "remap": lambda: jax.make_jaxpr(dp._remap_indices)(
+            jnp.zeros(64, jnp.int32), out_start, i32, i32),
+    }[which]()
+    counts = _count_primitives(jaxpr.jaxpr, {})
+    assert not {"while", "sort", "scan", "cond"} & set(counts), counts
+    assert counts.get("gather", 0) == word_reads, counts
