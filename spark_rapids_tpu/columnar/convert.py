@@ -375,6 +375,8 @@ def arrow_to_device_column(arr, capacity: int, conf=None) -> DeviceColumn:
     if isinstance(arr, pa.ChunkedArray):
         arr = arr.combine_chunks()
     dtype = T.from_arrow(arr.type)
+    if pa.types.is_dictionary(arr.type) and not is_string_like(dtype):
+        arr = arr.cast(arr.type.value_type)   # only strings stay encoded
     n = len(arr)
     valid_np = np.zeros(capacity, dtype=bool)
     if n:
@@ -403,6 +405,8 @@ def arrow_to_device_column(arr, capacity: int, conf=None) -> DeviceColumn:
             enc = encode_string_arrow(arr, dtype, capacity, conf=conf)
             if enc is not None:
                 return enc
+        if pa.types.is_dictionary(arr.type):
+            arr = arr.cast(arr.type.value_type)   # encoding declined
         chars, lengths = _strings_to_matrix(arr, capacity)
         return DeviceColumn(dtype, jnp.asarray(chars), validity,
                             lengths=jnp.asarray(lengths))

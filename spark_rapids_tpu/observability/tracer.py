@@ -102,7 +102,8 @@ TRACING = {"on": False, "profiler": False}
 #: the report treat unknown categories as opaque)
 CATEGORIES = ("query", "plan", "task", "op", "stage", "dispatch", "compile",
               "scan", "sync", "h2d", "d2h", "spill", "shuffle", "sem_wait",
-              "fault", "queue", "encode", "admission", "cancel", "fatal")
+              "fault", "queue", "encode", "admission", "cancel", "fatal",
+              "broadcast", "join")
 
 #: every profiler annotation's name starts with this
 PROFILER_PREFIX = "srt:"

@@ -32,7 +32,7 @@ import numpy as np
 from .. import types as T
 from ..columnar.column import DeviceColumn
 from .ranks import (column_sort_keys, dense_rank_columns, lex_sort,
-                    stable_argsort, tuple_searchsorted)
+                    prefix_sum, stable_argsort, tuple_searchsorted)
 
 
 def _scope(xp, name: str):
@@ -122,6 +122,7 @@ class JoinInfo(NamedTuple):
     total: "np.ndarray"         # int64 scalar: total inner pairs
     n_unmatched_l: "np.ndarray"  # int64 scalar
     n_unmatched_b: "np.ndarray"  # int64 scalar
+    n_null_keys: "np.ndarray"   # int64 scalar: live probe rows, a key NULL
 
     def sizing_scalars(self) -> tuple:
         """The three output-sizing scalars — THE one blocking host
@@ -165,7 +166,7 @@ def join_build(xp, lkeys: Sequence[DeviceColumn], rkeys: Sequence[DeviceColumn],
     lo = xp.searchsorted(sb, lrank, side="left")
     hi = xp.searchsorted(sb, lrank, side="right")
     counts = (hi - lo).astype(xp.int64)
-    csum = xp.cumsum(counts)
+    csum = prefix_sum(xp, counts)
     total = csum[lcap - 1] if lcap else xp.asarray(0, dtype=xp.int64)
 
     sp = xp.sort(lrank)
@@ -176,8 +177,9 @@ def join_build(xp, lkeys: Sequence[DeviceColumn], rkeys: Sequence[DeviceColumn],
     b_unmatched = rmask & ~b_matched
     n_unl = xp.sum(l_unmatched.astype(xp.int64))
     n_unb = xp.sum(b_unmatched.astype(xp.int64))
+    n_null = xp.sum((lmask & (lrank == -1)).astype(xp.int64))
     return JoinInfo(counts, csum, lo, perm_b, l_unmatched, b_unmatched,
-                    total, n_unl, n_unb)
+                    total, n_unl, n_unb, n_null)
 
 
 class JoinBuildSide(NamedTuple):
@@ -225,8 +227,20 @@ def join_search_keys(xp, key_cols: Sequence[DeviceColumn],
                 keys.append(c.join_codes.astype(xp.int64))
                 continue
             c = c.materialized()
+        if _is_narrow_int(c):
+            # an int32 key stays int32 (both sides come through here): one
+            # sort operand and one gather a search round where the int64
+            # form, split for the chip, costs two of each
+            keys.append(c.data.astype(xp.int32))
+            continue
         keys.extend(column_sort_keys(xp, c))
     return keys
+
+
+def _is_narrow_int(col: DeviceColumn) -> bool:
+    return (col.lengths is None and col.data is not None
+            and isinstance(col.dtype, (T.ByteType, T.ShortType,
+                                       T.IntegerType, T.DateType)))
 
 
 def _bad_rows(xp, key_cols: Sequence[DeviceColumn], mask, null_safe: bool):
@@ -321,7 +335,7 @@ def probe_join_info(xp, lkeys: Sequence[DeviceColumn], lmask, rmask,
             hit = hit & (s[loc] == q)
         hi = xp.where(hit, build.run_end[loc], lo)
     counts = xp.where(hit, hi - lo, 0).astype(xp.int64)
-    csum = xp.cumsum(counts)
+    csum = prefix_sum(xp, counts)
     total = csum[lcap - 1] if lcap else xp.asarray(0, dtype=xp.int64)
     if need_l_unmatched:
         l_unmatched = lmask & (counts == 0)
@@ -358,8 +372,9 @@ def probe_join_info(xp, lkeys: Sequence[DeviceColumn], lmask, rmask,
     else:
         b_unmatched = xp.zeros(rcap, dtype=bool)
         n_unb = xp.asarray(0, dtype=xp.int64)
+    n_null = xp.sum((bad & lmask).astype(xp.int64))
     return JoinInfo(counts, csum, lo.astype(xp.int64), build.perm_b,
-                    l_unmatched, b_unmatched, total, n_unl, n_unb)
+                    l_unmatched, b_unmatched, total, n_unl, n_unb, n_null)
 
 
 class PairMaps(NamedTuple):

@@ -51,6 +51,9 @@ def lex_sort(xp, keys):
     two's-complement low words compare unsigned).  Sorted key values are
     reconstructed from the sorted pairs, so callers see the same
     (perm, sorted_keys) contract.
+
+    Between 2^14 and 2^16 rows the chip's sort is ``_network_sort``, the
+    same order from a program that compiles at once (``_NETWORK_ROWS``).
     """
     keys = list(keys)
     if xp.__name__ == "numpy":
@@ -74,8 +77,11 @@ def lex_sort(xp, keys):
         else:
             sort_keys.append(k)
             split.append(False)
-    out = jax.lax.sort(tuple(sort_keys) + (iota,), num_keys=len(sort_keys),
-                       is_stable=True)
+    if _network_sorts(n, sort_keys):
+        out = _network_sort(tuple(sort_keys) + (iota,))
+    else:
+        out = jax.lax.sort(tuple(sort_keys) + (iota,),
+                           num_keys=len(sort_keys), is_stable=True)
     perm = out[-1]
     sorted_keys = []
     idx = 0
@@ -94,6 +100,68 @@ def lex_sort(xp, keys):
             sorted_keys.append(out[idx])
             idx += 1
     return perm, sorted_keys
+
+
+#: rows between which ``lex_sort`` is the rolled network below.  The chip's
+#: compiler takes 8 s (2^14 rows, 3 operands) to 100 s (2^15 rows and
+#: above, 5 operands; ~10 s more an operand whatever the row count) for ONE
+#: ``lax.sort`` program and 1.3 s at 2^13 rows; the network compiles in
+#: under a second at any width (compiled for a described v5e, PERF.md
+#: section 6, PR 33).  Above 2^16 rows ``lax.sort`` stays: its run time is
+#: what counts there.
+_NETWORK_ROWS = (1 << 14, 1 << 16)
+
+
+def _network_sorts(n: int, sort_keys) -> bool:
+    """Trace-time choice of the sort's form.  Not on XLA:CPU, whose
+    ``lax.sort`` compiles at once; integer and bool keys only (a NaN has
+    no place in the network's total order)."""
+    import jax
+    return (_NETWORK_ROWS[0] <= n <= _NETWORK_ROWS[1] and n & (n - 1) == 0
+            and jax.default_backend() != "cpu"
+            and all(k.dtype.kind in "biu" for k in sort_keys))
+
+
+def _network_sort(operands):
+    """Ascending lexicographic sort of ``operands`` (most significant
+    first, every one a key, the last one making the tuples distinct: the
+    row's index, so the order is the stable order) as a bitonic network in
+    ONE rolled loop: log2(n)(log2(n)+1)/2 compare-exchange steps, each a
+    partner read at distance ``j`` (two contiguous slices of the doubled
+    array, no gather) and a select.  The program is the loop's body, so it
+    compiles in under a second where ``lax.sort`` takes minutes."""
+    import jax
+    import jax.numpy as jnp
+    n = operands[0].shape[0]
+    spans, dists = [], []
+    for a in range(1, n.bit_length()):
+        for b in range(a - 1, -1, -1):
+            spans.append(1 << a)
+            dists.append(1 << b)
+    spans = jnp.asarray(spans, jnp.int32)
+    dists = jnp.asarray(dists, jnp.int32)
+    iota = jnp.arange(n, dtype=jnp.int32)
+
+    def step(s, ops):
+        k, j = spans[s], dists[s]
+        low = (iota & j) == 0           # the pair's lower index
+
+        def partner(x):
+            twice = jnp.concatenate([x, x])
+            return jnp.where(low, jax.lax.dynamic_slice(twice, (j,), (n,)),
+                             jax.lax.dynamic_slice(twice, (n - j,), (n,)))
+        theirs = tuple(partner(x) for x in ops)
+        less = jnp.zeros((n,), jnp.bool_)
+        same = jnp.ones((n,), jnp.bool_)
+        for a, b in zip(theirs, ops):
+            less = less | (same & (a < b))
+            same = same & (a == b)
+        # ascending blocks keep the smaller tuple at the lower index
+        keeps_smaller = ((iota & k) == 0) == low
+        take = (less == keeps_smaller) & ~same
+        return tuple(jnp.where(take, a, b) for a, b in zip(theirs, ops))
+
+    return jax.lax.fori_loop(0, int(spans.shape[0]), step, tuple(operands))
 
 
 def tuple_searchsorted(xp, sorted_keys, query_keys, side="left",
@@ -135,6 +203,23 @@ def tuple_searchsorted(xp, sorted_keys, query_keys, side="left",
         lo = xp.where(go, mid + 1, lo)
         hi = xp.where(stay, mid, hi)
     return lo
+
+
+def prefix_sum(xp, x):
+    """``xp.cumsum(x)`` of a 1-D array, in two levels on the
+    device backend: inside blocks of 1,024 rows, then the blocks' carries.
+    The same sums at the same run time; the TPU compiler takes about a
+    second for this form where the flat sum takes 20-40 s for int64 and
+    4-14 s for int32 at 2^18-2^21 rows (PERF.md section 6, PR 32 and
+    PR 33) - per program that holds one, in every cold process."""
+    n = x.shape[0]
+    block = 1024
+    if xp.__name__ == "numpy" or n % block or n <= block:
+        return xp.cumsum(x)
+    inner = xp.cumsum(x.reshape(n // block, block), axis=1)
+    total = inner[:, -1]
+    carry = xp.cumsum(total) - total
+    return (inner + carry[:, None]).reshape(n)
 
 
 def dense_rank_from_sorted(xp, sorted_boundary_flags):

@@ -1228,6 +1228,17 @@ class HashAggregateExec(PhysicalPlan):
 
     # --- execute ----------------------------------------------------------
     def execute(self, pid: int, tctx: TaskContext):
+        """Counts the rows of the group table a final or complete aggregate
+        hands on (``aggGroupRows`` of last_query_metrics; the count is read
+        when the task has ended)."""
+        if self.mode not in ("final", "complete"):
+            yield from self._execute(pid, tctx)
+            return
+        for out in self._execute(pid, tctx):
+            tctx.inc_metric_late("aggGroupRows", out.num_rows)
+            yield out
+
+    def _execute(self, pid: int, tctx: TaskContext):
         """Out-of-core contract (``GpuMergeAggregateIterator``
         ``aggregate.scala:711-792``): inputs are registered as spillable the
         moment they arrive, and every device kernel runs under the retry

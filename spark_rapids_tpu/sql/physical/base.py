@@ -47,9 +47,11 @@ class TaskContext:
         if parent is not None:
             self.metrics: Dict[str, float] = parent.metrics
             self._metrics_lock = parent._metrics_lock
+            self._late_metrics: list = parent._late_metrics
         else:
             self.metrics = {}
             self._metrics_lock = threading.Lock()
+            self._late_metrics = []
         from ...config import METRICS_LEVEL, SERVING_TENANT
         self._rank = _METRIC_RANK.get(
             str(self.conf.get(METRICS_LEVEL)).upper(), 1)
@@ -77,6 +79,22 @@ class TaskContext:
             return
         with self._metrics_lock:
             self.metrics[name] = self.metrics.get(name, 0.0) + value
+
+    def inc_metric_late(self, name: str, value, level: str = "MODERATE"):
+        """Adds a count that is still on the device (a batch's
+        ``num_rows``).  It is read by ``settle_metrics`` when the task has
+        ended, never here: no counter makes the host wait for a program."""
+        if _METRIC_RANK.get(level, 1) > self._rank:
+            return
+        with self._metrics_lock:
+            self._late_metrics.append((name, value))
+
+    def settle_metrics(self) -> None:
+        with self._metrics_lock:
+            late = list(self._late_metrics)
+            del self._late_metrics[:]
+        for name, value in late:
+            self.inc_metric(name, float(value))
 
     # --- thread-local current task (Spark TaskContext.get() analog) -------
     _tls = threading.local()
@@ -320,6 +338,8 @@ class PhysicalPlan:
             arm_oom_injection(0, 0)
             TaskContext._set_current(prev_ctx)
             sem.release_if_necessary(pid)
+            if not failed:
+                tctx.settle_metrics()
             # merge under a lock: concurrent tasks of the parallel
             # scheduler all land their metrics on this one plan object
             with _PLAN_METRICS_LOCK:

@@ -727,20 +727,28 @@ class BroadcastExchangeExec(PhysicalPlan):
                         from ...memory import retention as _ret
                         _ret.pin_batch(self._cached)
                         return self._cached
-            batches = []
-            with _trace.span("shuffle", "broadcast.materialize"):
-                for cpid in range(self.children[0].num_partitions()):
-                    ctctx = TaskContext(cpid, tctx.conf, parent=tctx)
-                    with ctctx.as_current():
-                        batches.extend(
-                            self.children[0].execute(cpid, ctctx))
-            from ...parallel import placement
-            batches = placement.gather(batches)
-            if not batches:
-                self._cached = empty_batch_for(self.output)
-            else:
-                self._cached = (ColumnarBatch.concat(batches)
-                                if len(batches) > 1 else batches[0])
+            with _trace.span("broadcast", "build"):
+                batches = []
+                with _trace.span("broadcast", "build.collect"):
+                    for cpid in range(self.children[0].num_partitions()):
+                        ctctx = TaskContext(cpid, tctx.conf, parent=tctx)
+                        with ctctx.as_current():
+                            batches.extend(
+                                self.children[0].execute(cpid, ctctx))
+                # one batch on the device for every probe to share: the
+                # pieces brought to one chip and packed without padding
+                with _trace.span("broadcast", "build.upload"):
+                    from ...parallel import placement
+                    batches = placement.gather(batches)
+                    if not batches:
+                        self._cached = empty_batch_for(self.output)
+                    else:
+                        self._cached = (ColumnarBatch.concat(batches)
+                                        if len(batches) > 1 else batches[0])
+            from ...memory.spill import batch_device_bytes
+            tctx.inc_metric_late("broadcastBuildRows", self._cached.num_rows)
+            tctx.inc_metric("broadcastBuildBytes",
+                            batch_device_bytes(self._cached))
             if share_key is not None:
                 from ...serving import broadcast_cache as _bc
                 _bc.store(share_key, self._cached,

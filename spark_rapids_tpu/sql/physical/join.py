@@ -57,6 +57,15 @@ _JOIN_SELECTIVITY: Dict[tuple, float] = {}
 _SEL_LOCK = threading.Lock()
 
 
+def _join_estimate(children) -> Optional[int]:
+    """A join's size for the side choice of the join above it: its larger
+    child's (a key join keeps about its probe side's rows; the filters
+    that shrink it are not estimated), unknown where neither is known."""
+    known = [b for b in (c.estimate_bytes() for c in children)
+             if b is not None]
+    return max(known) if known else None
+
+
 def record_selectivity(spec_key, sel: float,
                        generation: Optional[int] = None) -> None:
     """Record observed selectivity, max-joined: a low-match tail batch
@@ -92,6 +101,18 @@ def clear_selectivity() -> None:
         _JOIN_SELECTIVITY.clear()
 
 
+#: ``_strategy_counted_in`` before any collect (a query context may be None)
+_NO_QUERY = object()
+
+#: two shuffled sides within this factor of each other are of like size, and
+#: their join keeps the text's order.  Flipped at 4.5x (TPC-H Q3's joined
+#: customer and orders under ``lineitem``, 16 columns, every side shuffled)
+#: a collect went from 0.849 to 1.314 s on four chips and from 2.25 to 1.91 s
+#: on one (PERF.md section 6, PR 33); a star join's dimension is 14x to
+#: 1000x smaller than its fact table
+_LIKE_SIZE = 8
+
+
 class BaseJoinExec(PhysicalPlan):
     """Shared machinery: side normalization (right joins flip to left),
     output schema, pair gathering, residual-condition assembly."""
@@ -99,7 +120,8 @@ class BaseJoinExec(PhysicalPlan):
     def __init__(self, how: str, left_keys: Sequence[Expression],
                  right_keys: Sequence[Expression],
                  condition: Optional[Expression],
-                 left: PhysicalPlan, right: PhysicalPlan, backend=TPU):
+                 left: PhysicalPlan, right: PhysicalPlan, backend=TPU,
+                 build_left: bool = False):
         super().__init__(left, right)
         self.backend = backend
         self.how = how
@@ -108,12 +130,17 @@ class BaseJoinExec(PhysicalPlan):
         #: exactly once even when the parallel partition scheduler drives
         #: several probe partitions into execute concurrently
         self._setup_lock = threading.Lock()
-        self._flipped = how == "right"
+        self._strategy_counted_in = _NO_QUERY
+        if build_left and how != "inner":
+            raise ValueError(f"build_left is for inner joins, not {how!r}")
+        self._flipped = how == "right" or build_left
         if self._flipped:
-            # right outer == left outer with sides swapped + column reorder
+            # right outer == left outer with sides swapped + column reorder;
+            # an inner join that builds on its left child (the smaller one,
+            # plan_join) is the same swap with nothing to null-extend
             self._probe, self._build = right, left
             self._probe_keys, self._build_keys = list(right_keys), list(left_keys)
-            self._norm_how = "left"
+            self._norm_how = "inner" if build_left else "left"
         else:
             self._probe, self._build = left, right
             self._probe_keys, self._build_keys = list(left_keys), list(right_keys)
@@ -158,6 +185,17 @@ class BaseJoinExec(PhysicalPlan):
         self._fast_ok = fastpath_supported(
             [e.data_type for e in self._bound_pkeys + self._bound_bkeys])
         self._bs_key = ("bs", exprs_key(self._bound_bkeys))
+
+    def estimate_bytes(self):
+        return _join_estimate(self.children)
+
+    def _count_strategy(self, tctx: TaskContext, name: str) -> None:
+        """``joinStrategy<Name>`` of last_query_metrics: once a join and
+        collect, whichever partition comes first."""
+        with self._setup_lock:
+            if self._strategy_counted_in is not tctx.query_ctx:
+                self._strategy_counted_in = tctx.query_ctx
+                tctx.inc_metric("joinStrategy" + name)
 
     # --- whole-stage probe fusion ----------------------------------------
     def absorb_probe_steps(self, steps, new_probe: PhysicalPlan) -> None:
@@ -205,7 +243,7 @@ class BaseJoinExec(PhysicalPlan):
     def _get_probe_fn(self):
         if self._probe_fn is None:
             self._probe_fn = self._jit(self._probe_info,
-                                       key=("probe", self._sig))
+                                       key=("probesearch", self._sig))
         return self._probe_fn
 
     # --- schema -----------------------------------------------------------
@@ -433,11 +471,13 @@ class BaseJoinExec(PhysicalPlan):
         if tctx is not None:
             tctx.inc_metric("joinHostReadbacks")
         with self._stage(tctx, "readback"):
+            scalars = list(info.sizing_scalars()) + [info.n_null_keys]
             if self.backend == TPU:
                 import jax
-                tot, unl, unb = jax.device_get(list(info.sizing_scalars()))
-            else:
-                tot, unl, unb = info.sizing_scalars()
+                scalars = jax.device_get(scalars)
+            tot, unl, unb, nulls = scalars
+        if tctx is not None:
+            tctx.inc_metric("joinNullKeyRows", int(nulls))
         return int(tot), int(unl), int(unb)
 
     # --- phase 2 ----------------------------------------------------------
@@ -466,7 +506,9 @@ class BaseJoinExec(PhysicalPlan):
                 info = self._probe_info(probe, build, bs)
                 out = self._gather_impl(probe, build, info, out_cap)
                 return out, info
-            fn = self._jit(impl, key=("fusedprobe", self._sig, out_cap))
+            # named jit_srt_<Exec>_probe_<digest> on the device's trace:
+            # a broadcast join's probes apart from a shuffled join's
+            fn = self._jit(impl, key=("probe", self._sig, out_cap))
             self._gather_cache[key] = fn
         return fn
 
@@ -743,22 +785,35 @@ class BaseJoinExec(PhysicalPlan):
             STATS["fused_probes"] += 1
             tctx.inc_metric("joinFastpathProbes")
             tctx.inc_metric("joinFusedProbes")
-            with self._stage(tctx, "fusedProbe"):
+            with self._stage(tctx, "fusedProbe"), \
+                    _tracer.span("join", "probe"):
                 out, info = self._fused_probe_fn(spec_cap)(probe, build, bs)
         else:
-            info = self._join_info(probe, build, tctx)
-            if speculating:
-                with self._stage(tctx, "gather"):
-                    out = self._gather_fn(spec_cap)(probe, build, info)
+            with _tracer.span("join", "probe"):
+                info = self._join_info(probe, build, tctx)
+                if speculating:
+                    with self._stage(tctx, "gather"):
+                        out = self._gather_fn(spec_cap)(probe, build, info)
 
         tot, unl, unb = self._fetch_totals(info, tctx)
         self._record_selectivity(probe, tot)
         total_out = total_out_of(tot, unl, unb)
+        tctx.inc_metric("joinProbeRows", probe.num_rows_bound)
+        tctx.inc_metric("joinOutputRows", total_out)
         if speculating:
             if total_out <= spec_cap:
                 STATS["spec_hits"] += 1
                 tctx.inc_metric("joinSpecHits")
-                yield out.with_known_rows(total_out)
+                out = out.with_known_rows(total_out)
+                # a first batch is sized before any selectivity is known
+                # (1.0: twice the probe's capacity); handed on as it is,
+                # every exec above would compile a program of its own for
+                # that one oversized shape (a 2^19-row group-id program
+                # where the learned batches are 2^14).  Cut it to its rows'
+                # bucket, as the learned batches come
+                if bucket_capacity(total_out) * 4 <= spec_cap:
+                    out = out.shrunk()
+                yield out
                 return
             # overflow: the realized output exceeds the predicted bucket —
             # re-gather at the exact capacity (the totals are on the host
@@ -930,6 +985,7 @@ class ShuffledHashJoinExec(BaseJoinExec):
         tctx.inc_metric("bloomFiltersBuilt")
 
     def execute(self, pid: int, tctx: TaskContext):
+        self._count_strategy(tctx, "Shuffle")
         with self._setup_lock:
             self._maybe_install_bloom(tctx)
         btctx = TaskContext(pid, tctx.conf, parent=tctx)
@@ -959,6 +1015,7 @@ class BroadcastHashJoinExec(BaseJoinExec):
 
     def execute(self, pid: int, tctx: TaskContext):
         assert isinstance(self._build, BroadcastExchangeExec)
+        self._count_strategy(tctx, "Broadcast")
         build = self._build.broadcast_batch(tctx)
         probes = list(self._probe.execute(pid, tctx))
         if not probes:
@@ -1081,6 +1138,16 @@ def _release_catalog_handles(catalog, handles) -> None:
             pass
 
 
+def _live_bytes(parts) -> int:
+    """What a broadcast of these batches would hold: each batch's arrays at
+    its live rows' share of its capacity (a filter's output keeps its
+    input's capacity; a concat drops the padding).  Reads each batch's row
+    count, so it waits for the programs that made them."""
+    from ...memory.spill import batch_device_bytes
+    return sum(batch_device_bytes(b) * b.num_rows_int // max(b.capacity, 1)
+               for bs in parts for b in bs)
+
+
 class MaterializedExec(PhysicalPlan):
     """Leaf serving pre-computed batches per partition — the runtime-stats
     carrier AQE re-plans over (GpuCustomShuffleReaderExec's shuffle-stage
@@ -1145,21 +1212,25 @@ class AdaptiveJoinExec(PhysicalPlan):
     this when its estimates say "shuffle"; if the materialized build side
     turns out to fit the broadcast threshold, the cheaper broadcast hash
     join is picked instead — a provably different plan on mis-estimated
-    inputs."""
+    inputs.  The side that is measured, and built on, is the child the
+    static estimates call the smaller (``build_left``: an inner join whose
+    left child is; ``plan_join``), and its size is that of its live rows:
+    a filter's output still has its input's capacity."""
 
     def __init__(self, node, left: PhysicalPlan, right: PhysicalPlan,
-                 backend, conf):
+                 backend, conf, build_left: bool = False):
         super().__init__(left, right)
         self.backend = backend
         self._node = node
         self._conf = conf
+        self._build_left = build_left
         self._chosen: Optional[PhysicalPlan] = None
         self._choose_lock = threading.Lock()
         self.chosen_strategy: Optional[str] = None
         # static shape only (output schema / explain); never executed
         self._shape = ShuffledHashJoinExec(
             node.how, node.left_keys, node.right_keys, node.condition,
-            left, right, backend=backend)
+            left, right, backend=backend, build_left=build_left)
 
     @property
     def output(self):
@@ -1167,6 +1238,9 @@ class AdaptiveJoinExec(PhysicalPlan):
 
     def num_partitions(self):
         return int(self._conf.shuffle_partitions)
+
+    def estimate_bytes(self):
+        return _join_estimate(self.children)
 
     def _choose(self, tctx: TaskContext):
         if self._chosen is not None:
@@ -1177,37 +1251,49 @@ class AdaptiveJoinExec(PhysicalPlan):
 
     def _choose_locked(self, tctx: TaskContext):
         from ...config import AUTO_BROADCAST_THRESHOLD
-        node, left, right = self._node, self.children[0], self.children[1]
+        node, build_left = self._node, self._build_left
+        build = self.children[0 if build_left else 1]
         parts = []
-        for p in range(right.num_partitions()):
-            rtctx = TaskContext(p, tctx.conf, parent=tctx)
-            with rtctx.as_current():
-                parts.append(list(right.execute(p, rtctx)))
-        right_m = MaterializedExec(right.output, parts, backend=self.backend)
-        threshold = int(self._conf.get(AUTO_BROADCAST_THRESHOLD))
-        can_broadcast = (node.how in ("inner", "left", "left_semi",
-                                      "left_anti", "existence")
-                         and right_m.estimate_bytes() <= threshold)
+        with _tracer.span("join", "adaptive.materialize"):
+            for p in range(build.num_partitions()):
+                btctx = TaskContext(p, tctx.conf, parent=tctx)
+                with btctx.as_current():
+                    parts.append(list(build.execute(p, btctx)))
+            threshold = int(self._conf.get(AUTO_BROADCAST_THRESHOLD))
+            # measured only where a broadcast can follow (a negative
+            # threshold turns broadcasts off): the read waits for the device
+            can_broadcast = (node.how in ("inner", "left", "left_semi",
+                                          "left_anti", "existence")
+                             and threshold >= 0
+                             and _live_bytes(parts) <= threshold)
+            build_m = MaterializedExec(build.output, parts,
+                                       backend=self.backend)
+        sides = dict(backend=self.backend, build_left=build_left)
         if can_broadcast:
-            build = BroadcastExchangeExec(right_m, backend=self.backend)
+            bx = BroadcastExchangeExec(build_m, backend=self.backend)
+            left, right = ((bx, self.children[1]) if build_left
+                           else (self.children[0], bx))
             self._chosen = BroadcastHashJoinExec(
                 node.how, node.left_keys, node.right_keys, node.condition,
-                left, build, backend=self.backend)
+                left, right, **sides)
             self.chosen_strategy = "broadcast"
         else:
             n = self.num_partitions()
             from ...parallel.partitioning import HashPartitioning
             from .exchange import ShuffleExchangeExec
+            left, right = ((build_m, self.children[1]) if build_left
+                           else (self.children[0], build_m))
             lx = ShuffleExchangeExec(
                 HashPartitioning(node.left_keys, n), left,
                 backend=self.backend, coalescible=False,
-                skew_splittable=node.how != "full")
+                skew_splittable=not build_left and node.how != "full")
             rx = ShuffleExchangeExec(
-                HashPartitioning(node.right_keys, n), right_m,
-                backend=self.backend, coalescible=False)
+                HashPartitioning(node.right_keys, n), right,
+                backend=self.backend, coalescible=False,
+                skew_splittable=build_left)
             self._chosen = ShuffledHashJoinExec(
                 node.how, node.left_keys, node.right_keys, node.condition,
-                lx, rx, backend=self.backend)
+                lx, rx, **sides)
             self.chosen_strategy = "shuffle"
 
     def execute(self, pid, tctx):
@@ -1224,7 +1310,8 @@ class AdaptiveJoinExec(PhysicalPlan):
 
     def simple_string(self):
         tag = self.chosen_strategy or "undecided"
-        return f"{self.node_name()} {self._node.how} [aqe: {tag}]"
+        side = ", build=left" if self._build_left else ""
+        return f"{self.node_name()} {self._node.how} [aqe: {tag}{side}]"
 
 
 # --------------------------------------------------------------------------
@@ -1253,15 +1340,32 @@ def plan_join(node, left: PhysicalPlan, right: PhysicalPlan, backend,
 
     from ...config import AUTO_BROADCAST_THRESHOLD
     threshold = int(conf.get(AUTO_BROADCAST_THRESHOLD))
-    build_bytes = right.estimate_bytes()
     hinted = bool(getattr(node, "broadcast_hint", False))
+    # the side choice: an inner equi-join builds on the child that is
+    # smaller by what can be observed here, whichever side of the text it
+    # stands on (Spark's JoinSelection picks its build side the same way):
+    # a left child that may be broadcast, or one a shuffled join's right
+    # child dwarfs.  Two sides of like size that would both be shuffled
+    # keep the text's order (_LIKE_SIZE), a hint names the right child,
+    # and unknown sizes keep the text's order too
+    left_bytes, right_bytes = left.estimate_bytes(), right.estimate_bytes()
+    build_left = (how == "inner" and not hinted
+                  and left_bytes is not None and right_bytes is not None
+                  and left_bytes < right_bytes
+                  and (left_bytes <= threshold
+                       or left_bytes * _LIKE_SIZE <= right_bytes))
+    probe, build = (right, left) if build_left else (left, right)
+    probe_keys, build_keys = ((node.right_keys, node.left_keys) if build_left
+                              else (node.left_keys, node.right_keys))
+    build_bytes = left_bytes if build_left else right_bytes
+    sides = dict(backend=backend, build_left=build_left)
     can_broadcast = (how in ("inner", "left", "left_semi", "left_anti",
                              "existence")
                      and (hinted
                           or (build_bytes is not None
                               and build_bytes <= threshold)))
-    if can_broadcast and (hinted or left.num_partitions() > 1):
-        build = BroadcastExchangeExec(right, backend=backend)
+    if can_broadcast and (hinted or probe.num_partitions() > 1):
+        bx = BroadcastExchangeExec(build, backend=backend)
         # dynamic partition pruning: a hive-partitioned probe scan joined
         # on its partition column skips files the broadcast keys rule out.
         # ONLY probe-filtering joins qualify — outer/anti/existence joins
@@ -1269,10 +1373,10 @@ def plan_join(node, left: PhysicalPlan, right: PhysicalPlan, backend,
         # rows pruning would drop
         if how in ("inner", "left_semi"):
             from .dpp import apply_dpp
-            left = apply_dpp(left, node.left_keys, node.right_keys, build)
+            probe = apply_dpp(probe, probe_keys, build_keys, bx)
+        left, right = (bx, probe) if build_left else (probe, bx)
         return BroadcastHashJoinExec(how, node.left_keys, node.right_keys,
-                                     node.condition, left, build,
-                                     backend=backend)
+                                     node.condition, left, right, **sides)
 
     from ...config import ADAPTIVE_ENABLED
     nparts = max(left.num_partitions(), right.num_partitions())
@@ -1281,18 +1385,20 @@ def plan_join(node, left: PhysicalPlan, right: PhysicalPlan, backend,
                         "existence")):
         # the static estimate said "shuffle" (or was unknown): let AQE
         # re-decide from the materialized build side at runtime
-        return AdaptiveJoinExec(node, left, right, backend, conf)
+        return AdaptiveJoinExec(node, left, right, backend, conf,
+                                build_left=build_left)
     if nparts > 1:
         n = int(conf.shuffle_partitions)
-        # the PROBE side gets skew splitting; right joins flip sides in
-        # BaseJoinExec (probe=right, build=left), full joins concat
-        # their probe batches back (join.py execute), so neither benefits
+        # the PROBE side gets skew splitting; right joins and inner joins
+        # that build on the left flip sides in BaseJoinExec (probe=right,
+        # build=left), full joins concat their probe batches back (join.py
+        # execute), so neither benefits
         left = ShuffleExchangeExec(
             HashPartitioning(node.left_keys, n), left, backend=backend,
             coalescible=False,
-            skew_splittable=how not in ("full", "right"))
+            skew_splittable=how not in ("full", "right") and not build_left)
         right = ShuffleExchangeExec(
             HashPartitioning(node.right_keys, n), right, backend=backend,
-            coalescible=False, skew_splittable=how == "right")
+            coalescible=False, skew_splittable=how == "right" or build_left)
     return ShuffledHashJoinExec(how, node.left_keys, node.right_keys,
-                                node.condition, left, right, backend=backend)
+                                node.condition, left, right, **sides)

@@ -933,10 +933,42 @@ def _to_arrow_table(data, schema) -> pa.Table:
     raise TypeError(f"cannot create DataFrame from {type(data)}")
 
 
+def _share_dictionaries(table: pa.Table, per: int) -> pa.Table:
+    """String columns of few distinct values, dictionary-encoded once for
+    the whole table before it is cut: every partition's codes then refer
+    to ONE dictionary (``columnar/encoded.py`` keeps an Arrow dictionary
+    as it comes), so pieces of different partitions concatenate as codes
+    inside one program (``ColumnarBatch.concat``: a broadcast build, an
+    exchange's read) instead of being unified on the host and joined
+    array by array.  The rule is the scan's own (``_cardinality_ok`` at a
+    partition's rows); a column over it is left for each partition to
+    decide, as before."""
+    import pyarrow.compute as pc
+    from ..columnar import encoded as E
+    if not E.enabled() or per <= 0:
+        return table
+    limit = E._max_cardinality()
+    for i, field in enumerate(table.schema):
+        if not (pa.types.is_string(field.type)
+                or pa.types.is_large_string(field.type)):
+            continue
+        column = table.column(i)
+        # a first look at a prefix: a long text column is not encoded whole
+        head = column.slice(0, 1 << 16)
+        if pc.count_distinct(head).as_py() > limit:
+            continue
+        encoded = pc.dictionary_encode(column.combine_chunks())
+        if E._cardinality_ok(len(encoded.dictionary), per, limit):
+            table = table.set_column(i, field.name, encoded)
+    return table
+
+
 def _split_table(table: pa.Table, n: int) -> List[pa.Table]:
     n = max(1, n)
     rows = table.num_rows
     per = -(-rows // n) if rows else 0
+    if n > 1:
+        table = _share_dictionaries(table, per)
     parts = []
     for i in range(n):
         lo = min(i * per, rows)

@@ -377,6 +377,8 @@ def from_arrow(at) -> DataType:
                                 for f in at))
     if pa.types.is_map(at):
         return MapType(from_arrow(at.key_type), from_arrow(at.item_type))
+    if pa.types.is_dictionary(at):
+        return from_arrow(at.value_type)    # an encoding, not a type
     raise TypeError(f"unsupported arrow type {at}")
 
 

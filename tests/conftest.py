@@ -32,6 +32,8 @@ import pytest  # noqa: E402
 QUICK_MODULES = {
     "test_columnar", "test_expressions", "test_sql", "test_joins",
     "test_join_fastpath",
+    # the TPC-DS star join (ISSUE 33): side choice, NULL keys, the share
+    "test_tpcds_star",
     "test_memory", "test_native", "test_cross_slice", "test_hive_udf",
     # observability tracer: tier-1 per ISSUE 3 (trace regressions must
     # surface in the quick gate, not only in full CI)

@@ -422,3 +422,51 @@ class TestLexSort64Split:
         p = np.asarray(perm)
         ones = p[:300]   # rows with key 1, in original order
         assert np.all(np.diff(ones) > 0)
+
+
+class TestLexSortNetwork:
+    """Between 2^14 and 2^16 rows the chip's ``lex_sort`` is a rolled
+    bitonic network, not ``lax.sort`` (whose program takes the chip's
+    compiler 8-100 s at those sizes): same permutation and sorted keys as
+    the numpy oracle, ties in row order, 64-bit keys split as before."""
+
+    @pytest.fixture()
+    def as_on_the_chip(self, monkeypatch):
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    @pytest.mark.parametrize("log2", [14, 15, 16])
+    def test_matches_numpy(self, as_on_the_chip, log2):
+        import jax
+        import jax.numpy as jnp
+
+        from spark_rapids_tpu.ops import ranks
+        n = 1 << log2
+        rng = np.random.default_rng(log2)
+        keys = [rng.integers(0, 2, n).astype(bool),
+                rng.integers(-3, 3, n).astype(np.int32),
+                rng.integers(-2**62, 2**62, n) // (1 << 61) * (1 << 40),
+                rng.integers(0, 4, n).astype(np.uint32)]
+        assert ranks._network_sorts(n, [jnp.asarray(k) for k in keys])
+        lowered = jax.jit(lambda *k: ranks.lex_sort(jnp, list(k))).lower(
+            *keys)
+        assert "stablehlo.sort" not in lowered.as_text()
+        perm, got = lowered.compile()(*keys)
+        want_perm, want = ranks.lex_sort(np, keys)
+        assert np.array_equal(np.asarray(perm), want_perm)
+        for a, b in zip(got, want):
+            assert np.array_equal(np.asarray(a), b)
+
+    @pytest.mark.parametrize("n,kind", [(1 << 13, "i"), (1 << 17, "i"),
+                                        (20_000, "i"), (1 << 15, "f")])
+    def test_other_sizes_and_floats_keep_lax_sort(self, as_on_the_chip,
+                                                  n, kind):
+        import jax
+        import jax.numpy as jnp
+
+        from spark_rapids_tpu.ops import ranks
+        k = (np.arange(n, dtype=np.int32)[::-1].copy() if kind == "i"
+             else np.linspace(1.0, 0.0, n))
+        assert not ranks._network_sorts(n, [jnp.asarray(k)])
+        text = jax.jit(lambda a: ranks.lex_sort(jnp, [a])).lower(k).as_text()
+        assert "stablehlo.sort" in text

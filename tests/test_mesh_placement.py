@@ -419,8 +419,11 @@ def test_one_executor_runs_the_parents_q3(four_chips):
     """With ``spark.executor.instances`` unset or 1 nothing of the
     placement is on the path: Q3's physical plan and the names of the
     programs it launches (a digest of each program's key, the same in
-    every process) are those recorded from the commit before the key
-    existed (``tests/data/q3_one_executor_programs.json``)."""
+    every process) are those recorded with the key unset
+    (``tests/data/q3_one_executor_programs.json``: first from the commit
+    before the key existed, again at PR 33: the same plan, the probe
+    programs named ``_probe_``, and one ``concat`` program a schema now
+    that a table's partitions share their dictionaries)."""
     import json
     import os
     from spark_rapids_tpu.testing import tpch_queries as TQ

@@ -107,6 +107,52 @@ def test_dense_group_reduce_compiles_for_v5e(one_chip, s, out):
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
+@pytest.mark.parametrize("log2", [13, 15, 18, 21])
+def test_join_probe_prefix_sum_compiles_for_v5e(one_chip, log2):
+    """The probe's running total of matches per row (``ops/join.py``: an
+    int64 sum over a probe batch) in the two-level form: it compiles at
+    the batch sizes the cells use, and holds no flat int64 scan, whose
+    lowering took 20-40 s of every cold process for each probe program
+    (PERF.md section 6, PR 33)."""
+    from spark_rapids_tpu.ops.ranks import prefix_sum
+    n = 1 << log2
+
+    def running_total(hit, lo, hi):
+        counts = jnp.where(hit, hi - lo, 0).astype(jnp.int64)
+        csum = prefix_sum(jnp, counts)
+        return csum, csum[n - 1]
+
+    compiled, text = _compile(
+        running_total,
+        jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip))
+    windows = re.findall(r"window=\{size=([0-9x]+)", text)
+    assert all(int(w.split("x")[-1]) <= 1024 for w in windows), windows
+
+
+@pytest.mark.parametrize("log2,operands", [(15, 2), (14, 6), (16, 6)])
+def test_small_lex_sort_is_a_rolled_network_for_v5e(one_chip, monkeypatch,
+                                                    log2, operands):
+    """A join's build sort (a bool and an int32 key at 2^15 rows) and a
+    string-keyed sort (six 32-bit words) between 2^14 and 2^16 rows hold
+    no ``lax.sort``: one such program took the chip's compiler 20-200 s in
+    every cold process (PERF.md section 6, PR 33); the network's loop body
+    compiles at once."""
+    import time
+
+    from spark_rapids_tpu.ops import ranks
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 1 << log2
+    kinds = [jnp.bool_] + [jnp.int32, jnp.uint32] * 3
+    t0 = time.perf_counter()
+    _compile(lambda *k: ranks.lex_sort(jnp, list(k)),
+             *[jax.ShapeDtypeStruct((n,), kinds[i], sharding=one_chip)
+               for i in range(operands)],
+             sortless="a sort of 2^14..2^16 rows is the rolled network")
+    assert time.perf_counter() - t0 < 30
+
+
 # --------------------------------------------------------------------------
 # the gate: loud on a TPU, silent nowhere
 # --------------------------------------------------------------------------
