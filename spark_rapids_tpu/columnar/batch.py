@@ -128,9 +128,15 @@ class ColumnarBatch:
         return ColumnarBatch.make(names, columns, self.num_rows)
 
     def select(self, indices: Sequence[int]) -> "ColumnarBatch":
-        return ColumnarBatch.make(
+        """Some of the columns, the same rows: a new tuple over the same
+        arrays, on the host (no program runs)."""
+        b = ColumnarBatch.make(
             [self.names[i] for i in indices],
             [self.columns[i] for i in indices], self.num_rows)
+        for known in ("_nrows_host", "_nrows_bound"):
+            if getattr(self, known, None) is not None:
+                setattr(b, known, getattr(self, known))
+        return b
 
     # --- reshaping (host-orchestrated, device-executed) -------------------
     def repadded(self, new_capacity: int) -> "ColumnarBatch":

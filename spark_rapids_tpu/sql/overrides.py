@@ -392,5 +392,6 @@ class TpuOverrides:
 
 def explain_potential_plan(df, all_ops: bool = True) -> str:
     """Public explain API (reference ``ExplainPlan.explainPotentialGpuPlan``)."""
-    meta = TpuOverrides.apply(df._plan, df._session.conf)
+    from .column_pruning import prune_columns
+    meta = TpuOverrides.apply(prune_columns(df._plan), df._session.conf)
     return meta.explain(all_ops)

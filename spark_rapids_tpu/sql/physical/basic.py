@@ -51,6 +51,11 @@ import threading as _threading
 _UPLOAD_LOCK = _threading.Lock()
 
 
+#: ``InMemoryScanExec._counted_in`` before any collect (a query context may
+#: be None)
+_NOT_COUNTED = object()
+
+
 class _PendingUpload:
     __slots__ = ("event", "error")
 
@@ -146,13 +151,23 @@ def _cached_upload(table, backend: str, conf=None, chip=None) -> list:
 
 class InMemoryScanExec(PhysicalPlan):
     """Scan over pre-partitioned pyarrow tables (Relation leaf +
-    HostColumnarToGpu fused: decode on host, upload once)."""
+    HostColumnarToGpu fused: decode on host, upload once).
+
+    ``attrs`` may name only some of the tables' columns (the planner's
+    column pruning, ``sql/column_pruning.py``).  The upload cache still holds
+    each table whole, once; the scan hands on the named columns of every
+    cached batch, a selection on the host that launches nothing, so a column
+    nobody reads is never expanded, gathered or exchanged."""
 
     def __init__(self, attrs, partitions, backend=TPU):
         super().__init__()
         self.backend = backend
         self._attrs = list(attrs)
         self._parts = partitions  # List[pa.Table]
+        self._names = tuple(a.name for a in self._attrs)
+        self._bytes: Optional[int] = None
+        self._count_lock = _threading.Lock()
+        self._counted_in = _NOT_COUNTED
 
     @property
     def output(self):
@@ -162,13 +177,37 @@ class InMemoryScanExec(PhysicalPlan):
         return len(self._parts)
 
     def estimate_bytes(self):
-        return sum(t.nbytes for t in self._parts)
+        """The Arrow bytes of the columns handed on: what a join above would
+        really move (Catalyst's statistics after ``ColumnPruning``)."""
+        if self._bytes is None:
+            self._bytes = sum(
+                t.nbytes if t.num_columns == len(self._names)
+                else sum(t.column(n).nbytes for n in self._names)
+                for t in self._parts)
+        return self._bytes
+
+    def _count_columns(self, tctx: TaskContext) -> None:
+        """``scanColumnsRead`` / ``scanColumnsPruned`` of last_query_metrics:
+        once a scan and collect, whichever partition comes first."""
+        with self._count_lock:
+            if self._counted_in is tctx.query_ctx:
+                return
+            self._counted_in = tctx.query_ctx
+        tctx.inc_metric("scanColumnsRead", len(self._names))
+        tctx.inc_metric("scanColumnsPruned",
+                        self._parts[0].num_columns - len(self._names))
 
     def execute(self, pid: int, tctx: TaskContext):
         from ...parallel.placement import home_chip
-        yield from _cached_upload(
-            self._parts[pid], self.backend, tctx.conf,
-            chip=home_chip(pid, tctx.conf) if self.backend == TPU else None)
+        self._count_columns(tctx)
+        for batch in _cached_upload(
+                self._parts[pid], self.backend, tctx.conf,
+                chip=home_chip(pid, tctx.conf) if self.backend == TPU
+                else None):
+            if len(batch.names) > len(self._names):
+                batch = batch.select(
+                    [batch.names.index(n) for n in self._names])
+            yield batch
 
     def simple_string(self):
         return f"{self.node_name()} [{', '.join(a.name for a in self._attrs)}]"

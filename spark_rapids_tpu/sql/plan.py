@@ -69,6 +69,14 @@ class Relation(LogicalPlan):
                 for f in self.table.schema]
         return self._output
 
+    def narrowed(self, attrs) -> "Relation":
+        """This relation handing on only ``attrs`` (some of ``output``, in
+        its order): the same table and partition objects, which the scan's
+        upload cache is keyed by (``sql/column_pruning.py``)."""
+        new = Relation(self.table, self.partitions)
+        new._output = list(attrs)
+        return new
+
     def simple_string(self):
         return f"Relation [{', '.join(a.name for a in self.output)}]"
 
@@ -84,6 +92,9 @@ class CachedRelation(LogicalPlan):
 
     @property
     def table(self):
+        whole = getattr(self, "_whole", None)
+        if whole is not None:
+            return whole.table
         if not hasattr(self, "_table"):
             import io as _io
             import pyarrow.parquet as _pq
@@ -94,11 +105,24 @@ class CachedRelation(LogicalPlan):
 
     @property
     def output(self):
+        if hasattr(self, "_output"):
+            return self._output
         return [AttributeReference(f.name, f.data_type, True)
                 for f in self.schema_fields]
 
+    def narrowed(self, attrs) -> "CachedRelation":
+        """This relation handing on only ``attrs``.  The copy holds no bytes
+        of its own: it reads the table this one decodes, once for both."""
+        names = {a.name for a in attrs}
+        new = CachedRelation(b"", tuple(f for f in self.schema_fields
+                                        if f.name in names))
+        new._whole = getattr(self, "_whole", None) or self
+        new._output = list(attrs)
+        return new
+
     def simple_string(self):
-        nbytes = len(self.blob) or getattr(self, "_blob_len", 0)
+        whole = getattr(self, "_whole", None) or self
+        nbytes = len(whole.blob) or getattr(whole, "_blob_len", 0)
         return (f"CachedRelation [{', '.join(a.name for a in self.output)}] "
                 f"({nbytes} parquet bytes)")
 

@@ -9,6 +9,7 @@ from typing import List, Optional
 from ..config import RapidsConf
 from ..parallel.partitioning import (HashPartitioning, RangePartitioning,
                                      RoundRobinPartitioning, SinglePartitioning)
+from . import column_pruning
 from . import plan as P
 from .expressions.core import AttributeReference
 from .overrides import PlanMeta, TpuOverrides
@@ -29,6 +30,9 @@ class Planner:
 
     # ------------------------------------------------------------------
     def plan(self, logical: P.LogicalPlan) -> PhysicalPlan:
+        # a new tree: everything below reads (and keys by node identity)
+        # the pruned plan, the caller's is left as it was
+        logical = column_pruning.prune_columns(logical)
         self._window_group_limits = {}
         parents: dict = {}
         _count_parents(logical, parents, set())

@@ -785,7 +785,9 @@ class TpuSession:
             self, "_last_logical", None)
         if logical is None:
             raise RuntimeError("no query has been collected yet")
-        meta = TpuOverrides.apply(logical, self._conf)
+        from .column_pruning import prune_columns
+        # the placement report describes the plan that executes
+        meta = TpuOverrides.apply(prune_columns(logical), self._conf)
         from ..config import OPTIMIZER_ENABLED
         if bool(self._conf.get(OPTIMIZER_ENABLED)):
             # keep the placement report consistent with the physical plan

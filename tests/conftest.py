@@ -34,6 +34,9 @@ QUICK_MODULES = {
     "test_join_fastpath",
     # the TPC-DS star join (ISSUE 33): side choice, NULL keys, the share
     "test_tpcds_star",
+    # the planner's column pruning (ISSUE 34): the rule by node type, the
+    # same answers with and without it, the join cells' plans at their sizes
+    "test_column_pruning",
     "test_memory", "test_native", "test_cross_slice", "test_hive_udf",
     # observability tracer: tier-1 per ISSUE 3 (trace regressions must
     # surface in the quick gate, not only in full CI)

@@ -423,7 +423,9 @@ def test_one_executor_runs_the_parents_q3(four_chips):
     (``tests/data/q3_one_executor_programs.json``: first from the commit
     before the key existed, again at PR 33: the same plan, the probe
     programs named ``_probe_``, and one ``concat`` program a schema now
-    that a table's partitions share their dictionaries)."""
+    that a table's partitions share their dictionaries; again at PR 34:
+    the scans hand on the 2 + 4 + 4 columns Q3 reads, so the same 46
+    programs run over fewer columns and 28 of them have new digests)."""
     import json
     import os
     from spark_rapids_tpu.testing import tpch_queries as TQ
