@@ -57,6 +57,12 @@ Event categories:
                    and decline-site materializations (columnar/encoded.py)
 ``admission``      serving admission waits; ``cancel`` drain latency of a
                    cancelled query; ``fatal`` device-fatal quarantine
+``sort``           ``compute``: one launch of a sort exec's program over
+                   one batch (sql/physical/sortlimit.py); ``range_bounds``:
+                   a range exchange sampling its map outputs, sorting the
+                   sample and picking the boundary rows (exchange.py)
+``window``         ``compute``: one launch of a window exec's program over
+                   one key-complete batch (sql/physical/window.py)
 ``stage``          whole-stage program execution: one span per fused-stage
                    batch (map-chain program call or terminal-stage batch
                    production; sql/physical/fusion.py)
@@ -103,7 +109,7 @@ TRACING = {"on": False, "profiler": False}
 CATEGORIES = ("query", "plan", "task", "op", "stage", "dispatch", "compile",
               "scan", "sync", "h2d", "d2h", "spill", "shuffle", "sem_wait",
               "fault", "queue", "encode", "admission", "cancel", "fatal",
-              "broadcast", "join")
+              "broadcast", "join", "sort", "window")
 
 #: every profiler annotation's name starts with this
 PROFILER_PREFIX = "srt:"

@@ -52,8 +52,9 @@ def lex_sort(xp, keys):
     reconstructed from the sorted pairs, so callers see the same
     (perm, sorted_keys) contract.
 
-    Between 2^14 and 2^16 rows the chip's sort is ``_network_sort``, the
-    same order from a program that compiles at once (``_NETWORK_ROWS``).
+    Between 2^14 and 2^16 rows, and below that where the comparator is
+    wide, the chip's sort is ``_network_sort``: the same order from a
+    program that compiles at once (``_NETWORK_ROWS``, ``_NETWORK_WORDS``).
     """
     keys = list(keys)
     if xp.__name__ == "numpy":
@@ -110,6 +111,15 @@ def lex_sort(xp, keys):
 #: section 6, PR 33).  Above 2^16 rows ``lax.sort`` stays: its run time is
 #: what counts there.
 _NETWORK_ROWS = (1 << 14, 1 << 16)
+#: rows x key operands above which ``lex_sort`` is the network below 2^14
+#: rows too.  One ``lax.sort`` program's compile time grows with both:
+#: 1.8 s at 2^11 rows x 16 operands, 9.3 s at 2^12 x 16, 12.5 s at 2^13 x
+#: 8, then 41.3 s at 2^13 x 16, 38.7 s at 2^12 x 32, 46.1 s at 2^11 x 90,
+#: and at 2^13 x 91 (a group-by keyed by a 200-character string) the
+#: chip's host had not finished after 330 s; the network takes 3.5-6.8 s at
+#: 2^12-2^13 rows x 90 operands (compiled for a described v5e, PERF.md
+#: section 6, PR 35).  The narrow sorts below 2^14 rows stay ``lax.sort``.
+_NETWORK_WORDS = 1 << 17
 
 
 def _network_sorts(n: int, sort_keys) -> bool:
@@ -117,7 +127,8 @@ def _network_sorts(n: int, sort_keys) -> bool:
     ``lax.sort`` compiles at once; integer and bool keys only (a NaN has
     no place in the network's total order)."""
     import jax
-    return (_NETWORK_ROWS[0] <= n <= _NETWORK_ROWS[1] and n & (n - 1) == 0
+    return ((_NETWORK_ROWS[0] <= n or n * len(sort_keys) > _NETWORK_WORDS)
+            and n <= _NETWORK_ROWS[1] and n & (n - 1) == 0
             and jax.default_backend() != "cpu"
             and all(k.dtype.kind in "biu" for k in sort_keys))
 

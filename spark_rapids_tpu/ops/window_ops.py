@@ -108,14 +108,49 @@ def range_reduce(xp, v, starts, ends, op, identity):
 # Frame aggregations
 # ---------------------------------------------------------------------------
 
-def frame_sum(xp, v, valid, starts, ends, out_dtype=None):
-    """Sum of valid v over [s, e) per row (null-skipping, Spark agg)."""
+def frame_sum(xp, v, valid, starts, ends, out_dtype=None, seg_start=None):
+    """Sum of valid v over [s, e) per row (null-skipping, Spark agg).
+
+    A float sum on the device, given each row's ``seg_start`` (a frame
+    never leaves its row's segment), comes from prefix sums that restart
+    with every segment (:func:`_segment_prefix_sum`)."""
     dt = out_dtype or v.dtype
     vz = xp.where(valid, v, xp.asarray(0, dtype=v.dtype)).astype(dt)
+    if (seg_start is not None and xp.__name__ != "numpy"
+            and np.dtype(dt).kind == "f"):
+        n = vz.shape[0]
+        p = _segment_prefix_sum(xp, vz, seg_start)
+        below = xp.where(starts > seg_start,
+                         p[xp.clip(starts - 1, 0, n - 1)], 0)
+        return xp.where(ends > starts,
+                        p[xp.clip(ends - 1, 0, n - 1)] - below, 0)
     c = xp.cumsum(vz)
     zero = xp.zeros((1,), dtype=dt)
     cpad = xp.concatenate([zero, c])  # cpad[i] = sum of v[:i]
     return cpad[xp.maximum(ends, 0)] - cpad[xp.maximum(starts, 0)]
+
+
+def _segment_prefix_sum(xp, x, seg_start):
+    """Inclusive prefix sums of ``x`` that restart at each segment's first
+    row, as log2(n) shifted adds: after the step of distance d a row holds
+    the sum of the 2d rows that end with it, cut at its segment's start.
+
+    Two reasons over one ``cumsum`` and its differences.  The chip's
+    compiler: a float64 ``cumsum`` is a reduce-window over the X64
+    rewrite's float32 pairs and takes 120 s at 2^15 rows, flat or in
+    blocks, where these adds take a second (compiled for a described v5e,
+    PERF.md section 6, PR 35).  And the sums themselves: a segment's total
+    no longer carries the rounding of everything before it, so a segment
+    of zeros sums to exactly 0 (a NULL ratio in TPC-DS q98, not a division
+    by the last bit of a difference)."""
+    n = x.shape[0]
+    idx = xp.arange(n, dtype=xp.int32)
+    shift = 1
+    while shift < n:
+        before = xp.concatenate([xp.zeros((shift,), x.dtype), x[:-shift]])
+        x = x + xp.where(idx - shift >= seg_start, before, 0)
+        shift *= 2
+    return x
 
 
 def frame_count(xp, valid, starts, ends):

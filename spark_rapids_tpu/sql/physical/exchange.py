@@ -294,8 +294,12 @@ class ShuffleExchangeExec(PhysicalPlan):
                             if b is not None)
                     <= int(tctx.conf.get(ADAPTIVE_COALESCE_ROWS)))
 
-        if isinstance(self.partitioning, RangePartitioning) and not coalesce:
-            self._compute_range_bounds(map_out, tctx)
+        if isinstance(self.partitioning, RangePartitioning):
+            if coalesce:    # every row goes to partition 0: nothing sampled
+                tctx.inc_metric("rangeBoundSamples", 0)
+            else:
+                with _trace.span("sort", "range_bounds", partitions=nt):
+                    self._compute_range_bounds(map_out, tctx)
 
         topo = mgr.topology
         multi = topo is not None and topo.multi_slice
@@ -612,6 +616,7 @@ class ShuffleExchangeExec(PhysicalPlan):
             n = batch.num_rows_int
             if n > 4096:  # cheap deterministic sample
                 batch = batch.sliced(0, 4096)
+            tctx.inc_metric("rangeBoundSamples", min(n, 4096))
             samples.append(batch)
         if not samples:
             part.set_bounds(self._empty_batch())

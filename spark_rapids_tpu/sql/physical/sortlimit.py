@@ -21,10 +21,11 @@ from typing import Sequence
 import numpy as np
 
 from ...columnar.batch import ColumnarBatch
+from ...observability import tracer as _trace
 from ...ops.sorting import sort_permutation
 from ..expressions.core import EvalContext, bind_references
 from ..plan import SortOrder
-from .base import TPU, PhysicalPlan
+from .base import TPU, PhysicalPlan, count_stage_dispatch
 
 #: observability for tests: counts of out-of-core engagements
 STATS = {"ooc_sorts": 0, "merge_steps": 0}
@@ -119,6 +120,16 @@ class SortExec(PhysicalPlan):
             batch = compact_batch(xp, batch, mask)
         return self._compute(batch)
 
+    def _launch(self, fn, batch: ColumnarBatch, tctx) -> ColumnarBatch:
+        """One sort program over one batch: the span and the counter of
+        every launch (the row count stays on the device until the task has
+        ended)."""
+        count_stage_dispatch()
+        with _trace.span("sort", "compute"):
+            out = fn(batch)
+        tctx.inc_metric_late("sortRows", out.num_rows)
+        return out
+
     def execute(self, pid, tctx):
         yield from self.execute_batches(
             list(self.children[0].execute(pid, tctx)), tctx)
@@ -136,12 +147,10 @@ class SortExec(PhysicalPlan):
         # cheaper than one device sync per batch
         total = sum(b.num_rows_bound for b in batches)
         if total > target:
-            yield from self._out_of_core(batches, target)
+            yield from self._out_of_core(batches, target, tctx)
             return
         merged = ColumnarBatch.concat(batches) if len(batches) > 1 else batches[0]
-        from .base import count_stage_dispatch
-        count_stage_dispatch()
-        out = self._stage_fn(merged)
+        out = self._launch(self._stage_fn, merged, tctx)
         if self._pre_steps:
             # absorbed filters can drop rows, so the count is no longer
             # host-known — only bounded by the pre-filter total
@@ -158,7 +167,7 @@ class SortExec(PhysicalPlan):
         yield out
 
     # --- out-of-core path -------------------------------------------------
-    def _out_of_core(self, batches, target: int):
+    def _out_of_core(self, batches, target: int, tctx):
         from ...memory.retry import split_spillable_in_half, with_retry
         from ...memory.spill import (ACTIVE_BATCHING_PRIORITY,
                                      SpillableColumnarBatch)
@@ -176,11 +185,8 @@ class SortExec(PhysicalPlan):
             # first touch runs the STAGE program (absorbed chain + sort);
             # the phase-2 merge below re-sorts already-processed rows and
             # must use the pure-sort program only
-            from .base import count_stage_dispatch
-
             def run_sort(sb):
-                count_stage_dispatch()
-                return self._stage_fn(sb.get())
+                return self._launch(self._stage_fn, sb.get(), tctx)
 
             for sorted_b in with_retry(spillables, run_sort,
                                        split_spillable_in_half):
@@ -240,8 +246,7 @@ class SortExec(PhysicalPlan):
                         hb.num_rows))
                 union = (ColumnarBatch.concat(heads) if len(heads) > 1
                          else heads[0])
-                count_stage_dispatch()
-                merged = self._fn(union)
+                merged = self._launch(self._fn, union, tctx)
                 e = min(target, merged.num_rows_int)
                 emit = merged.sliced(0, e)
                 # consumed rows per run (host bincount over emitted prefix)

@@ -21,6 +21,7 @@ import numpy as np
 from ... import types as T
 from ...columnar.batch import ColumnarBatch
 from ...columnar.column import DeviceColumn
+from ...observability import tracer as _trace
 from ...ops import window_ops as W
 from ...ops.ranks import column_sort_keys
 from ..expressions import aggregates as AGG
@@ -31,7 +32,7 @@ from ..expressions.windows import (CURRENT_ROW, CumeDist, DenseRank, Lag,
                                    UNBOUNDED_PRECEDING, WindowExpression,
                                    WindowFrame)
 from ..plan import SortOrder
-from .base import TPU, PhysicalPlan
+from .base import TPU, PhysicalPlan, count_stage_dispatch
 
 
 def _select_column(xp, mask, a: DeviceColumn, b: DeviceColumn) -> DeviceColumn:
@@ -163,7 +164,20 @@ class WindowExec(PhysicalPlan):
             seg_keys.extend(column_sort_keys(xp, c))
         return seg_keys
 
-    def _compute(self, batch: ColumnarBatch) -> ColumnarBatch:
+    def _launch(self, fn, batch: ColumnarBatch, tctx) -> ColumnarBatch:
+        """One window program over one key-complete batch: the span and the
+        counters of every launch.  Rows and partitions stay on the device
+        until the task has ended (``inc_metric_late``)."""
+        count_stage_dispatch()
+        with _trace.span("window", "compute"):
+            out, partitions = fn(batch)
+        tctx.inc_metric_late("windowRows", out.num_rows)
+        tctx.inc_metric_late("windowPartitions", partitions)
+        return out
+
+    def _compute(self, batch: ColumnarBatch):
+        """(the batch with the window columns appended, the number of
+        window partitions among its live rows)."""
         xp = self.xp
         ctx = EvalContext(batch, xp=xp)
         n = batch.capacity
@@ -197,8 +211,9 @@ class WindowExec(PhysicalPlan):
             new_cols.append(col.mask_dead_rows(live))
 
         names = tuple(a.name for a in self.output)
+        partitions = xp.sum(is_seg_start & live, dtype=xp.int32)
         return ColumnarBatch(names, tuple(batch.columns) + tuple(new_cols),
-                             batch.num_rows)
+                             batch.num_rows), partitions
 
     # ------------------------------------------------------------------
     def _frame_bounds(self, frame: WindowFrame, xp, idx, seg_start, seg_end,
@@ -368,14 +383,15 @@ class WindowExec(PhysicalPlan):
             val = fn.children[0].eval(ctx)
             dt = fn.data_type
             s = W.frame_sum(xp, val.data, val.validity, fs, fe,
-                            out_dtype=dt.np_dtype)
+                            out_dtype=dt.np_dtype, seg_start=seg_start)
             has = W.frame_count(xp, val.validity, fs, fe) > 0
             return DeviceColumn(dt, s, has)
 
         if isinstance(fn, AGG.Average):
             val = fn.children[0].eval(ctx)
             s = W.frame_sum(xp, val.data.astype(xp.float64), val.validity,
-                            fs, fe, out_dtype=xp.float64)
+                            fs, fe, out_dtype=xp.float64,
+                            seg_start=seg_start)
             c = W.frame_count(xp, val.validity, fs, fe)
             avg = s / xp.maximum(c, 1).astype(xp.float64)
             return DeviceColumn(T.DOUBLE, avg, c > 0)
@@ -469,9 +485,7 @@ class WindowExec(PhysicalPlan):
             return out
 
         def run_window(s):
-            from .base import count_stage_dispatch
-            count_stage_dispatch()
-            return self._fn(s.get())
+            return self._launch(self._fn, s.get(), tctx)
 
         def process(head):
             sb = SpillableColumnarBatch.create(head,
@@ -553,9 +567,7 @@ class WindowExec(PhysicalPlan):
             return
         merged = (ColumnarBatch.concat(batches) if len(batches) > 1
                   else batches[0])
-        from .base import count_stage_dispatch
-        count_stage_dispatch()
-        yield self._fn(merged)
+        yield self._launch(self._fn, merged, tctx)
 
     def _execute_stage_terminal(self, pid, tctx, target: int):
         """Sort/window stage terminal: the absorbed partition sort (and
@@ -577,9 +589,7 @@ class WindowExec(PhysicalPlan):
             merged = (ColumnarBatch.concat(batches) if len(batches) > 1
                       else batches[0])
             tctx.inc_metric("windowStageFusedBatches")
-            from .base import count_stage_dispatch
-            count_stage_dispatch()
-            yield self._fused_fn(merged)
+            yield self._launch(self._fused_fn, merged, tctx)
             return
         yield from self._execute_key_batched(
             pid, tctx, target, source=s.execute_batches(batches, tctx))

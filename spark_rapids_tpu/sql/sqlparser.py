@@ -732,12 +732,29 @@ class Parser:
         e = self._multiplicative()
         while True:
             if self.accept_op("+"):
-                e = self._fold_interval(A.Add, e, self._multiplicative())
+                e = self._fold_interval(A.Add, e, self._addend())
             elif self.accept_op("-"):
-                e = self._fold_interval(A.Subtract, e,
-                                        self._multiplicative())
+                e = self._fold_interval(A.Subtract, e, self._addend())
             else:
                 return e
+
+    def _addend(self) -> Expression:
+        """The operand after ``+``/``-``.  ``<n> <unit>`` right before a
+        closing parenthesis is a labeled duration, the form the TPC-DS
+        templates write their date ranges in (``(cast('1999-02-22' as
+        date) + 30 days)``, query98.tpl): the same value as ``INTERVAL
+        <n> <unit>``.  Anywhere else a name after a number stays what it
+        was, the implicit alias of a select item."""
+        v, u = self.peek(), self.peek(1)
+        if v.kind == "num" and v.text.isdigit() and u.kind == "ident" \
+                and u.text.lower() in _INTERVAL_UNITS \
+                and self.peek(2).kind == "op" and self.peek(2).text == ")":
+            self.next()
+            self.next()
+            months, days, micros = (
+                int(v.text) * k for k in _INTERVAL_UNITS[u.text.lower()])
+            return IntervalLiteral(months, days, micros)
+        return self._multiplicative()
 
     def _fold_interval(self, cls, a: Expression, b: Expression
                        ) -> Expression:

@@ -709,6 +709,12 @@ WHERE ss_sold_time_sk = t_time_sk AND ss_hdemo_sk = hd_demo_sk
 
 
 def _oracle_q98(got, t):
+    """A simplified q98 (ids and ``d_year`` in place of the strings and
+    the thirty days).  The official ``query98.tpl`` text with its
+    qualification parameters is ``benchmarks/queries/tpcds_q98.sql``, tested
+    against ``benchmarks/reference/tpcds_q98.py`` in
+    ``tests/test_tpcds_report.py`` and measured by the cell
+    ``tpcds-sf100-report-q98``."""
     pdf = _merged(t, ["date_dim", "item"])
     pdf = pdf[pdf.i_category_id.isin([1, 2, 3]) & (pdf.d_year == 1999)]
     grouped = (pdf.groupby(["i_item_id", "i_category_id", "i_class_id",

@@ -37,6 +37,9 @@ QUICK_MODULES = {
     # the planner's column pruning (ISSUE 34): the rule by node type, the
     # same answers with and without it, the join cells' plans at their sizes
     "test_column_pruning",
+    # TPC-DS q98 (ISSUE 35): the window, the global sort and the range
+    # exchange against the benchmark's reference, NULLs, ties, zero totals
+    "test_tpcds_report",
     "test_memory", "test_native", "test_cross_slice", "test_hive_udf",
     # observability tracer: tier-1 per ISSUE 3 (trace regressions must
     # surface in the quick gate, not only in full CI)

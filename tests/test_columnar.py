@@ -457,6 +457,36 @@ class TestLexSortNetwork:
         for a, b in zip(got, want):
             assert np.array_equal(np.asarray(a), b)
 
+    @pytest.mark.parametrize("log2,words", [(13, 17), (12, 33), (11, 90)])
+    def test_a_wide_comparator_below_2_14_rows_is_the_network(
+            self, as_on_the_chip, log2, words):
+        """A group-by or an ORDER BY over a 200-character string is ~90
+        key words a row: ``lax.sort``'s compile time grows with rows x
+        operands (41 s at 2^13 x 16; unfinished after 330 s at 2^13 x 91
+        on the chip's host, PERF.md section 6 PR 35), so above 2^17
+        word-rows the network sorts the small batches too."""
+        import jax
+        import jax.numpy as jnp
+
+        from spark_rapids_tpu.ops import ranks
+        n = 1 << log2
+        rng = np.random.default_rng(words)
+        makers = [lambda: rng.integers(0, 2, n).astype(bool),
+                  lambda: rng.integers(-2, 2, n).astype(np.int32),
+                  lambda: (rng.integers(-1, 2, n) + 2**31).astype(np.uint32)]
+        keys = [makers[i % 3]() for i in range(words)]
+        assert ranks._network_sorts(n, [jnp.asarray(k) for k in keys])
+        assert not ranks._network_sorts(n, [jnp.asarray(k)
+                                            for k in keys[:words // 2]])
+        lowered = jax.jit(lambda *k: ranks.lex_sort(jnp, list(k))).lower(
+            *keys)
+        assert "stablehlo.sort" not in lowered.as_text()
+        perm, got = lowered.compile()(*keys)
+        want_perm, want = ranks.lex_sort(np, keys)
+        assert np.array_equal(np.asarray(perm), want_perm)
+        for a, b in zip(got, want):
+            assert np.array_equal(np.asarray(a), b)
+
     @pytest.mark.parametrize("n,kind", [(1 << 13, "i"), (1 << 17, "i"),
                                         (20_000, "i"), (1 << 15, "f")])
     def test_other_sizes_and_floats_keep_lax_sort(self, as_on_the_chip,

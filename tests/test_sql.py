@@ -448,6 +448,33 @@ def test_interval_arithmetic(spark):
     assert naive(r["f"]) == datetime.datetime(2020, 1, 31, 10)
 
 
+def test_labeled_durations_of_the_tpcds_templates(spark):
+    """``(date + 30 days)``: the form query98.tpl and its like write their
+    date ranges in, the same value as ``INTERVAL 30 DAYS``; a name after a
+    number that no parenthesis closes is still a select item's alias."""
+    import datetime
+    one = spark.sql(
+        "SELECT (CAST('1999-02-22' AS date) + 30 days) AS hi, "
+        "(CAST('2000-03-11' AS date) - 30 days) AS lo, "
+        "(CAST('2000-01-31' AS date) + 1 month) AS m, "
+        "1 + 30 days").collect()
+    row = one.to_pylist()[0]
+    assert row["hi"] == datetime.date(1999, 3, 24)
+    assert row["lo"] == datetime.date(2000, 2, 10)
+    assert row["m"] == datetime.date(2000, 2, 29)
+    assert row["days"] == 31
+    t = pa.table({"d": pa.array([datetime.date(1999, 2, 21),
+                                 datetime.date(1999, 2, 22),
+                                 datetime.date(1999, 3, 24),
+                                 datetime.date(1999, 3, 25)])})
+    spark.create_dataframe(t).createOrReplaceTempView("t_days")
+    got = spark.sql(
+        "SELECT count(*) AS c FROM t_days WHERE d BETWEEN "
+        "cast('1999-02-22' as date) AND (cast('1999-02-22' as date) "
+        "+ 30 days)").collect().to_pylist()[0]["c"]
+    assert got == 2
+
+
 def test_string_literal_backslash_escapes(spark):
     """Spark default (escapedStringLiterals=false): '\\\\d' is the 2-char
     regex escape, '\\n' a newline, '' a quote, \\% keeps its backslash."""

@@ -131,20 +131,24 @@ def test_join_probe_prefix_sum_compiles_for_v5e(one_chip, log2):
     assert all(int(w.split("x")[-1]) <= 1024 for w in windows), windows
 
 
-@pytest.mark.parametrize("log2,operands", [(15, 2), (14, 6), (16, 6)])
+@pytest.mark.parametrize("log2,operands", [(15, 2), (14, 6), (16, 6),
+                                           (13, 91), (15, 58)])
 def test_small_lex_sort_is_a_rolled_network_for_v5e(one_chip, monkeypatch,
                                                     log2, operands):
     """A join's build sort (a bool and an int32 key at 2^15 rows) and a
     string-keyed sort (six 32-bit words) between 2^14 and 2^16 rows hold
     no ``lax.sort``: one such program took the chip's compiler 20-200 s in
     every cold process (PERF.md section 6, PR 33); the network's loop body
-    compiles at once."""
+    compiles at once.  So do TPC-DS q98's group ids (2^13 rows x 91 key
+    words: a 200-character string among the keys; ``lax.sort`` had not
+    compiled after 330 s on the chip's host, PERF.md section 6, PR 35) and
+    the sort of its answer (2^15 rows x 58 words)."""
     import time
 
     from spark_rapids_tpu.ops import ranks
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     n = 1 << log2
-    kinds = [jnp.bool_] + [jnp.int32, jnp.uint32] * 3
+    kinds = [jnp.bool_] + [jnp.int32, jnp.uint32] * (operands // 2)
     t0 = time.perf_counter()
     _compile(lambda *k: ranks.lex_sort(jnp, list(k)),
              *[jax.ShapeDtypeStruct((n,), kinds[i], sharding=one_chip)
