@@ -18,7 +18,7 @@ file supports; correctness beats the fast path).
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
@@ -32,12 +32,14 @@ from .device_parquet import (_buf_to_words, _max_string_matrix_bytes,
 
 
 def decode_file(path: str, options: Dict, out_fields, tctx=None,
-                conf=None, raw: Optional[bytes] = None
-                ) -> Optional[ColumnarBatch]:
+                conf=None, raw: Optional[bytes] = None,
+                columns: Optional[Sequence[int]] = None,
+                width: Optional[int] = None) -> Optional[ColumnarBatch]:
     """Decode one CSV file into a :class:`ColumnarBatch` typed by the
     plan's output fields, or ``None`` to decline to the host reader.
     Callers that already read the file pass ``raw`` so a decline does
-    not re-read it from disk."""
+    not re-read it from disk.  A narrowed scan's ``out_fields`` are some
+    of a line's ``width`` fields: those at positions ``columns``."""
     sep = str(options.get("sep", options.get("delimiter", ",")))
     if len(sep) != 1:
         return None
@@ -71,7 +73,7 @@ def decode_file(path: str, options: Dict, out_fields, tctx=None,
     if n == 0:
         return None
 
-    ncols = len(out_fields)
+    ncols = width or len(out_fields)
     dp = np.flatnonzero(buf == ord(sep)).astype(np.int64)
     dp = dp[dp >= starts[0]]
     if ncols > 1:
@@ -94,7 +96,8 @@ def decode_file(path: str, options: Dict, out_fields, tctx=None,
     from ..ops import cast_strings as CS
     cols = []
     fail_counts = []
-    for ci, fld in enumerate(out_fields):
+    for ci, fld in zip(range(ncols) if columns is None else columns,
+                       out_fields):
         dt = fld.dtype if hasattr(fld, "dtype") else fld.data_type
         if isinstance(dt, T.NullType):
             cols.append(null_column(dt, capacity))

@@ -1000,10 +1000,12 @@ def _dtype_ok(kind: int, dtype) -> bool:
 
 
 def decode_file(path: str, stripes: Optional[List[int]] = None,
-                tctx=None, orc_file=None, conf=None):
+                tctx=None, orc_file=None, conf=None,
+                columns: Optional[List[str]] = None):
     """Decode (a subset of stripes of) one ORC file into a
     :class:`ColumnarBatch`, device-decoding every column the envelope
-    supports and falling back to pyarrow per column otherwise.  Returns
+    supports and falling back to pyarrow per column otherwise; of the
+    fields ``columns`` names alone (None: all), in the order given.  Returns
     ``None`` when no column takes the device path (callers use their
     host read wholesale) — the same contract as
     :func:`.device_parquet.decode_file`."""
@@ -1013,7 +1015,7 @@ def decode_file(path: str, stripes: Optional[List[int]] = None,
     from ..columnar.batch import ColumnarBatch
     from ..columnar.column import bucket_capacity
     from ..columnar.convert import arrow_to_device_column
-    from .device_parquet import _max_string_matrix_bytes
+    from .device_parquet import _max_string_matrix_bytes, wanted_fields
 
     if orc_file is None:
         orc_file = pa_orc.ORCFile(path)
@@ -1070,9 +1072,13 @@ def decode_file(path: str, stripes: Optional[List[int]] = None,
         set_decline_reason("unsupported-stripe-footer")
         return None
 
+    wanted = wanted_fields(schema, columns)
+    if wanted is None:
+        return None
     device_cols: Dict[int, object] = {}
     host_fields: List[int] = []
-    for fi, fld in enumerate(schema):
+    for fi in wanted:
+        fld = schema.field(fi)
         tid = field_type_id.get(fi)
         try:
             dtype = T.from_arrow(fld.type)
@@ -1129,5 +1135,5 @@ def decode_file(path: str, stripes: Optional[List[int]] = None,
             if tctx is not None:
                 tctx.inc_metric("orcHostDecodedColumns")
 
-    cols = [device_cols[fi] for fi in range(len(schema))]
-    return ColumnarBatch.make([f.name for f in schema], cols, n_rows)
+    return ColumnarBatch.make([schema.field(fi).name for fi in wanted],
+                              [device_cols[fi] for fi in wanted], n_rows)

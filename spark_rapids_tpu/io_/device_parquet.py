@@ -1147,11 +1147,47 @@ def _precheck_chunk_meta(cc) -> None:
                 "PLAIN byte-array walk without native helper")
 
 
+def wanted_fields(schema, columns: Optional[Sequence[str]]
+                  ) -> Optional[List[int]]:
+    """Field indices of ``schema`` (a file's Arrow schema) for the names
+    ``columns``, in the order given (None: every field).  None, with the
+    decline reason set, where the file has no such column: the host path
+    then reads the file and pyarrow says which."""
+    if columns is None:
+        return list(range(len(schema)))
+    wanted = [schema.get_field_index(name) for name in columns]
+    if any(fi < 0 for fi in wanted):
+        from .decode_stats import set_decline_reason
+        set_decline_reason("no-such-column")
+        return None
+    return wanted
+
+
+def chunk_bytes(md, row_groups: Sequence[int],
+                columns: Optional[Sequence[str]] = None) -> int:
+    """Uncompressed bytes of the column chunks a read of ``columns`` (top-
+    level field names; None: every column) of ``row_groups`` decodes: what
+    the scan's spans and ``decode_stats`` count for a run."""
+    if columns is None:
+        return sum(md.row_group(rg).total_byte_size for rg in row_groups)
+    wanted = set(columns)
+    rg0 = md.row_group(row_groups[0]) if row_groups else None
+    leaves = [li for li in range(rg0.num_columns if rg0 else 0)
+              if rg0.column(li).path_in_schema.split(".", 1)[0] in wanted]
+    return sum(md.row_group(rg).column(li).total_uncompressed_size
+               for rg in row_groups for li in leaves)
+
+
 def decode_file(path: str, row_groups: Optional[Sequence[int]] = None,
-                tctx=None, pf=None, conf=None):
+                tctx=None, pf=None, conf=None,
+                columns: Optional[Sequence[str]] = None):
     """Decode (a subset of row groups of) one parquet file into a
     :class:`ColumnarBatch`, device-decoding every column the envelope
     supports and falling back to pyarrow per column otherwise.
+
+    ``columns`` names the fields to decode (None: all of them): no other
+    column's chunk is read from the file, and the batch holds them in the
+    order given.
 
     Returns ``None`` when no column takes the device path, or when safe
     decode requires the host pipeline's whole-table handling (ragged
@@ -1163,11 +1199,15 @@ def decode_file(path: str, row_groups: Optional[Sequence[int]] = None,
     from ..columnar.batch import ColumnarBatch
     from ..columnar.column import bucket_capacity
     from ..columnar.convert import arrow_to_device_column
+    from ..observability import tracer as _trace
 
     if pf is None:
         pf = pq.ParquetFile(path)   # callers with an open handle pass it in
     md = pf.metadata
     schema = pf.schema_arrow
+    wanted = wanted_fields(schema, columns)
+    if wanted is None:
+        return None
     rgs = list(range(md.num_row_groups)) if row_groups is None \
         else list(row_groups)
     if not rgs:
@@ -1191,7 +1231,8 @@ def decode_file(path: str, row_groups: Optional[Sequence[int]] = None,
     device_cols: Dict[int, object] = {}
     host_fields: List[int] = []
     with open(path, "rb") as fobj:
-        for fi, fld in enumerate(schema):
+        for fi in wanted:
+            fld = schema.field(fi)
             li = leaf_of_field.get(fi)
             try:
                 dtype = T.from_arrow(fld.type)
@@ -1252,12 +1293,20 @@ def decode_file(path: str, row_groups: Optional[Sequence[int]] = None,
         set_decline_reason("no-device-columns")
         return None
     if host_fields:
+        # these columns alone take the host's way, under the spans of the
+        # whole-run host path: scan_host_ms and h2d_ms count them
         names = [schema.field(fi).name for fi in host_fields]
-        tbl = pf.read_row_groups(rgs, columns=names)
-        for k, fi in enumerate(host_fields):
-            device_cols[fi] = arrow_to_device_column(tbl.column(k), capacity)
-            if tctx is not None:
-                tctx.inc_metric("parquetHostDecodedColumns")
+        with _trace.span("scan", "host_decode", row_groups=len(rgs),
+                         bytes=chunk_bytes(md, rgs, names),
+                         columns=len(names), declined="per-column"):
+            tbl = pf.read_row_groups(rgs, columns=names)
+        with _trace.span("h2d", "arrow_to_device", bytes=tbl.nbytes,
+                         rows=n_rows):
+            for k, fi in enumerate(host_fields):
+                device_cols[fi] = arrow_to_device_column(tbl.column(k),
+                                                         capacity)
+                if tctx is not None:
+                    tctx.inc_metric("parquetHostDecodedColumns")
 
-    cols = [device_cols[fi] for fi in range(len(schema))]
-    return ColumnarBatch.make([f.name for f in schema], cols, n_rows)
+    return ColumnarBatch.make([schema.field(fi).name for fi in wanted],
+                              [device_cols[fi] for fi in wanted], n_rows)

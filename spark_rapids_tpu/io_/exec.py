@@ -6,7 +6,12 @@ reference's host-side scan pipeline: path replacement + file cache
 (``filecache.py``), footer-statistics row-group pruning against pushed
 filter conjuncts (``pushdown.py``; ``GpuParquetScan.scala:2765``), and
 chunked multi-batch reads (``spark.rapids.sql.reader.chunked``,
-``RapidsConf.scala:568``)."""
+``RapidsConf.scala:568``).
+
+A scan the planner narrowed (``ScanRelation.narrowed``,
+``sql/column_pruning.py``) reads, decodes and uploads the columns of its
+output and no other, on every path below: a file's columns are found by
+their position in the whole schema, as the whole scan binds them."""
 
 from __future__ import annotations
 
@@ -25,9 +30,15 @@ from ..config import (CSV_DEVICE_DECODE, JSON_DEVICE_DECODE,
                       READER_CHUNKED, READER_CHUNKED_TARGET_ROWS,
                       RapidsConf)
 from ..observability import tracer as _trace
-from ..sql.physical.base import CPU, TPU, PhysicalPlan, TaskContext
+from ..sql.physical.base import (CPU, TPU, PhysicalPlan, ScanColumnCounter,
+                                 TaskContext)
 from . import registry
 from .filecache import resolve_read_path
+
+
+#: ``FileScanExec._read``: cut the file to its own columns at the output's
+#: positions
+_OWN = object()
 
 
 class FileScanExec(PhysicalPlan):
@@ -47,19 +58,48 @@ class FileScanExec(PhysicalPlan):
         #: scan-adjacent filter; used for row-group pruning only — the
         #: device filter above still applies the full predicate
         self.pushed_filters: List = []
+        #: positions of the whole schema this scan hands on (None: all)
+        self._keep = node.columns
+        self._columns_counted = ScanColumnCounter()
 
     @property
     def output(self):
         return self.node.output
+
+    def _names_in(self, file_names) -> Optional[List[str]]:
+        """A file's own names of the columns this scan hands on, in the
+        output's order (None: the file is read whole)."""
+        if self._keep is None:
+            return None
+        return [file_names[i] for i in self._keep]
+
+    def _narrow(self, table):
+        """What a reader with no ``columns=`` of its own read, cut to the
+        output's columns before upload."""
+        if self._keep is None:
+            return table
+        return table.select(list(self._keep))
 
     def num_partitions(self):
         if self.reader_type == "COALESCING":
             return 1
         return max(1, len(self.files))
 
-    def _read(self, path, tctx: Optional[TaskContext] = None):
+    def _read(self, path, tctx: Optional[TaskContext] = None,
+              wanted=_OWN):
+        """One file read on the host and cut to the output's columns: this
+        file's own (``_OWN``), or those of the names ``wanted`` that it has
+        (None: the file whole) where COALESCING lines files up by name."""
         path = resolve_read_path(path, self.conf)
-        if self.node.fmt == "parquet" and self.pushed_filters and \
+        fmt = self.node.fmt
+        names = None
+        if fmt in ("parquet", "orc") and wanted is not None \
+                and self._keep is not None:
+            # a reader with a ``columns=`` of its own decodes no other
+            have = registry.schema_names(fmt, path)
+            names = self._names_in(have) if wanted is _OWN \
+                else [n for n in wanted if n in have]
+        if fmt == "parquet" and self.pushed_filters and \
                 bool(self.conf.get(PARQUET_PUSHDOWN_ENABLED)):
             import pyarrow.parquet as pq
             from .pushdown import prune_row_groups
@@ -69,11 +109,15 @@ class FileScanExec(PhysicalPlan):
                 self._emit_prune_stats(
                     (pf.metadata.num_row_groups, len(keep)), tctx)
                 if not keep:
-                    return pf.schema_arrow.empty_table()
-                return self._host_decode(pf, keep)
-        with _trace.span("scan", "host_decode", fmt=self.node.fmt):
-            return registry.read_file(self.node.fmt, path,
-                                      self.node.options)
+                    empty = pf.schema_arrow.empty_table()
+                    return empty if names is None else empty.select(names)
+                return self._host_decode(pf, keep, names=names)
+        with _trace.span("scan", "host_decode", fmt=fmt):
+            table = registry.read_file(fmt, path, self.node.options,
+                                       columns=names)
+        if names is None and wanted is _OWN:
+            table = self._narrow(table)
+        return table
 
     def _read_chunked_orc(self, path, tctx: TaskContext):
         """ORC chunked reads: one pa.Table per stripe run up to the
@@ -83,13 +127,15 @@ class FileScanExec(PhysicalPlan):
         import pyarrow.orc as orc
         path = resolve_read_path(path, self.conf)
         f = orc.ORCFile(path)
+        names = self._names_in(f.schema.names)
         if tctx is not None:
             tctx.inc_metric("orcStripesTotal", f.nstripes)
         target = int(self.conf.get(READER_CHUNKED_TARGET_ROWS))
         run: List = []
         rows = 0
         for i in range(f.nstripes):
-            run.append(pa.Table.from_batches([f.read_stripe(i)]))
+            run.append(pa.Table.from_batches(
+                [f.read_stripe(i, columns=names)]))
             rows += run[-1].num_rows
             if rows >= target:
                 yield pa.concat_tables(run)
@@ -97,7 +143,7 @@ class FileScanExec(PhysicalPlan):
         if run:
             yield pa.concat_tables(run)
         if f.nstripes == 0:
-            yield f.read()
+            yield f.read(columns=names)
 
     def _read_chunked(self, path, tctx: TaskContext):
         """Yield one pa.Table per run of row groups up to the chunk-row
@@ -107,22 +153,24 @@ class FileScanExec(PhysicalPlan):
         pf, runs, prune_stats = self._parquet_runs(path)
         self._emit_prune_stats(prune_stats, tctx)
         if not runs:
-            yield pf.schema_arrow.empty_table()
+            yield self._narrow(pf.schema_arrow.empty_table())
             return
+        names = self._names_in(pf.schema_arrow.names)
         for run in runs:
-            yield self._host_decode(pf, run)
+            yield self._host_decode(pf, run, names=names)
 
     @staticmethod
-    def _host_decode(pf, run, declined: str = ""):
-        """pyarrow's read of one row-group run, as its own span: the
-        caller hands the table to ``upload`` afterwards, so decode and
-        H2D are told apart (``declined``: why the device decoder gave the
-        run up, '' when it was never asked)."""
+    def _host_decode(pf, run, declined: str = "", names=None):
+        """pyarrow's read of one row-group run (of the columns ``names``;
+        None: all), as its own span: the caller hands the table to
+        ``upload`` afterwards, so decode and H2D are told apart
+        (``declined``: why the device decoder gave the run up, '' when it
+        was never asked)."""
+        from .device_parquet import chunk_bytes
         with _trace.span("scan", "host_decode", row_groups=len(run),
-                         bytes=sum(pf.metadata.row_group(rg).total_byte_size
-                                   for rg in run),
+                         bytes=chunk_bytes(pf.metadata, run, names),
                          declined=declined):
-            return pf.read_row_groups(run)
+            return pf.read_row_groups(run, columns=names)
 
     def _parquet_runs(self, path: str):
         """The ONE implementation of prune-then-split for parquet reads
@@ -177,34 +225,34 @@ class FileScanExec(PhysicalPlan):
         splitting applies exactly as on the host pipeline."""
         import jax
 
-        from .device_parquet import decode_file
+        from .device_parquet import chunk_bytes, decode_file
 
         path = resolve_read_path(path, self.conf)
         pf, runs, prune_stats = self._parquet_runs(path)
         self._emit_prune_stats(prune_stats, tctx)
         chunked = bool(self.conf.get(READER_CHUNKED))
         if not runs:
-            yield from upload(pf.schema_arrow.empty_table())
+            yield from upload(self._narrow(pf.schema_arrow.empty_table()))
             return
         from . import decode_stats as DS
+        names = self._names_in(pf.schema_arrow.names)
         declined = False   # a whole-file decline holds for every run
         for run in runs:
             if chunked:
                 tctx.inc_metric("chunkedReadBatches")
-            run_bytes = sum(pf.metadata.row_group(rg).total_byte_size
-                            for rg in run)
+            run_bytes = chunk_bytes(pf.metadata, run, names)
             batch = None
             if not declined:
                 with _trace.span("scan", "device_decode", bytes=run_bytes,
                                  row_groups=len(run)):
                     batch = decode_file(path, run, tctx, pf=pf,
-                                        conf=self.conf)
+                                        conf=self.conf, columns=names)
             if batch is None:
                 reason = DS.record_declined(
                     "parquet", run_bytes,
                     reason="prior-decline" if declined else None)
                 declined = True
-                yield from upload(self._host_decode(pf, run, reason))
+                yield from upload(self._host_decode(pf, run, reason, names))
             else:
                 DS.record_engaged("parquet", run_bytes)
                 yield batch if self.backend != CPU \
@@ -222,11 +270,12 @@ class FileScanExec(PhysicalPlan):
 
         path = resolve_read_path(path, self.conf)
         f = pa_orc.ORCFile(path)
+        names = self._names_in(f.schema.names)
         if tctx is not None:
             tctx.inc_metric("orcStripesTotal", f.nstripes)
         stripes = list(range(f.nstripes))
         if not stripes:
-            yield from upload(f.read())
+            yield from upload(f.read(columns=names))
             return
         if bool(self.conf.get(READER_CHUNKED)):
             target = int(self.conf.get(READER_CHUNKED_TARGET_ROWS))
@@ -261,7 +310,7 @@ class FileScanExec(PhysicalPlan):
                                  stripes=len(run)):
                     batch = decode_file(
                         path, run if len(runs) > 1 else None, tctx,
-                        orc_file=f, conf=self.conf)
+                        orc_file=f, conf=self.conf, columns=names)
             if batch is None:
                 reason = DS.record_declined(
                     "orc", run_bytes,
@@ -271,10 +320,11 @@ class FileScanExec(PhysicalPlan):
                                  stripes=len(run), declined=reason):
                     if len(runs) > 1:
                         table = pa.concat_tables(
-                            [pa.Table.from_batches([f.read_stripe(s)])
+                            [pa.Table.from_batches(
+                                [f.read_stripe(s, columns=names)])
                              for s in run])
                     else:
-                        table = f.read()
+                        table = f.read(columns=names)
                 yield from upload(table)
             else:
                 DS.record_engaged("orc", run_bytes)
@@ -292,23 +342,24 @@ class FileScanExec(PhysicalPlan):
         import jax
 
         from ..columnar.batch import ColumnarBatch
-        from .device_parquet import decode_file
+        from .device_parquet import chunk_bytes, decode_file
         batches = []
         extra = []
+        names = self._names_in(schema0.names)   # every file's schema
         for path, pf, groups, prune_stats in infos:
             self._emit_prune_stats(prune_stats, tctx)
             if not groups:
                 continue
             from . import decode_stats as DS
-            nb = sum(pf.metadata.row_group(rg).total_byte_size
-                     for rg in groups)
+            nb = chunk_bytes(pf.metadata, groups, names)
             with _trace.span("scan", "device_decode", bytes=nb,
                              row_groups=len(groups)):
                 batch = decode_file(path, groups, tctx, pf=pf,
-                                    conf=self.conf)
+                                    conf=self.conf, columns=names)
             if batch is None:
                 reason = DS.record_declined("parquet", nb)
-                pieces = upload(self._host_decode(pf, groups, reason))
+                pieces = upload(self._host_decode(pf, groups, reason,
+                                                  names))
                 if len(pieces) == 1:
                     batches.append(pieces[0])
                 else:
@@ -325,11 +376,11 @@ class FileScanExec(PhysicalPlan):
         elif not extra:
             # everything pruned away: same empty-schema batch the host
             # path produces
-            yield from upload(schema0.empty_table())
+            yield from upload(self._narrow(schema0.empty_table()))
         yield from extra
 
     def _text_device_scan(self, pid, tctx, upload, opts, decode_fn,
-                          host_read_fn):
+                          host_read_fn, **narrowing):
         """Shared read-decode-decline protocol for the text-format device
         parsers (CSV and JSON): read the bytes once, try the device
         decoder, and on decline re-parse the SAME bytes on host — no
@@ -349,7 +400,7 @@ class FileScanExec(PhysicalPlan):
         from . import decode_stats as DS
         fmt = registry._normalize_fmt(self.node.fmt, opts)
         batch = decode_fn(path, opts, self.node.output, tctx, self.conf,
-                          raw=raw)
+                          raw=raw, **narrowing)
         if batch is not None:
             DS.record_engaged(fmt, len(raw))
             if self.backend == CPU:
@@ -357,12 +408,17 @@ class FileScanExec(PhysicalPlan):
             yield batch
             return True
         DS.record_declined(fmt, len(raw))
-        for piece in upload(host_read_fn(_io.BytesIO(raw), opts)):
+        for piece in upload(self._narrow(
+                host_read_fn(_io.BytesIO(raw), opts))):
             yield piece
         return True
 
     def execute(self, pid: int, tctx: TaskContext):
         import jax
+
+        read = len(self.node.output)
+        self._columns_counted.count(tctx, read,
+                                    self.node.file_width or read)
 
         def upload_one(table):
             batch = arrow_to_device(table, conf=self.conf)
@@ -411,12 +467,23 @@ class FileScanExec(PhysicalPlan):
                     yield from self._coalescing_device(infos, schema0,
                                                        tctx, upload)
                     return
+            # the promote-concat lines files up by name: each is asked for
+            # the first file's names of the output's columns, where its
+            # reader can select; what is left is cut after the concat
+            wanted = None
+            if self._keep is not None and self.files and \
+                    self.node.fmt in ("parquet", "orc"):
+                wanted = self._names_in(registry.schema_names(
+                    self.node.fmt,
+                    resolve_read_path(self.files[0], self.conf)))
             n_threads = int(self.conf.get(MULTITHREAD_READ_NUM_THREADS))
             with ThreadPoolExecutor(max_workers=n_threads) as pool:
-                tables = list(pool.map(lambda p: self._read(p, tctx),
-                                       self.files))
+                tables = list(pool.map(
+                    lambda p: self._read(p, tctx, wanted), self.files))
             if tables:
-                yield from upload(pa.concat_tables(tables, promote_options="default"))
+                table = pa.concat_tables(tables, promote_options="default")
+                yield from upload(self._narrow(table) if wanted is None
+                                  else table.select(wanted))
             return
 
         if pid >= len(self.files):
@@ -448,9 +515,11 @@ class FileScanExec(PhysicalPlan):
         text_fmt = registry._normalize_fmt(self.node.fmt, opts)
         if text_fmt == "csv" and bool(self.conf.get(CSV_DEVICE_DECODE)):
             from .device_csv import decode_file as _decode
+            # a line's fields are found by position; JSON's by name
             done = yield from self._text_device_scan(
                 pid, tctx, upload, opts, _decode,
-                registry.read_csv_source)
+                registry.read_csv_source, columns=self._keep,
+                width=self.node.file_width)
             if done:
                 return
         if text_fmt == "json" and bool(self.conf.get(JSON_DEVICE_DECODE)):
@@ -494,5 +563,8 @@ class FileScanExec(PhysicalPlan):
             fs = ", ".join(f"{c} {op} {v!r}" for c, op, v in
                            self.pushed_filters)
             extra = f" pushed=[{fs}]"
+        if self._keep is not None:
+            extra += (f" columns=[{', '.join(a.name for a in self.output)}]"
+                      f" of {self.node.file_width}")
         return (f"{self.node_name()} {self.node.fmt} "
                 f"[{len(self.files)} files, {self.reader_type}]{extra}")

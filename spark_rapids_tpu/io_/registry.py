@@ -66,6 +66,15 @@ def infer_schema(fmt: str, paths: Sequence[str], options: Dict) -> T.StructType:
         for f in schema))
 
 
+def schema_names(fmt: str, path: str) -> List[str]:
+    """A parquet or ORC file's own column names, from its footer."""
+    if fmt == "parquet":
+        import pyarrow.parquet as pq
+        return pq.read_schema(path).names
+    import pyarrow.orc as orc
+    return orc.ORCFile(path).schema.names
+
+
 def read_csv_source(src, options: Dict,
                     columns: Optional[List[str]] = None) -> pa.Table:
     """CSV parse over a path OR a file-like source (the device decoder's

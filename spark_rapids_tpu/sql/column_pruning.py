@@ -5,14 +5,16 @@ itself, at the head of ``Planner.plan``.
 
 One top-down pass.  Each node is handed the positions of its output that its
 parent reads and asks its children for those plus what its own expressions
-reference.  Two kinds of node shrink: an in-memory leaf (``Relation``,
-``CachedRelation``) becomes a narrowed copy over the SAME table and partition
-objects (the scan's upload cache is keyed by them), and a ``Project`` or an
-``Expand`` drops the expressions nobody reads (the SQL front end puts a
-``select *`` project over every relation and every join).  Every other node
-keeps its own output and only passes the requirement down; a node the rule
-does not know requires every column of its children.  ``ScanRelation`` is
-left whole (ROADMAP Queue 1 item 1).
+reference.  Two kinds of node shrink: a leaf becomes a narrowed copy — an
+in-memory one (``Relation``, ``CachedRelation``) over the SAME table and
+partition objects (the scan's upload cache is keyed by them), a file scan
+(``ScanRelation``) over the same paths and options with a ``read_schema`` of
+the columns read, which ``FileScanExec`` then reads, decodes and uploads and
+no other — and a ``Project`` or an ``Expand`` drops the expressions nobody
+reads (the SQL front end puts a ``select *`` project over every relation and
+every join).  Every other node keeps its own output and only passes the
+requirement down; a node the rule does not know requires every column of its
+children.
 
 A reference is resolved the way ``bind_references`` binds it: by ``expr_id``
 first, then by name — and by name every match is kept, so what binds first
@@ -42,8 +44,8 @@ Pruned = Tuple[P.LogicalPlan, Tuple[int, ...]]
 
 
 def prune_columns(plan: P.LogicalPlan) -> P.LogicalPlan:
-    """``plan`` with every in-memory scan narrowed to the columns the query
-    reads.  The root keeps its whole output."""
+    """``plan`` with every scan, of a table in memory or of files, narrowed
+    to the columns the query reads.  The root keeps its whole output."""
     return _Pruner().prune(plan, _all(plan.output))[0]
 
 
@@ -135,11 +137,11 @@ class _Pruner:
         keep = sorted(required) or [_narrowest(out)]
         return node.narrowed([out[i] for i in keep]), tuple(keep)
 
-    _CachedRelation = _Relation
+    _CachedRelation = _ScanRelation = _Relation
 
     def _unknown(self, node, required: Positions) -> Pruned:
-        """A leaf that is not an in-memory table, a pandas node, anything
-        added later: all of its own output, all of its children's."""
+        """A leaf that is no table and no file (``Range``), a pandas node,
+        anything added later: all of its own output, all of its children's."""
         kids = [self.prune(c, _all(c.output))[0] for c in node.children]
         return (_with_children(node, kids),
                 tuple(range(len(node.output))))

@@ -463,6 +463,26 @@ class PhysicalPlan:
         return "\n".join(lines)
 
 
+class ScanColumnCounter:
+    """``scanColumnsRead`` / ``scanColumnsPruned`` of last_query_metrics for
+    one scan exec (``sql/column_pruning.py``): counted once a scan and
+    collect, whichever partition comes first."""
+
+    _NEVER = object()       # a query context may be None
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counted_in = self._NEVER
+
+    def count(self, tctx: "TaskContext", read: int, whole: int) -> None:
+        with self._lock:
+            if self._counted_in is tctx.query_ctx:
+                return
+            self._counted_in = tctx.query_ctx
+        tctx.inc_metric("scanColumnsRead", read)
+        tctx.inc_metric("scanColumnsPruned", whole - read)
+
+
 def count_stage_dispatch(n: float = 1) -> None:
     """Account ``n`` device-program dispatches to the current task's
     ``stageOpDispatches`` metric — the stage-scope dispatch counter

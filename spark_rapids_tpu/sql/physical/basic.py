@@ -15,7 +15,8 @@ from ... import types as T
 from ..expressions.core import (Alias, AttributeReference, BoundReference,
                                 EvalContext, Expression, bind_references)
 from ..plan import SortOrder
-from .base import CPU, TPU, PhysicalPlan, TaskContext
+from .base import (CPU, TPU, PhysicalPlan, ScanColumnCounter,
+                   TaskContext)
 
 
 def _to_backend_batch(batch: ColumnarBatch, backend: str) -> ColumnarBatch:
@@ -49,11 +50,6 @@ _UPLOAD_CACHE: dict = {}
 #: two and dropping one (a lost entry would double HBM residency)
 import threading as _threading
 _UPLOAD_LOCK = _threading.Lock()
-
-
-#: ``InMemoryScanExec._counted_in`` before any collect (a query context may
-#: be None)
-_NOT_COUNTED = object()
 
 
 class _PendingUpload:
@@ -166,8 +162,7 @@ class InMemoryScanExec(PhysicalPlan):
         self._parts = partitions  # List[pa.Table]
         self._names = tuple(a.name for a in self._attrs)
         self._bytes: Optional[int] = None
-        self._count_lock = _threading.Lock()
-        self._counted_in = _NOT_COUNTED
+        self._columns_counted = ScanColumnCounter()
 
     @property
     def output(self):
@@ -186,20 +181,10 @@ class InMemoryScanExec(PhysicalPlan):
                 for t in self._parts)
         return self._bytes
 
-    def _count_columns(self, tctx: TaskContext) -> None:
-        """``scanColumnsRead`` / ``scanColumnsPruned`` of last_query_metrics:
-        once a scan and collect, whichever partition comes first."""
-        with self._count_lock:
-            if self._counted_in is tctx.query_ctx:
-                return
-            self._counted_in = tctx.query_ctx
-        tctx.inc_metric("scanColumnsRead", len(self._names))
-        tctx.inc_metric("scanColumnsPruned",
-                        self._parts[0].num_columns - len(self._names))
-
     def execute(self, pid: int, tctx: TaskContext):
         from ...parallel.placement import home_chip
-        self._count_columns(tctx)
+        self._columns_counted.count(tctx, len(self._names),
+                                    self._parts[0].num_columns)
         for batch in _cached_upload(
                 self._parts[pid], self.backend, tctx.conf,
                 chip=home_chip(pid, tctx.conf) if self.backend == TPU

@@ -134,6 +134,10 @@ class ScanRelation(LogicalPlan):
     paths: Tuple[str, ...] = ()
     read_schema: Optional[T.StructType] = None
     options: dict = field(default_factory=dict)
+    #: a narrowed copy's positions in the file's whole schema (None: this
+    #: node reads every column), and how many columns that schema has
+    columns: Optional[Tuple[int, ...]] = field(default=None, init=False)
+    file_width: Optional[int] = field(default=None, init=False)
 
     @property
     def output(self):
@@ -146,8 +150,28 @@ class ScanRelation(LogicalPlan):
                             for f in self.read_schema.fields]
         return self._output
 
+    def narrowed(self, attrs) -> "ScanRelation":
+        """This scan handing on only ``attrs`` (some of ``output``, in the
+        file's order): the same paths and options, a ``read_schema`` of those
+        fields and, in ``columns``, their positions in the whole schema.
+        ``FileScanExec`` finds them in a file by those positions, as the
+        whole scan binds a file's columns (``sql/column_pruning.py``)."""
+        out = self.output
+        keep = [i for i, a in enumerate(out) if any(a is x for x in attrs)]
+        new = ScanRelation(
+            self.fmt, self.paths,
+            T.StructType(tuple(self.read_schema.fields[i] for i in keep)),
+            self.options)
+        new._output = [out[i] for i in keep]
+        new.columns = tuple(keep) if self.columns is None \
+            else tuple(self.columns[i] for i in keep)
+        new.file_width = self.file_width or len(out)
+        return new
+
     def simple_string(self):
-        return f"Scan {self.fmt} {list(self.paths)[:1]}"
+        cols = "" if self.columns is None else \
+            f" [{', '.join(a.name for a in self.output)}]"
+        return f"Scan {self.fmt} {list(self.paths)[:1]}{cols}"
 
 
 @dataclass(eq=False)

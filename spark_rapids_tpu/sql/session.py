@@ -508,14 +508,18 @@ class TpuSession:
             for k, v0 in rob0.items():
                 m[k] = rob1[k] - v0
             # encoded-execution / prepack / device-decode engagement
-            # deltas (decode counters only when scans actually ran, so
-            # in-memory queries don't carry two dozen zero keys)
+            # deltas (a format's decode counters only when a scan of that
+            # format ran, so in-memory queries don't carry two dozen zero
+            # keys; then all four of them, so a scan that declined nothing
+            # says ``<fmt>DecodeFilesDeclined`` 0)
             if aux0 is not None:
                 aux1 = _aux_stats_snapshot()
-                for k, v0 in aux0.items():
-                    d = aux1.get(k, v0) - v0
-                    if d or not k.endswith(
-                            ("Engaged", "Declined")):
+                delta = {k: aux1.get(k, v0) - v0 for k, v0 in aux0.items()}
+                scanned = {k.split("Decode")[0] for k, d in delta.items()
+                           if d and k.endswith(("Engaged", "Declined"))}
+                for k, d in delta.items():
+                    if not k.endswith(("Engaged", "Declined")) \
+                            or k.split("Decode")[0] in scanned:
                         m[k] = d
         if not tracing:
             self.last_query_trace_summary = None

@@ -112,10 +112,14 @@ QUICK_MODULES = {
     "test_dense_group_reduce",
     # what the benchmark's cells run and the next PRs rework (ISSUE 31):
     # the device parquet decoder against pyarrow (the parquet cell's
-    # longest device programs, Queue 1 item 1), the prepacked D2H every
+    # longest device programs, Queue 1 item 3), the prepacked D2H every
     # collect ends in, and the kernel cache every cell's programs live
     # in — tier-1 time goes where the cells go
     "test_device_parquet", "test_prepack", "test_kernel_cache",
+    # a file scan the planner narrowed (ISSUE 36): every reader path of
+    # FileScanExec, narrowed, against the whole scan; the wide string that
+    # is not read no longer declines the file, the one that is read does
+    "test_scan_pruning",
 }
 
 

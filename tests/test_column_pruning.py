@@ -632,3 +632,207 @@ def test_q7_at_the_cells_size_broadcasts_all_four_dimensions():
         want = reference(C.tables_for_reference(tables, spec["tables"]))
         numbers = C.compare(got.to_pandas(date_as_object=False), want, spec)
         assert C.within(numbers, spec["limits"]) and len(want) == 100
+
+
+# --- a scan of files (ISSUE 36): the rule narrows ``ScanRelation`` too ---------------
+
+def file_scans(plan):
+    """Every ``ScanRelation`` of a logical plan, left to right."""
+    if isinstance(plan, P.ScanRelation):
+        return [plan]
+    return [s for c in plan.children for s in file_scans(c)]
+
+
+def file_columns(plan):
+    return [[a.name for a in s.output] for s in file_scans(plan)]
+
+
+@contextlib.contextmanager
+def scan_rule_off():
+    """The rule as the parent had it: a ``ScanRelation`` is left whole."""
+    rule = CP._Pruner._ScanRelation
+    CP._Pruner._ScanRelation = CP._Pruner._unknown
+    try:
+        yield
+    finally:
+        CP._Pruner._ScanRelation = rule
+
+
+@pytest.fixture(scope="module")
+def lineitem_file(tmp_path_factory):
+    """LINEITEM (16 columns) as the parquet cell stores it, at a 1000th of
+    its size; the session that reads it and the table."""
+    import pyarrow.parquet as pq
+    t = TPCH.build_tables({"scale_factor": 0.001}, 5, ("lineitem",)
+                          )["lineitem"]
+    assert t.num_columns == 16
+    path = str(tmp_path_factory.mktemp("pruned_scan") / "lineitem.parquet")
+    pq.write_table(t, path, row_group_size=2048)
+    with session_of() as s:
+        s.read.parquet(path).createOrReplaceTempView("lineitem")
+        yield s, path, t
+
+
+@pytest.mark.parametrize("q, wanted", [
+    ("tpch_q6", ["l_quantity", "l_extendedprice", "l_discount",
+                 "l_shipdate"]),
+    ("tpch_q1", ["l_quantity", "l_extendedprice", "l_discount", "l_tax",
+                 "l_returnflag", "l_linestatus", "l_shipdate"])])
+def test_the_parquet_cells_queries_read_4_and_7_of_16_columns(
+        lineitem_file, q, wanted):
+    s, path, t = lineitem_file
+    text, spec, reference = query(q)
+    df = s.sql(text)
+    (scan,) = file_scans(CP.prune_columns(df._plan))
+    assert [a.name for a in scan.output] == wanted      # the file's order
+    assert [f.name for f in scan.read_schema.fields] == wanted
+    assert scan.columns == tuple(t.column_names.index(n) for n in wanted)
+    assert scan.file_width == 16 and scan.paths == (path,)
+    (whole,) = file_scans(df._plan)
+    assert len(whole.output) == 16 and whole.columns is None
+    # the same AttributeReference objects: what is above still binds
+    assert all(any(a is b for b in whole.output) for a in scan.output)
+    got = df.collect()
+    m = s.last_query_metrics
+    assert m.get("scanColumnsRead") == len(wanted)
+    assert m.get("scanColumnsPruned") == 16 - len(wanted)
+    want = reference(C.tables_for_reference({"lineitem": t}, spec["tables"]))
+    numbers = C.compare(got.to_pandas(date_as_object=False), want, spec)
+    assert C.within(numbers, spec["limits"]), numbers
+
+
+def test_count_star_over_a_file_keeps_its_narrowest_column(lineitem_file):
+    s, path, t = lineitem_file
+    df = s.sql("select count(*) as n from lineitem")
+    # int32 and date32 are the narrowest; l_linenumber is the first of them
+    assert file_columns(CP.prune_columns(df._plan)) == [["l_linenumber"]]
+    assert df.collect()["n"].to_pylist() == [t.num_rows]
+    assert s.last_query_metrics.get("scanColumnsRead") == 1
+
+
+def test_a_file_read_whole_or_with_two_columns_of_one_name_keeps_its_node(
+        lineitem_file, tmp_path):
+    import pyarrow.parquet as pq
+    s, path, _ = lineitem_file
+    df = s.read.parquet(path)
+    assert CP.prune_columns(df._plan) is df._plan
+    star = s.sql("select * from lineitem where l_quantity < 3")
+    assert file_scans(CP.prune_columns(star._plan)) == file_scans(star._plan)
+    twice = str(tmp_path / "twice.parquet")
+    pq.write_table(pa.Table.from_arrays(
+        [pa.array([1, 2]), pa.array([3, 4]), pa.array([5, 6])],
+        names=["a", "a", "b"]), twice)
+    rel = s.read.parquet(twice)._plan
+    top = P.Project((rel.output[2],), rel)
+    assert file_scans(CP.prune_columns(top)) == [rel]
+
+
+def test_a_file_joined_with_itself_gets_two_narrowings(lineitem_file):
+    s, path, _ = lineitem_file
+    df = s.sql("select x.l_tax, y.l_comment from lineitem x, lineitem y "
+               "where x.l_orderkey = y.l_orderkey "
+               "and x.l_linenumber = 1 and y.l_linenumber = 2")
+    (whole,) = set(map(id, file_scans(df._plan)))       # one node, twice
+    narrowed = CP.prune_columns(df._plan)
+    left, right = file_scans(narrowed)
+    assert left is not right and id(left) != whole != id(right)
+    assert file_columns(narrowed) == [
+        ["l_orderkey", "l_linenumber", "l_tax"],
+        ["l_orderkey", "l_linenumber", "l_comment"]]
+    assert left.paths == right.paths == (path,)
+    assert left.options is right.options
+    got = df.collect()
+    with scan_rule_off():
+        want = df.collect()
+    order = [("l_tax", "ascending"), ("l_comment", "ascending")]
+    assert got.num_rows > 0 and got.sort_by(order).equals(want.sort_by(order))
+
+
+def test_a_file_scan_is_not_mutated_by_the_rule(lineitem_file):
+    s, path, _ = lineitem_file
+    df = s.sql(query("tpch_q6")[0])
+    (scan,) = file_scans(df._plan)
+    before = (df._plan.tree_string(), list(scan.output), scan.read_schema,
+              scan.columns, scan.file_width)
+    first = df.collect()
+    second = df.collect()               # plans twice, narrows twice
+    assert first.equals(second)
+    (after,) = file_scans(df._plan)
+    assert after is scan
+    assert (df._plan.tree_string(), list(scan.output), scan.read_schema,
+            scan.columns, scan.file_width) == before
+    assert all(a is b for a, b in zip(scan.output, before[1]))
+    assert len(scan.read_schema.fields) == 16
+
+
+def test_a_narrowed_scan_of_a_narrowed_scan_keeps_the_files_positions(
+        lineitem_file):
+    s, path, t = lineitem_file
+    (scan,) = file_scans(s.read.parquet(path)._plan)
+    once = scan.narrowed([scan.output[i] for i in (2, 5, 9)])
+    again = once.narrowed([once.output[2], once.output[0]])
+    assert again.columns == (2, 9) and again.file_width == 16
+    assert [a.name for a in again.output] == ["l_suppkey", "l_linestatus"]
+
+
+def test_the_scan_adjacent_filter_still_pushes_its_conjuncts(lineitem_file):
+    from spark_rapids_tpu.io_.exec import FileScanExec
+    s, path, t = lineitem_file
+    df = s.read.parquet(path)
+    q = (df.filter((df.l_orderkey > 10**9) & (df.l_quantity < 24))
+         .select("l_tax"))
+    (scan,) = [n for n in walk_physical(s.physical_plan(q))
+               if isinstance(n, FileScanExec)]
+    assert [a.name for a in scan.output] == ["l_orderkey", "l_quantity",
+                                             "l_tax"]
+    assert sorted(scan.pushed_filters) == [("l_orderkey", ">", 10**9),
+                                           ("l_quantity", "<", 24)]
+    assert q.collect().num_rows == 0
+    m = s.last_query_metrics
+    groups = -(-t.num_rows // 2048)
+    assert m.get("rowGroupsTotal") == m.get("rowGroupsPruned") == groups
+
+
+def _cell_frames(s, cell):
+    """A cell's tables at a small size, registered as the cell registers
+    them; its queries' texts."""
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        workload = json.load(f)
+    with open(os.path.join(BENCH, "configs",
+                           workload["config"] + ".json")) as f:
+        config = json.load(f)
+    assert workload["view"] == "memory"
+    gen, scale = (TPCH, {"scale_factor": 0.002}) \
+        if config["generator"] == "tpch" \
+        else (TPCDS, {"scale_factor": 0.01, "share_of": 8})
+    tables = gen.build_tables(scale, 2, config["tables"])
+    for name, t in tables.items():
+        s.create_dataframe(
+            t, num_partitions=int(config["storage"]["memory"]["partitions"])
+        ).createOrReplaceTempView(name)
+    return config, [query(q)[0] for q in workload["queries"]]
+
+
+@pytest.mark.parametrize("cell", [
+    "tpch-sf2.75-resident-q6q1", "tpch-1m-join-q3", "tpch-1m-join-q3-mesh4",
+    "tpcds-sf100-star-q7", "tpcds-sf100-report-q98"])
+def test_a_cell_that_scans_no_file_plans_as_on_the_parent(cell):
+    """The new rule matches ``ScanRelation`` alone: where a plan holds none,
+    the tree the rule returns and the physical plan are the parent's."""
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        config_name = json.load(f)["config"]
+    with open(os.path.join(BENCH, "configs", config_name + ".json")) as f:
+        conf = dict(json.load(f)["session_conf"])
+    conf.pop("spark.executor.instances", None)  # the plan's shape, one device
+    with session_of(**conf) as s:
+        _config, texts = _cell_frames(s, cell)
+        for text in texts:
+            df = s.sql(text)
+            assert not file_scans(df._plan)
+            now = CP.prune_columns(df._plan)
+            planned = s.physical_plan(df).tree_string()
+            with scan_rule_off():
+                before = CP.prune_columns(df._plan)
+                assert s.physical_plan(df).tree_string() == planned
+            assert now.tree_string() == before.tree_string()
+            assert scans(now) == scans(before)
