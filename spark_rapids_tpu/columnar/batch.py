@@ -6,6 +6,7 @@
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -13,8 +14,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import tracer as _trace
 from ..types import DataType, StructField, StructType
 from .column import DeviceColumn, bucket_capacity
+
+#: ``num_rows_int`` memo misses of the process, each one blocking readback
+#: (``syncReadbacks`` of last_query_metrics is a query's delta); pool and
+#: prefetch threads miss too, so the count is taken under a lock
+SYNC_STATS = {"readbacks": 0}
+_SYNC_LOCK = threading.Lock()
 
 
 @jax.tree_util.register_pytree_node_class
@@ -52,8 +60,10 @@ class ColumnarBatch:
     def empty(schema: StructType) -> "ColumnarBatch":
         from .column import null_column
         cap = bucket_capacity(0)
-        cols = tuple(null_column(f.data_type, cap) for f in schema.fields)
-        return ColumnarBatch.make(schema.names, cols, 0)
+        with _trace.eager("batch.empty", columns=len(schema.fields)):
+            cols = tuple(null_column(f.data_type, cap)
+                         for f in schema.fields)
+            return ColumnarBatch.make(schema.names, cols, 0)
 
     # --- shape ------------------------------------------------------------
     @property
@@ -75,7 +85,10 @@ class ColumnarBatch:
         :meth:`with_known_rows`."""
         cached = getattr(self, "_nrows_host", None)
         if cached is None:
-            cached = int(self.num_rows)
+            with _trace.span("sync", "batch.num_rows"):
+                cached = int(self.num_rows)
+            with _SYNC_LOCK:
+                SYNC_STATS["readbacks"] += 1
             self._nrows_host = cached
         return cached
 
@@ -140,7 +153,9 @@ class ColumnarBatch:
 
     # --- reshaping (host-orchestrated, device-executed) -------------------
     def repadded(self, new_capacity: int) -> "ColumnarBatch":
-        cols = tuple(c.slice_capacity(new_capacity) for c in self.columns)
+        with _trace.eager("batch.repadded", columns=self.num_cols):
+            cols = tuple(c.slice_capacity(new_capacity)
+                         for c in self.columns)
         b = ColumnarBatch(self.names, cols, self.num_rows)
         cached = getattr(self, "_nrows_host", None)
         if cached is not None:
@@ -166,8 +181,9 @@ class ColumnarBatch:
         downstream kernels/serializers don't chew dead padding."""
         if self.capacity <= self._SHRINK_MIN_CAPACITY:
             return self
-        cap = self.shrunk_capacity(self.num_rows_int)
-        return self if cap >= self.capacity else self.repadded(cap)
+        with _trace.eager("batch.shrunk", columns=self.num_cols):
+            cap = self.shrunk_capacity(self.num_rows_int)
+            return self if cap >= self.capacity else self.repadded(cap)
 
     def window(self, start, num_rows, capacity: int,
                xp=jnp) -> "ColumnarBatch":
@@ -181,15 +197,16 @@ class ColumnarBatch:
     def sliced(self, start: int, length: int) -> "ColumnarBatch":
         """Host-side slice: returns a batch viewing rows [start, start+len).
         Implemented as a gather so the result is bucket-padded."""
-        n = self.num_rows_int
-        length = max(0, min(length, n - start))
-        cap = bucket_capacity(length)
         from ..parallel import placement
-        with placement.beside(self.columns):
-            idx = jnp.arange(cap, dtype=jnp.int32) + start
-            valid = jnp.arange(cap, dtype=jnp.int32) < length
-            cols = tuple(c.gather(idx, valid) for c in self.columns)
-            return ColumnarBatch.make(self.names, cols, length)
+        with _trace.eager("batch.sliced", columns=self.num_cols):
+            n = self.num_rows_int
+            length = max(0, min(length, n - start))
+            cap = bucket_capacity(length)
+            with placement.beside(self.columns):
+                idx = jnp.arange(cap, dtype=jnp.int32) + start
+                valid = jnp.arange(cap, dtype=jnp.int32) < length
+                cols = tuple(c.gather(idx, valid) for c in self.columns)
+                return ColumnarBatch.make(self.names, cols, length)
 
     def gather(self, idx: jnp.ndarray, idx_valid: Optional[jnp.ndarray],
                out_rows) -> "ColumnarBatch":
@@ -205,6 +222,11 @@ class ColumnarBatch:
         (:func:`concat_declined`) take the per-array path."""
         if not batches:
             raise ValueError("ColumnarBatch.concat requires at least one batch")
+        with _trace.eager("batch.concat", pieces=len(batches)):
+            return ColumnarBatch._concat(batches)
+
+    @staticmethod
+    def _concat(batches: Sequence["ColumnarBatch"]) -> "ColumnarBatch":
         batches = [b for b in batches if b.num_rows_int > 0] or list(batches[:1])
         if len(batches) == 1:
             return batches[0]

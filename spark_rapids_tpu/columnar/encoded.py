@@ -339,10 +339,13 @@ class DictEncodedColumn(DeviceColumn):
         if m is not None:
             return m
         import jax.numpy as jnp
+
+        from ..observability import tracer as _trace
         d = self.dictionary.column
-        safe = jnp.clip(self.codes, 0, d.capacity - 1)
-        data = jnp.where(self.validity[:, None], d.data[safe], 0)
-        lengths = jnp.where(self.validity, d.lengths[safe], 0)
+        with _trace.eager("encoded.dict_materialize"):
+            safe = jnp.clip(self.codes, 0, d.capacity - 1)
+            data = jnp.where(self.validity[:, None], d.data[safe], 0)
+            lengths = jnp.where(self.validity, d.lengths[safe], 0)
         m = DeviceColumn(self.dtype, data, self.validity, lengths=lengths)
         self._mat = m
         _bump("materializations")
@@ -460,13 +463,18 @@ class RLEColumn(DeviceColumn):
         if m is not None:
             return m
         import jax.numpy as jnp
-        idx = jnp.arange(self.capacity, dtype=jnp.int32)
-        run_idx = jnp.searchsorted(self.run_ends, idx, side="right")
-        run_idx = jnp.clip(run_idx, 0, self.run_values.capacity - 1)
-        data = jnp.where(self.validity, self.run_values.data[run_idx], 0)
-        aux = None
-        if self.run_values.aux is not None:
-            aux = jnp.where(self.validity, self.run_values.aux[run_idx], 0)
+
+        from ..observability import tracer as _trace
+        with _trace.eager("encoded.rle_materialize"):
+            idx = jnp.arange(self.capacity, dtype=jnp.int32)
+            run_idx = jnp.searchsorted(self.run_ends, idx, side="right")
+            run_idx = jnp.clip(run_idx, 0, self.run_values.capacity - 1)
+            data = jnp.where(self.validity,
+                             self.run_values.data[run_idx], 0)
+            aux = None
+            if self.run_values.aux is not None:
+                aux = jnp.where(self.validity,
+                                self.run_values.aux[run_idx], 0)
         m = DeviceColumn(self.dtype, data, self.validity, aux=aux)
         self._mat = m
         _bump("materializations")

@@ -29,8 +29,24 @@ DECODE_STATS: Dict[str, dict] = {
           "decline_reasons": {}}
     for fmt in FORMATS}
 
+#: the parquet device decoder's host work: compressed column-chunk bytes
+#: read off files, pages walked, and their bytes once decompressed
+PARQUET_PAGES = {"chunk_bytes_read": 0, "pages_decoded": 0,
+                 "bytes_decompressed": 0}
+
 _LOCK = threading.Lock()
 _TLS = threading.local()
+
+
+def record_chunk_read(nbytes: int) -> None:
+    with _LOCK:
+        PARQUET_PAGES["chunk_bytes_read"] += int(nbytes)
+
+
+def record_pages(pages: int, out_bytes: int) -> None:
+    with _LOCK:
+        PARQUET_PAGES["pages_decoded"] += int(pages)
+        PARQUET_PAGES["bytes_decompressed"] += int(out_bytes)
 
 
 def set_decline_reason(reason: str) -> None:
@@ -80,6 +96,10 @@ def snapshot() -> Dict[str, float]:
             out[f"{fmt}DecodeFilesDeclined"] = s["files_declined"]
             out[f"{fmt}DecodeBytesEngaged"] = s["bytes_engaged"]
             out[f"{fmt}DecodeBytesDeclined"] = s["bytes_declined"]
+        out["parquetChunkBytesRead"] = PARQUET_PAGES["chunk_bytes_read"]
+        out["parquetPagesDecoded"] = PARQUET_PAGES["pages_decoded"]
+        out["parquetBytesDecompressed"] = \
+            PARQUET_PAGES["bytes_decompressed"]
     return out
 
 

@@ -614,6 +614,9 @@ class _ChunkPlan:
     # bounded python loop), consumed by the device gather kernel
     str_starts: List[np.ndarray] = field(default_factory=list)
     str_lens: List[np.ndarray] = field(default_factory=list)
+    # what the page walk read: pages, decompressed page bytes
+    pages: int = 0
+    out_bytes: int = 0
 
 
 def _plain_dict_values(phys: str, data: bytes, n: int) -> np.ndarray:
@@ -704,6 +707,8 @@ def _plan_chunk(raw: bytes, cc, phys: str, nullable: bool,
         if n_pages > 100_000:
             raise _Unsupported("page count guard")
 
+        plan.pages += 1
+        plan.out_bytes += h.uncompressed_size
         if h.type == 2:                       # dictionary page
             if h.encoding not in (_ENC_PLAIN, _ENC_PLAIN_DICT):
                 raise _Unsupported("non-PLAIN dictionary")
@@ -797,7 +802,9 @@ def _merge_plans(plans: List[_ChunkPlan], phys: str) -> _ChunkPlan:
     plans union their per-group dictionaries into one global dictionary
     with per-group device-side index remapping (host cost is O(dictionary
     entries), never O(rows))."""
-    out = _ChunkPlan(nullable=plans[0].nullable, is_dict=plans[0].is_dict)
+    out = _ChunkPlan(nullable=plans[0].nullable, is_dict=plans[0].is_dict,
+                     pages=sum(p.pages for p in plans),
+                     out_bytes=sum(p.out_bytes for p in plans))
     if plans[0].is_dict:
         _unify_dictionaries(plans, phys, out)
     bufs: List[bytes] = []
@@ -1200,6 +1207,7 @@ def decode_file(path: str, row_groups: Optional[Sequence[int]] = None,
     from ..columnar.column import bucket_capacity
     from ..columnar.convert import arrow_to_device_column
     from ..observability import tracer as _trace
+    from .decode_stats import record_chunk_read, record_pages
 
     if pf is None:
         pf = pq.ParquetFile(path)   # callers with an open handle pass it in
@@ -1242,7 +1250,7 @@ def decode_file(path: str, row_groups: Optional[Sequence[int]] = None,
                 host_fields.append(fi)
                 continue
             try:
-                plans = []
+                chunks = []
                 phys = None
                 type_length = int(getattr(md.schema.column(li), "length",
                                           0) or 0)
@@ -1263,14 +1271,25 @@ def decode_file(path: str, row_groups: Optional[Sequence[int]] = None,
                     offs = [o for o in (cc.dictionary_page_offset,
                                         cc.data_page_offset)
                             if o is not None and o > 0]
-                    fobj.seek(min(offs))
-                    raw = fobj.read(cc.total_compressed_size)
-                    plans.append(_plan_chunk(raw, cc, phys, fld.nullable,
-                                             type_length))
-                merged = _merge_plans(plans, phys)
-                device_cols[fi] = _decode_column_device(
-                    merged, phys, dtype, fld.type, capacity, n_rows,
-                    max_str_bytes, type_length, conf=conf)
+                    with _trace.span("scan", "chunk_read",
+                                     bytes=cc.total_compressed_size):
+                        fobj.seek(min(offs))
+                        raw = fobj.read(cc.total_compressed_size)
+                    record_chunk_read(len(raw))
+                    chunks.append((raw, cc))
+                with _trace.span("scan", "pages") as pages:
+                    plans = [_plan_chunk(raw, cc, phys, fld.nullable,
+                                         type_length) for raw, cc in chunks]
+                    merged = _merge_plans(plans, phys)
+                    pages.set_metadata(
+                        pages=merged.pages, out_bytes=merged.out_bytes,
+                        bytes=sum(len(raw) for raw, _ in chunks))
+                record_pages(merged.pages, merged.out_bytes)
+                with _trace.eager("parquet.decode_column",
+                                  rows=n_rows):
+                    device_cols[fi] = _decode_column_device(
+                        merged, phys, dtype, fld.type, capacity, n_rows,
+                        max_str_bytes, type_length, conf=conf)
                 if tctx is not None:
                     tctx.inc_metric("parquetDeviceDecodedColumns")
             except _Unsupported:

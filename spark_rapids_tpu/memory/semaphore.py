@@ -79,8 +79,9 @@ class TpuSemaphore:
         t0 = time.perf_counter()
         acquired = False
         try:
-            while not self._sem.acquire(timeout=_lc.POLL_S):
-                _lc.check_cancel("sem_wait")
+            with _trace.span("sem_wait", "semaphore.acquire", task=task_id):
+                while not self._sem.acquire(timeout=_lc.POLL_S):
+                    _lc.check_cancel("sem_wait")
             acquired = True
         finally:
             waited = time.perf_counter() - t0
@@ -92,9 +93,6 @@ class TpuSemaphore:
                 self._cond.notify_all()
         if tctx is not None:
             tctx.inc_metric("semaphoreWaitTime", waited)
-        if waited > 1e-6 and _trace.TRACING["on"]:
-            _trace.get_tracer().complete("sem_wait", "semaphore.acquire",
-                                         t0, waited, task=task_id)
         _om.observe("sem_wait_ms", waited * 1e3)
 
     def release_if_necessary(self, task_id: int):

@@ -10,9 +10,11 @@ The verdict classes (docs/observability.md):
                     cache; warm reruns are the fix, not kernel work
 ``h2d-d2h-bound``   transfer spans (cats ``h2d``+``d2h``) — bytes crossing
                     the host link; prepack/resident tiers are the levers
-``dispatch-bound``  many small compiled-program launches with little
-                    attributed span time — per-op Python dispatch + launch
-                    overhead; whole-stage fusion is the lever
+``dispatch-bound``  the host launching programs: self time of the
+                    ``dispatch`` (kernel-cache launches) and ``eager``
+                    (launches past the cache) spans — per-op Python
+                    dispatch + launch overhead; whole-stage fusion is the
+                    lever
 ``sem_wait-bound``  device-semaphore waits (cat ``sem_wait``) — tasks
                     contending for chip admission
 ``spill-bound``     spill tier movement (cat ``spill``)
@@ -63,7 +65,19 @@ _CAT_TO_VERDICT = {
     "shuffle": "shuffle-bound",
     "queue": "shuffle-bound",
     "admission": "admission-bound",
+    "dispatch": "dispatch-bound",
+    "eager": "dispatch-bound",
 }
+
+
+def _verdict_of(ev: Dict[str, Any]) -> Optional[str]:
+    """The verdict an event's self time counts toward: its category's, but
+    a ``dispatch`` span that re-traced (``retraced`` arg) was compiling."""
+    cat = ev.get("cat", "")
+    if cat == "dispatch" and (ev.get("args") or {}).get("retraced"):
+        return "compile-bound"
+    return _CAT_TO_VERDICT.get(cat)
+
 
 VERDICTS = ("sync-bound", "compile-bound", "h2d-d2h-bound",
             "dispatch-bound", "sem_wait-bound", "spill-bound",
@@ -170,16 +184,6 @@ def _stamp_levers(ranked: List[Dict[str, Any]], stages: int = 0) -> None:
         ev["levers"] = _lever_evidence(e, stages)
         ev["lever"] = LEVERS.get(e["category"], "")
 
-#: per-launch overhead floor used to estimate dispatch-bound time when
-#: the trace cannot attribute it directly (Python dispatch + XLA launch;
-#: an uncovered launch can cost more than this, so it
-#: deliberately UNDER-estimates — a dispatch-bound verdict from this
-#: floor is conservative)
-DEFAULT_DISPATCH_COST_MS = 0.05
-
-#: launches below this count never yield a dispatch-bound verdict
-DISPATCH_FLOOR = 32
-
 #: kernel keys named in dispatch-bound evidence (top launch sources)
 DISPATCH_TOP_K = 5
 
@@ -270,7 +274,6 @@ def diagnose(events: List[Dict[str, Any]],
              metrics: Optional[Dict[str, Any]] = None,
              wall_ms: Optional[float] = None,
              dropped_events: int = 0,
-             dispatch_cost_ms: float = DEFAULT_DISPATCH_COST_MS,
              dispatch_by_key: Optional[Dict[str, int]] = None
              ) -> Dict[str, Any]:
     """Ranked bottleneck diagnosis from a tracer snapshot.
@@ -287,8 +290,7 @@ def diagnose(events: List[Dict[str, Any]],
     totals: Dict[str, Dict[str, float]] = {}
     by_exec: Dict[str, Dict[str, Dict[str, float]]] = {}
     for i, ev in enumerate(events):
-        cat = ev.get("cat", "")
-        verdict = _CAT_TO_VERDICT.get(cat)
+        verdict = _verdict_of(ev)
         if verdict is None:
             continue
         ms = self_ms[i]
@@ -298,6 +300,9 @@ def diagnose(events: List[Dict[str, Any]],
         t["ms"] += ms
         t["n"] += 1
         t["bytes"] += nbytes
+        if verdict == "dispatch-bound":
+            key = ev.get("cat", "") + "_ms"     # eager_ms / dispatch_ms
+            t[key] = t.get(key, 0.0) + ms
         node = ev.get("exec") or "(driver)"
         rows = by_exec.setdefault(verdict, {})
         row = rows.setdefault(node, {"ms": 0.0, "n": 0, "bytes": 0})
@@ -315,26 +320,21 @@ def diagnose(events: List[Dict[str, Any]],
             for name, r in top]}
         if t["bytes"]:
             evidence["bytes"] = int(t["bytes"])
+        if verdict == "dispatch-bound":
+            # measured: self time of the launch spans, split by kind,
+            # beside the launch counts that say which programs ran
+            dispatches = int(counters.get(
+                "deviceDispatches", metrics.get("deviceDispatches", 0))
+                or 0)
+            evidence.update(_dispatch_evidence(dispatches, metrics,
+                                               dispatch_by_key))
+            evidence["stage_op_dispatches"] = int(
+                metrics.get("stageOpDispatches", 0))
+            for key in ("eager_ms", "dispatch_ms"):
+                evidence[key] = round(t.get(key, 0.0), 3)
         ranked.append(_verdict_entry(verdict, t["ms"], t["n"], evidence))
 
     attributed_ms = sum(e["ms"] for e in ranked)
-    # dispatch-bound: launches the spans above do not explain.  Estimate
-    # from the launch count at the conservative per-launch floor, capped
-    # by the unattributed wall when the wall is known.
-    dispatches = int(counters.get("deviceDispatches",
-                                  metrics.get("deviceDispatches", 0)) or 0)
-    if dispatches >= DISPATCH_FLOOR:
-        est = dispatches * dispatch_cost_ms
-        if wall_ms is not None:
-            est = min(est, max(0.0, wall_ms - attributed_ms))
-        if est > 0:
-            ev = _dispatch_evidence(dispatches, metrics, dispatch_by_key)
-            ev["stage_op_dispatches"] = int(
-                metrics.get("stageOpDispatches", 0))
-            ev["estimated"] = True
-            ev["per_dispatch_ms"] = dispatch_cost_ms
-            ranked.append(_verdict_entry(
-                "dispatch-bound", est, dispatches, ev))
 
     # peer-failure: the query crossed the pod-scale fault domain —
     # quantified from the fault-domain metric deltas, with the ms cost
@@ -417,16 +417,14 @@ def diagnose_summary(summary: Dict[str, Any],
             "h2d-d2h-bound", 0.0, 0,
             {"h2d_bytes": h2d, "d2h_bytes": d2h,
              "note": "bytes only: summary carries no transfer ms"}))
-    dispatches = int(summary.get("device_dispatches", 0) or 0)
-    if dispatches >= DISPATCH_FLOOR:
-        # summaries may carry the per-key launch table; when absent,
-        # evidence degrades to totals
+    if summary.get("dispatch_ms"):
+        # the launch spans' self time; summaries may carry the per-key
+        # launch table, and when absent evidence degrades to totals
         ev = _dispatch_evidence(
-            dispatches, {},
+            int(summary.get("device_dispatches", 0) or 0), {},
             dict(summary.get("dispatch_by_key") or {}))
-        ev["estimated"] = True
-        add("dispatch-bound", dispatches * DEFAULT_DISPATCH_COST_MS,
-            dispatches, **ev)
+        add("dispatch-bound", float(summary["dispatch_ms"]),
+            int(summary.get("dispatch_count", 0)), **ev)
     ranked.sort(key=lambda e: -e["ms"])
     attributed_ms = sum(e["ms"] for e in ranked)
     denom = wall_ms if wall_ms else (attributed_ms or 1.0)

@@ -87,6 +87,14 @@ def trace_summary(events: List[Dict[str, Any]],
         out["stage_ms"] = round(tot["stage_ms"], 3)
     if counters and counters.get("deviceDispatches"):
         out["device_dispatches"] = int(counters["deviceDispatches"])
+    # the host launching programs (the doctor's measured dispatch-bound):
+    # self time of the kernel cache's launches and of the eager blocks
+    from .doctor import _self_times, _verdict_of
+    launch = [ms for ev, ms in zip(events, _self_times(events))
+              if _verdict_of(ev) == "dispatch-bound"]
+    if launch:
+        out["dispatch_count"] = len(launch)
+        out["dispatch_ms"] = round(sum(launch), 3)
     if tot["fault_n"]:
         out["fault_count"] = int(tot["fault_n"])
     # truncation is first-class: a consumer (the doctor) must never have

@@ -18,9 +18,10 @@
 Both off, :func:`span` returns the shared null object: the cost is the
 flag lookups, nothing else.  :meth:`QueryTracer.complete` (a retroactive
 span from a measured ``t0``/duration) can only feed the ring; it remains
-for the sites off the query's own path (semaphore and queue waits, shuffle
-transport and serialization, serving admission, lifecycle, faults), which
-a profiler trace therefore does not show.
+for the sites off the query's own path (shuffle transport and
+serialization, serving admission, lifecycle, faults), which a profiler
+trace therefore does not show.  The waits ON the query's path (the device
+semaphore, the prefetch consumer) are spans.
 
 Event categories:
 
@@ -31,15 +32,32 @@ Event categories:
                    ``plan_for_collect``, re-plans included)
 ``task``           one partition's task (``<Exec>:task<n>``; base.py)
 ``op``             exec-node batch production (and join pipeline stages)
-``dispatch``       one launch of a kernel-cache program, by program name
+``dispatch``       one launch of a kernel-cache program, by program name;
+                   ``retraced=1`` when that launch re-traced (a new input
+                   signature of a wrapper that had run before)
 ``compile``        the launch of a program its jit wrapper has not run
-                   before (trace + lower + compile or cache load), and
-                   any later launch that re-traced (new input signature)
+                   before (trace + lower + compile or cache load)
+``eager``          a block of host work that launches programs past the
+                   kernel cache one by one (eager ``jnp`` on batches, a
+                   decoder's own ``jax.jit``), ``exec=`` the exec it ran
+                   for: ``batch.repadded|shrunk|sliced|concat|empty``
+                   (columnar/batch.py), ``top_n.merge``
+                   (``TakeOrderedAndProject``),
+                   ``encoded.dict_materialize|rle_materialize``
+                   (columnar/encoded.py), ``parquet.decode_column``
+                   (io_/device_parquet.py); never one span a launch
 ``scan``           file scans: ``footer`` (open + prune), ``device_decode``
-                   and ``host_decode`` (pyarrow) per row-group run
+                   and ``host_decode`` (pyarrow) per row-group run; inside
+                   ``device_decode``, per column, ``chunk_read`` (the
+                   chunk's compressed bytes off the file, ``bytes=``) and
+                   ``pages`` (page headers, decompression, the hybrid run
+                   walk, dictionary union: ``pages=``, ``bytes=``,
+                   ``out_bytes=``)
 ``sync``           blocking scalar readbacks: ``join.readback`` (join
                    sizing), ``agg.group_count`` (the aggregate waits for
-                   the program it launched to learn its group count)
+                   the program it launched to learn its group count),
+                   ``batch.num_rows`` (``ColumnarBatch.num_rows_int`` on a
+                   memo miss; counted as ``syncReadbacks``)
 ``h2d``            host -> device uploads (arrow decode, transitions)
 ``d2h``            device -> host fetches (bulk/prepacked device_get)
 ``spill``          spill-catalog tier movement
@@ -107,9 +125,9 @@ TRACING = {"on": False, "profiler": False}
 #: known span categories (exported traces may add more; the checker and
 #: the report treat unknown categories as opaque)
 CATEGORIES = ("query", "plan", "task", "op", "stage", "dispatch", "compile",
-              "scan", "sync", "h2d", "d2h", "spill", "shuffle", "sem_wait",
-              "fault", "queue", "encode", "admission", "cancel", "fatal",
-              "broadcast", "join", "sort", "window")
+              "eager", "scan", "sync", "h2d", "d2h", "spill", "shuffle",
+              "sem_wait", "fault", "queue", "encode", "admission", "cancel",
+              "fatal", "broadcast", "join", "sort", "window")
 
 #: every profiler annotation's name starts with this
 PROFILER_PREFIX = "srt:"
@@ -448,3 +466,13 @@ def span(cat: str, name: str, **args: Any):
     if TRACING["profiler"]:
         return _annotation(cat, name, args)
     return _NULL_SPAN
+
+
+def eager(site: str, **args: Any):
+    """:func:`span` of category ``eager`` over one BLOCK of launches past
+    the kernel cache, stamped with the exec it runs for (``exec=``); the
+    shared null object, at the cost of the flag lookups, when neither
+    sink is armed."""
+    if not (TRACING["on"] or TRACING["profiler"]):
+        return _NULL_SPAN
+    return span("eager", site, exec=current_exec(), **args)

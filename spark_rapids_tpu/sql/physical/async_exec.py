@@ -120,20 +120,18 @@ class AsyncPrefetchExec(PhysicalPlan):
         try:
             while True:
                 t0 = time.perf_counter()
-                while True:
-                    try:
-                        # polled get: a cancel must not leave the consumer
-                        # blocked forever on a wedged/slow producer
-                        item = q.get(timeout=_POLL_S)
-                        break
-                    except queue.Empty:
-                        _lc.check_cancel("prefetch")
-                dt = time.perf_counter() - t0
-                waited_s += dt
-                if dt > 1e-6 and _trace.TRACING["on"]:
-                    _trace.get_tracer().complete(
-                        "queue", "prefetch.consumer_wait", t0, dt,
-                        partition=pid, depth=q.qsize())
+                with _trace.span("queue", "prefetch.consumer_wait",
+                                 partition=pid, depth=q.qsize()):
+                    while True:
+                        try:
+                            # polled get: a cancel must not leave the
+                            # consumer blocked forever on a wedged/slow
+                            # producer
+                            item = q.get(timeout=_POLL_S)
+                            break
+                        except queue.Empty:
+                            _lc.check_cancel("prefetch")
+                waited_s += time.perf_counter() - t0
                 if item is _DONE:
                     break
                 if isinstance(item, _Raised):

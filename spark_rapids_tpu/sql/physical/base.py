@@ -174,11 +174,12 @@ class PhysicalPlan:
         from this node's iterator (children included) accrues to the
         node; the report derives self-time as inclusive minus children.
         When tracing is on (either sink), each pull additionally runs
-        inside an ``op`` span; for the ring it also brackets itself on
-        the tracer's exec stack — a nested child pull pushes the child on
-        top, so chokepoint spans (sync/h2d/d2h/spill) fired during the
-        pull attribute to the innermost executing exec.  The span closes
-        before the batch is yielded: it never covers the consumer."""
+        inside an ``op`` span and brackets itself on the tracer's exec
+        stack — a nested child pull pushes the child on top, so
+        chokepoint spans (sync/h2d/d2h/spill, and the ``exec=`` of the
+        ``eager`` spans) fired during the pull attribute to the innermost
+        executing exec.  The span closes before the batch is yielded: it
+        never covers the consumer."""
         super().__init_subclass__(**kw)
         orig = cls.__dict__.get("execute")
         if orig is None or getattr(orig, "_profiled", False):
@@ -191,14 +192,14 @@ class PhysicalPlan:
             import time as _t
 
             def gen():
-                ring = tr["on"]
-                name = self.node_name() if ring or tr["profiler"] else ""
+                traced = tr["on"] or tr["profiler"]
+                name = self.node_name() if traced else ""
                 t0 = _t.perf_counter_ns()
                 it = iter(_orig(self, pid, tctx))
                 self._prof_ns += _t.perf_counter_ns() - t0
                 while True:
                     t1 = _t.perf_counter_ns()
-                    if ring:
+                    if traced:
                         _trace.push_exec(name)
                     try:
                         with _trace.span("op", name, partition=pid):
@@ -207,7 +208,7 @@ class PhysicalPlan:
                         self._prof_ns += _t.perf_counter_ns() - t1
                         return
                     finally:
-                        if ring:
+                        if traced:
                             _trace.pop_exec()
                     self._prof_ns += _t.perf_counter_ns() - t1
                     self._prof_batches += 1

@@ -163,10 +163,15 @@ class _TrackedKernel:
         t0 = time.perf_counter()
         # a wrapper that has run nothing yet is about to trace and compile
         with _trace.span("compile" if before == 0 else "dispatch",
-                         self._label):
+                         self._label) as sp:
             out = self._fn(*args, **kwargs)
+            traced = cs is not None and cs() > before
+            if traced and before > 0:
+                # a re-trace for a new input signature: known only now,
+                # marked on the launch's own span (both sinks)
+                sp.set_metadata(retraced=1)
         dt = time.perf_counter() - t0
-        if cs is not None and cs() > before:
+        if traced:
             ms = dt * 1e3
             with _LOCK:
                 _STATS["compiles"] += 1
@@ -175,9 +180,6 @@ class _TrackedKernel:
                     self._label, {"compiles": 0, "ms": 0.0})
                 e["compiles"] += 1
                 e["ms"] += ms
-            if before > 0 and tr["on"]:
-                # a re-trace for a new input signature: known only now
-                _trace.get_tracer().complete("compile", self._label, t0, dt)
             if _om.METRICS["on"]:
                 _om.get_registry().observe("kernel_compile_ms", ms,
                                            kernel=self._label)

@@ -328,6 +328,11 @@ class TakeOrderedAndProjectExec(PhysicalPlan):
                 tops.append(b.sliced(0, min(self.n, b.num_rows_int)))
         if not tops:
             return
+        with _trace.eager("top_n.merge", pieces=len(tops)):
+            final = self._merge(tops)
+        yield final
+
+    def _merge(self, tops) -> ColumnarBatch:
         # every partition's top rows come to one chip: a limit's nature
         from ...parallel import placement
         tops = placement.gather(tops)
@@ -335,12 +340,10 @@ class TakeOrderedAndProjectExec(PhysicalPlan):
         final = self._sort._fn(merged)
         final = final.sliced(0, min(self.n, final.num_rows_int))
         if self.project_exprs is not None:
-            from .basic import ProjectExec
-            from ..expressions.core import EvalContext
             bound = [bind_references(e, self.children[0].output)
                      for e in self.project_exprs]
             ctx = EvalContext(final, xp=self.xp)
             cols = tuple(e.eval(ctx) for e in bound)
             names = tuple(a.name for a in self.output)
             final = ColumnarBatch(names, cols, final.num_rows)
-        yield final
+        return final

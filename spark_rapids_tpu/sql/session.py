@@ -32,10 +32,12 @@ def _aux_stats_snapshot() -> dict:
     """Flat snapshot of the process-wide encoded/prepack/decode counters
     whose per-query deltas fold into last_query_metrics (the robustness
     stats_snapshot pattern)."""
+    from ..columnar import batch as _batch
     from ..columnar import encoded as _enc
     from ..columnar import prepack as _pp
     from ..io_ import decode_stats as _ds
     out = dict(_ds.snapshot())
+    out["syncReadbacks"] = _batch.SYNC_STATS["readbacks"]
     es = _enc.stats_snapshot()
     out.update({
         "encodedColumnsEncoded": es["columns_encoded"]
@@ -508,18 +510,19 @@ class TpuSession:
             for k, v0 in rob0.items():
                 m[k] = rob1[k] - v0
             # encoded-execution / prepack / device-decode engagement
-            # deltas (a format's decode counters only when a scan of that
-            # format ran, so in-memory queries don't carry two dozen zero
-            # keys; then all four of them, so a scan that declined nothing
-            # says ``<fmt>DecodeFilesDeclined`` 0)
+            # deltas (a format's counters only when a scan of that format
+            # ran, so in-memory queries don't carry two dozen zero keys;
+            # then all of them, so a scan that declined nothing says
+            # ``<fmt>DecodeFilesDeclined`` 0)
             if aux0 is not None:
+                from ..io_.decode_stats import FORMATS
                 aux1 = _aux_stats_snapshot()
                 delta = {k: aux1.get(k, v0) - v0 for k, v0 in aux0.items()}
-                scanned = {k.split("Decode")[0] for k, d in delta.items()
-                           if d and k.endswith(("Engaged", "Declined"))}
+                scanned = tuple(k.split("Decode")[0]
+                                for k, d in delta.items()
+                                if d and k.endswith(("Engaged", "Declined")))
                 for k, d in delta.items():
-                    if not k.endswith(("Engaged", "Declined")) \
-                            or k.split("Decode")[0] in scanned:
+                    if not k.startswith(FORMATS) or k.startswith(scanned):
                         m[k] = d
         if not tracing:
             self.last_query_trace_summary = None

@@ -83,24 +83,38 @@ def test_compile_spill_shuffle_fixtures():
         assert diag["verdict"] == verdict, (cat, diag)
 
 
-def test_dispatch_bound_from_counters():
-    """Many launches, almost no attributed span time -> dispatch-bound
-    (estimated), with the launch counts as evidence."""
-    events = [_ev("sync", "r", 0.5)]
+def test_dispatch_bound_measured_from_launch_spans():
+    """The host launching programs -> dispatch-bound, charged the self
+    time of the ``eager`` and ``dispatch`` spans (a readback inside an
+    eager block stays sync-bound), with the launch counts as evidence."""
+    events = [_ev("eager", "batch.sliced", 40.0, ts=0.0,
+                  exec_="TpuTakeOrderedAndProject"),
+              _ev("sync", "batch.num_rows", 5.0, ts=10.0),
+              _ev("dispatch", "srt_SortExec_compute_0", 30.0, ts=100.0),
+              _ev("dispatch", "srt_SortExec_compute_0", 20.0, ts=200.0,
+                  retraced=1)]
     diag = OD.diagnose(events, counters={"deviceDispatches": 2000},
                        metrics={"stageOpDispatches": 1500},
                        wall_ms=500.0)
     assert diag["verdict"] == "dispatch-bound"
     top = diag["ranked"][0]
-    assert top["count"] == 2000
-    assert top["evidence"]["estimated"] is True
+    assert top["ms"] == pytest.approx(65.0)        # 35 eager + 30 dispatch
+    assert top["count"] == 2
+    assert top["evidence"]["eager_ms"] == pytest.approx(35.0)
+    assert top["evidence"]["dispatch_ms"] == pytest.approx(30.0)
+    assert top["evidence"]["top_execs"][0]["exec"] == \
+        "TpuTakeOrderedAndProject"
+    assert "estimated" not in top["evidence"]
     assert top["evidence"]["device_dispatches"] == 2000
     assert top["evidence"]["stage_op_dispatches"] == 1500
+    by = {r["category"]: r for r in diag["ranked"]}
+    assert by["sync-bound"]["ms"] == pytest.approx(5.0)
+    assert by["compile-bound"]["ms"] == pytest.approx(20.0)   # re-trace
 
 
-def test_dispatch_floor_suppresses_small_counts():
+def test_dispatch_counts_alone_make_no_verdict():
     diag = OD.diagnose([_ev("sync", "r", 5.0)],
-                       counters={"deviceDispatches": 8})
+                       counters={"deviceDispatches": 8000})
     assert "dispatch-bound" not in _categories(diag)
 
 
@@ -183,7 +197,8 @@ def test_diagnose_summary_degraded_mode():
     summary = {"sync_count": 40, "sync_ms": 900.0, "compile_count": 2,
                "compile_ms": 100.0, "h2d_bytes": 1 << 20,
                "d2h_bytes": 2048, "spill_ms": 0.0, "sem_wait_ms": 1.0,
-               "device_dispatches": 500, "trace_truncated": False}
+               "device_dispatches": 500, "dispatch_ms": 40.0,
+               "dispatch_count": 500, "trace_truncated": False}
     diag = OD.diagnose_summary(summary, wall_ms=1200.0)
     assert diag["verdict"] == "sync-bound"
     cats = _categories(diag)

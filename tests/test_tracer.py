@@ -612,3 +612,182 @@ def test_retrace_counters_are_zero_on_a_warm_collect():
     s3 = cache_stats()
     assert s3["retraces"] == s2["retraces"]
     assert s3["retrace_ms"] == s2["retrace_ms"]
+
+
+# --------------------------------------------------------------------------
+# the host's work inside an exec (PR 37): eager blocks, row-count syncs and
+# the parquet decode's host phases
+# --------------------------------------------------------------------------
+
+def _inside(child, parent):
+    return child["tid"] == parent["tid"] and parent["ts"] <= child["ts"] \
+        and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + 1e-3
+
+
+def _topn_query(sess, n=5000):
+    rng = np.random.default_rng(3)
+    df = sess.create_dataframe(pa.table({"k": rng.integers(0, 99, n),
+                                        "v": rng.random(n)}),
+                               num_partitions=4)
+    return df.orderBy(F.col("v").desc()).limit(7)
+
+
+def test_top_n_runs_its_eager_launches_under_its_op_span():
+    sess = srt.session(**{"spark.rapids.tpu.profile.enabled": True})
+    out = _topn_query(sess).collect()
+    assert out.num_rows == 7
+    events = sess._last_trace_events
+    ops = [e for e in events if e["cat"] == "op"
+           and e["name"] == "TpuTakeOrderedAndProject"]
+    eager = [e for e in events if e["cat"] == "eager"]
+    names = {e["name"] for e in eager}
+    assert {"top_n.merge", "batch.sliced"} <= names, names
+    mine = [e for e in eager if e["name"] in ("top_n.merge", "batch.sliced")
+            and e["exec"] == "TpuTakeOrderedAndProject"]
+    assert len(mine) >= 5          # a cut of each of 4 partitions, the merge
+    for e in mine:
+        assert e["exec"] == "TpuTakeOrderedAndProject"
+        assert e["args"]["exec"] == "TpuTakeOrderedAndProject"
+        assert any(_inside(e, op) for op in ops), e
+    # the merge's own cut reads its row count: a sync child
+    merge = [e for e in mine if e["name"] == "top_n.merge"]
+    syncs = [e for e in events if e["name"] == "batch.num_rows"]
+    assert any(_inside(s, m) for s in syncs for m in merge)
+
+
+def test_a_row_count_readback_is_one_span_per_memo_miss(tracing_on):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar import batch as CB
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    from spark_rapids_tpu import types as T
+    col = DeviceColumn(T.INT, jnp.arange(8, dtype=jnp.int32),
+                       jnp.ones(8, dtype=bool))
+    before = CB.SYNC_STATS["readbacks"]
+    b = CB.ColumnarBatch(("a",), (col,), jnp.asarray(5, dtype=jnp.int32))
+    assert b.num_rows_int == 5 and b.num_rows_int == 5     # miss, hit
+    known = CB.ColumnarBatch.make(("a",), (col,), 5)        # host-known
+    assert known.num_rows_int == 5
+    reads = [e for e in tracing_on.snapshot()
+             if (e["cat"], e["name"]) == ("sync", "batch.num_rows")]
+    assert len(reads) == 1
+    assert CB.SYNC_STATS["readbacks"] == before + 1
+
+
+def test_the_readback_count_loses_no_miss_across_threads():
+    """Pool and prefetch threads miss the memo concurrently: every miss
+    is counted (a lost read-modify-write would show as fewer)."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar import batch as CB
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    from spark_rapids_tpu import types as T
+    col = DeviceColumn(T.INT, jnp.arange(8, dtype=jnp.int32),
+                       jnp.ones(8, dtype=bool))
+    rows = jnp.asarray(3, dtype=jnp.int32)
+    workers, each = 16, 200
+    batches = [[CB.ColumnarBatch(("a",), (col,), rows)
+                for _ in range(each)] for _ in range(workers)]
+    before = CB.SYNC_STATS["readbacks"]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(
+            target=lambda bs: [b.num_rows_int for b in bs], args=(bs,))
+            for bs in batches]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert CB.SYNC_STATS["readbacks"] - before == workers * each
+
+
+def test_sync_readbacks_counts_the_querys_memo_misses():
+    sess = srt.session(**{"spark.rapids.tpu.profile.enabled": True})
+    _topn_query(sess).collect()
+    m = sess.last_query_metrics
+    reads = [e for e in sess._last_trace_events
+             if (e["cat"], e["name"]) == ("sync", "batch.num_rows")]
+    assert reads and m["syncReadbacks"] == len(reads)
+
+
+def _parquet(tmp_path, compression):
+    import pyarrow.parquet as pq
+    rng = np.random.default_rng(11)
+    path = str(tmp_path / f"t_{compression}.parquet")
+    pq.write_table(pa.table({"a": rng.integers(0, 9, 4000),
+                             "b": rng.random(4000),
+                             "c": rng.integers(0, 1 << 40, 4000)}),
+                   path, row_group_size=2000, compression=compression)
+    return path
+
+
+@pytest.mark.parametrize("compression", ["NONE", "SNAPPY"])
+def test_device_decode_nests_its_host_phases(tmp_path, compression):
+    import pyarrow.parquet as pq
+    from spark_rapids_tpu.io_ import device_parquet as DP
+    path = _parquet(tmp_path, compression)
+    sess = srt.session(**{"spark.rapids.tpu.profile.enabled": True})
+    df = sess.read.parquet(path).groupBy("a").agg(F.sum(F.col("b")))
+    df.collect()
+    events = sess._last_trace_events
+    decode = [e for e in events if (e["cat"], e["name"])
+              == ("scan", "device_decode")]
+    reads = [e for e in events if (e["cat"], e["name"])
+             == ("scan", "chunk_read")]
+    pages = [e for e in events if (e["cat"], e["name"]) == ("scan", "pages")]
+    cols = [e for e in events if (e["cat"], e["name"])
+            == ("eager", "parquet.decode_column")]
+    assert decode and reads and pages and cols
+    for e in reads + pages + cols:
+        assert any(_inside(e, d) for d in decode), e
+    md = pq.ParquetFile(path).metadata
+    rgs = list(range(md.num_row_groups))
+    # two columns read (a, b), one chunk a row group each
+    assert len(reads) == 2 * len(rgs) and len(pages) == 2
+    compressed = sum(md.row_group(rg).column(li).total_compressed_size
+                     for rg in rgs for li in (0, 1))
+    assert sum(e["args"]["bytes"] for e in reads) == compressed
+    if compression == "NONE":
+        assert compressed == DP.chunk_bytes(md, rgs, ["a", "b"])
+    assert sum(e["args"]["bytes"] for e in pages) == compressed
+    assert all(e["args"]["pages"] >= len(rgs) for e in pages)
+    m = sess.last_query_metrics
+    assert m["parquetChunkBytesRead"] == compressed
+    assert m["parquetPagesDecoded"] == sum(e["args"]["pages"]
+                                           for e in pages)
+    assert m["parquetBytesDecompressed"] == sum(e["args"]["out_bytes"]
+                                                for e in pages)
+    # an in-memory query carries no parquet counter
+    sess.create_dataframe(pa.table({"k": [1, 2]})).collect()
+    assert "parquetPagesDecoded" not in sess.last_query_metrics
+
+
+def test_every_new_site_is_the_null_span_with_both_sinks_off(
+        sinks_off, tmp_path, monkeypatch):
+    got = []
+    span, eager = OT.span, OT.eager
+
+    def spy_span(cat, name, **args):
+        out = span(cat, name, **args)
+        got.append((cat, name, out))
+        return out
+
+    def spy_eager(site, **args):
+        out = eager(site, **args)
+        got.append(("eager", site, out))
+        return out
+    monkeypatch.setattr(OT, "span", spy_span)
+    monkeypatch.setattr(OT, "eager", spy_eager)
+    sess = srt.session(**{"spark.rapids.tpu.profile.enabled": False})
+    _topn_query(sess).collect()
+    sess.read.parquet(_parquet(tmp_path, "SNAPPY")).groupBy("a").agg(
+        F.sum(F.col("b"))).collect()
+    seen = {(c, n) for c, n, _ in got}
+    assert {("eager", "top_n.merge"),
+            ("eager", "batch.sliced"), ("eager", "batch.concat"),
+            ("eager", "parquet.decode_column"), ("scan", "chunk_read"),
+            ("scan", "pages"), ("sync", "batch.num_rows")} <= seen, seen
+    assert [(c, n) for c, n, out in got if out is not OT._NULL_SPAN] == []
+    assert sinks_off.snapshot() == []

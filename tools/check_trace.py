@@ -20,7 +20,8 @@ Prometheus exposition contract (typed series, cumulative histogram
 buckets ending at +Inf, consistent _sum/_count).
 ``--doctor`` validates a doctor diagnosis JSON against the
 srt-doctor/1 schema (known verdict, ranked entries with
-category/ms/share/evidence).
+category/ms/share/evidence; a ``dispatch-bound`` entry is measured from
+the ``eager`` and ``dispatch`` spans, never estimated).
 ``--flow`` validates a merged trace (tools/trace_merge.py output):
 every flow id must have both an "s" start and an "f" finish, each
 anchored inside a real span on the same pid/tid, and every pid with
@@ -41,8 +42,9 @@ KNOWN_PH = ("X", "C", "i", "M", "B", "E", "s", "t", "f")
 #: CATEGORIES); unknown categories stay opaque — listed for reference
 #: and for --require-cat hints, not validated
 KNOWN_CATS = ("query", "plan", "task", "op", "stage", "dispatch", "compile",
-              "scan", "sync", "h2d", "d2h", "spill", "shuffle", "sem_wait",
-              "fault", "queue", "encode", "admission", "cancel", "fatal")
+              "eager", "scan", "sync", "h2d", "d2h", "spill", "shuffle",
+              "sem_wait", "fault", "queue", "encode", "admission", "cancel",
+              "fatal", "broadcast", "join", "sort", "window")
 
 
 def check(path: str, min_events: int = 1, require_cat: str = "",
@@ -243,6 +245,11 @@ def check_doctor(path: str):
                              f"{e['share']}")
         if e["ms"] > last_ms + 1e-9:
             raise ValueError("ranked list not sorted by ms desc")
+        if e["category"] == "dispatch-bound" and \
+                "estimated" in (e["evidence"] or {}):
+            raise ValueError(f"ranked[{i}] dispatch-bound is estimated: "
+                             f"it is measured from the eager and "
+                             f"dispatch spans' self time")
         last_ms = e["ms"]
     if ranked and doc["verdict"] != ranked[0]["category"]:
         raise ValueError("verdict != top ranked category")
