@@ -41,6 +41,8 @@ import struct
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .. import types as T
@@ -242,7 +244,6 @@ class Dictionary:
 
 
 def _register_pytrees():
-    import jax
     jax.tree_util.register_pytree_node_class(Dictionary)
 
 
@@ -261,7 +262,6 @@ def dictionary_from_values(dtype: T.DataType,
             chars[i, :len(v)] = np.frombuffer(v, dtype=np.uint8)
     validity = np.zeros(cap, dtype=bool)
     validity[:k] = True
-    import jax.numpy as jnp
     col = DeviceColumn(dtype, jnp.asarray(chars), jnp.asarray(validity),
                        lengths=jnp.asarray(lengths))
     h = _hash_values(list(values))
@@ -338,8 +338,6 @@ class DictEncodedColumn(DeviceColumn):
         m = self._mat
         if m is not None:
             return m
-        import jax.numpy as jnp
-
         from ..observability import tracer as _trace
         d = self.dictionary.column
         with _trace.eager("encoded.dict_materialize"):
@@ -392,7 +390,6 @@ class DictEncodedColumn(DeviceColumn):
             self.dictionary, _fix_1d(self.validity, new_capacity, False))
 
     def window(self, start, capacity: int, live) -> "DictEncodedColumn":
-        import jax.numpy as jnp
         from .column import _window
         validity = _window(self.validity, start, capacity) & live
         codes = jnp.where(validity, _window(self.codes, start, capacity), 0)
@@ -404,7 +401,6 @@ class DictEncodedColumn(DeviceColumn):
         filters, join output assembly, group-by key emission, and sorts.
         ``join_codes`` does not survive (it is only valid for the exact
         batch pair the join lowering prepared)."""
-        import jax.numpy as jnp
         safe = jnp.clip(idx, 0, self.capacity - 1)
         validity = self.validity[safe]
         if idx_valid is not None:
@@ -421,6 +417,26 @@ class DictEncodedColumn(DeviceColumn):
 # --------------------------------------------------------------------------
 # RLEColumn
 # --------------------------------------------------------------------------
+
+@jax.jit
+def _expand_rle(values, aux, run_ends, validity):
+    """Run values at every row, with null rows zeroed.  A row's run is
+    the number of ``run_ends`` at or before it — what
+    ``searchsorted(run_ends, row, side="right")`` returns for the sorted
+    ends — counted by a scatter of one mark a run end (ends at capacity,
+    the padding, are dropped; a zero-length run marks twice) and a
+    two-level prefix sum: a search compiles to a ``while`` of
+    whole-array gathers, one a round.  Every shape is the padded one, so
+    one program serves every ``num_runs`` of a capacity."""
+    from ..ops.ranks import prefix_sum
+    marks = jnp.zeros(validity.shape[0], jnp.int32
+                      ).at[run_ends].add(1, mode="drop")
+    run_idx = jnp.clip(prefix_sum(jnp, marks), 0, values.shape[0] - 1)
+    data = jnp.where(validity, values[run_idx], 0)
+    if aux is not None:
+        aux = jnp.where(validity, aux[run_idx], 0)
+    return data, aux
+
 
 class RLEColumn(DeviceColumn):
     """Run-length encoded fixed-width column: ``run_values`` (a plain
@@ -462,19 +478,11 @@ class RLEColumn(DeviceColumn):
         m = self._mat
         if m is not None:
             return m
-        import jax.numpy as jnp
-
         from ..observability import tracer as _trace
         with _trace.eager("encoded.rle_materialize"):
-            idx = jnp.arange(self.capacity, dtype=jnp.int32)
-            run_idx = jnp.searchsorted(self.run_ends, idx, side="right")
-            run_idx = jnp.clip(run_idx, 0, self.run_values.capacity - 1)
-            data = jnp.where(self.validity,
-                             self.run_values.data[run_idx], 0)
-            aux = None
-            if self.run_values.aux is not None:
-                aux = jnp.where(self.validity,
-                                self.run_values.aux[run_idx], 0)
+            data, aux = _expand_rle(self.run_values.data,
+                                    self.run_values.aux, self.run_ends,
+                                    self.validity)
         m = DeviceColumn(self.dtype, data, self.validity, aux=aux)
         self._mat = m
         _bump("materializations")
@@ -519,7 +527,6 @@ class RLEColumn(DeviceColumn):
 
 
 def _register_encoded_pytrees():
-    import jax
     jax.tree_util.register_pytree_node_class(Dictionary)
     jax.tree_util.register_pytree_node_class(DictEncodedColumn)
     jax.tree_util.register_pytree_node_class(RLEColumn)
@@ -563,7 +570,6 @@ def encode_string_column_np(dtype: T.DataType, values: List[Optional[bytes]],
         if v is not None:
             codes_np[i] = index[v]
             valid_np[i] = True
-    import jax.numpy as jnp
     _bump("columns_encoded")
     span = _trace_encode_span("dict.encode", rows=n, dict_size=k)
     if span is not None:
@@ -618,7 +624,6 @@ def encode_string_arrow(arr, dtype: T.DataType, capacity: int,
         codes_np = np.zeros(capacity, dtype=np.int32)
         codes_np[:n] = remap[idx_np.astype(np.int64)]
         codes_np[:n][~valid_np[:n]] = 0
-        import jax.numpy as jnp
         _bump("columns_encoded")
         span = _trace_encode_span("dict.encode", rows=n, dict_size=k)
         if span is not None:
@@ -644,7 +649,6 @@ def retain_scan_dictionary(dtype: T.DataType, mat: np.ndarray,
     None to decline (cardinality over budget, duplicate entries — e.g.
     repeated values across ORC stripe dictionaries — or encoding off);
     the caller then gathers exactly as before."""
-    import jax.numpy as jnp
     k = int(len(lens_np))
     if not enabled(conf) or not is_string_like(dtype) \
             or not _cardinality_ok(k, n_rows, _max_cardinality(conf)):
@@ -698,7 +702,6 @@ def encode_rle_numpy(dtype: T.DataType, data_np: np.ndarray,
     rvalid[:num_runs] = live_valid[starts]
     rends = np.full(run_cap, capacity, dtype=np.int32)
     rends[:num_runs] = ends
-    import jax.numpy as jnp
     run_col = DeviceColumn(dtype, jnp.asarray(rv), jnp.asarray(rvalid))
     _bump("rle_columns_encoded")
     return RLEColumn(dtype, run_col, jnp.asarray(rends), num_runs,
@@ -801,7 +804,6 @@ def try_concat_dict_columns(cols: Sequence[DeviceColumn],
     each piece's codes.  Returns None to decline (caller materializes)."""
     if not all(isinstance(c, DictEncodedColumn) for c in cols):
         return None
-    import jax.numpy as jnp
     dtype = cols[0].dtype
     first = cols[0].dictionary
     if same_dictionary(cols):
@@ -838,7 +840,6 @@ def try_concat_dict_columns(cols: Sequence[DeviceColumn],
 
 
 def _concat_padded(arrs, counts, out_capacity, fill):
-    import jax.numpy as jnp
     live = [a[:c] for a, c in zip(arrs, counts)]
     cat = jnp.concatenate(live) if live else arrs[0][:0]
     return jnp.pad(cat, (0, out_capacity - cat.shape[0]),
@@ -905,7 +906,6 @@ def lower_join_codes(probe_col: DictEncodedColumn,
     mapping = map_codes_between(probe_col.dictionary, build_col.dictionary)
     if mapping is None:
         return None
-    import jax.numpy as jnp
     m = jnp.asarray(mapping)
     safe = jnp.clip(probe_col.codes, 0, mapping.shape[0] - 1)
     jc = jnp.where(probe_col.validity, m[safe], 0)

@@ -11,6 +11,7 @@ import numpy as np
 from ...columnar.batch import ColumnarBatch
 from ...observability import tracer as _trace
 from ...columnar.column import DeviceColumn
+from ...columnar.encoded import RLEColumn
 from ... import types as T
 from ..expressions.core import (Alias, AttributeReference, BoundReference,
                                 EvalContext, Expression, bind_references)
@@ -192,6 +193,8 @@ class InMemoryScanExec(PhysicalPlan):
             if len(batch.names) > len(self._names):
                 batch = batch.select(
                     [batch.names.index(n) for n in self._names])
+            tctx.inc_metric("scanRleColumns", sum(
+                isinstance(c, RLEColumn) for c in batch.columns))
             yield batch
 
     def simple_string(self):

@@ -90,6 +90,167 @@ def test_rle_encode_roundtrip():
     assert device_to_arrow(enc).equals(device_to_arrow(raw))
 
 
+def _rle_layout(kind: str):
+    """(run ends, run validity, capacity) of one run layout; the ends are
+    padded with capacity as every producer pads them."""
+    cap = 256
+    if kind == "full":                 # the last run ends at capacity
+        ends = np.arange(16, cap + 1, 16)
+    elif kind == "partial":            # live prefix of 200 rows
+        ends = np.array([3, 50, 51, 120, 200])
+    elif kind == "one_run":
+        ends = np.array([180])
+    elif kind == "every_row":          # a run a row, all of capacity
+        ends = np.arange(1, cap + 1)
+    elif kind == "zero_length":        # empty runs, as a wire frame holds
+        ends = np.array([0, 0, 7, 7, 7, 30, 64, 64, 99])
+    else:                              # "null_breaks": NULL runs between
+        ends = np.array([10, 14, 40, 41, 90, 130, 131, 170])
+    k = len(ends)
+    run_cap = 1 << max(k - 1, 0).bit_length()
+    padded = np.full(max(run_cap, 1), cap, dtype=np.int32)
+    padded[:k] = ends
+    rvalid = np.zeros(padded.shape[0], dtype=bool)
+    rvalid[:k] = True
+    if kind == "null_breaks":
+        rvalid[1:k:2] = False
+    return padded, k, rvalid, cap
+
+
+def _rle_column(kind: str, dtype_name: str):
+    """An RLEColumn of one layout and one value type, built as the scan
+    and the wire reader build it: null runs hold zeroed values, rows past
+    the last run are dead."""
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    ends, k, rvalid, cap = _rle_layout(kind)
+    rng = np.random.default_rng(len(kind) * 7 + len(dtype_name))
+    np_dt = {"int8": np.int8, "int16": np.int16, "int32": np.int32,
+             "int64": np.int64, "decimal_aux": np.int64}[dtype_name]
+    info = np.iinfo(np_dt)
+    vals = rng.integers(info.min, info.max, ends.shape[0],
+                        dtype=np_dt, endpoint=True)
+    vals[~rvalid] = 0
+    aux = None
+    if dtype_name == "decimal_aux":
+        dt = T.DecimalType(38, 2)
+        aux = rng.integers(-2**40, 2**40, ends.shape[0]).astype(np.int64)
+        aux[~rvalid] = 0
+    else:
+        dt = {"int8": T.ByteType(), "int16": T.ShortType(),
+              "int32": T.IntegerType(), "int64": T.LongType()}[dtype_name]
+    run_of_row = np.searchsorted(ends, np.arange(cap), side="right")
+    valid = np.zeros(cap, dtype=bool)
+    live = run_of_row < k
+    valid[live] = rvalid[run_of_row[live]]
+    runs = DeviceColumn(dt, vals, rvalid, aux=aux)
+    return E.RLEColumn(dt, runs, ends, k, valid)
+
+
+def _searched(col):
+    """The expansion as it was before the prefix sum: a binary search of
+    every row among the run ends."""
+    import jax.numpy as jnp
+    idx = jnp.searchsorted(jnp.asarray(col.run_ends),
+                           jnp.arange(col.capacity, dtype=jnp.int32),
+                           side="right")
+    idx = jnp.clip(idx, 0, col.run_values.capacity - 1)
+    v = jnp.asarray(col.validity)
+    data = jnp.where(v, jnp.asarray(col.run_values.data)[idx], 0)
+    aux = None if col.run_values.aux is None else jnp.where(
+        v, jnp.asarray(col.run_values.aux)[idx], 0)
+    return data, aux
+
+
+@pytest.mark.parametrize("mode", ["eager", "jit"])
+@pytest.mark.parametrize("dtype_name",
+                         ["int8", "int16", "int32", "int64", "decimal_aux"])
+@pytest.mark.parametrize("kind", ["full", "partial", "one_run", "every_row",
+                                  "zero_length", "null_breaks"])
+def test_rle_expansion_matches_search_and_numpy(kind, dtype_name, mode):
+    import jax
+    col = _rle_column(kind, dtype_name)
+    if mode == "eager":
+        data, aux = col.data, col.aux
+    else:
+        data, aux = jax.jit(lambda c: (c.data, c.aux))(col)
+    old_data, old_aux = _searched(col)
+    ref = E.materialize_np(col)
+    data = np.asarray(data)
+    assert data.dtype == np.asarray(old_data).dtype
+    np.testing.assert_array_equal(data, np.asarray(old_data))
+    np.testing.assert_array_equal(data, np.asarray(ref.data))
+    if dtype_name == "decimal_aux":
+        np.testing.assert_array_equal(np.asarray(aux), np.asarray(old_aux))
+        np.testing.assert_array_equal(np.asarray(aux), np.asarray(ref.aux))
+    else:
+        assert aux is None and ref.aux is None
+    # null rows and rows past the last run read zero
+    assert not data[~np.asarray(col.validity)].any()
+
+
+def test_rle_expansion_has_no_loop_and_one_program_a_capacity():
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    cap = 1 << 16
+
+    def col_of(num_runs):
+        ends = np.full(1024, cap, dtype=np.int32)
+        ends[:num_runs] = np.linspace(40, cap - 3, num_runs).astype(np.int32)
+        runs = DeviceColumn(T.IntegerType(),
+                            np.arange(1024, dtype=np.int32),
+                            np.arange(1024) < num_runs)
+        return E.RLEColumn(T.IntegerType(), runs, ends, num_runs,
+                           np.arange(cap) < cap - 3)
+
+    text = jax.jit(lambda c: c.data).lower(col_of(700)).as_text()
+    assert "while" not in text
+    # the control: the search it replaced lowers to a loop
+    searched = jax.jit(lambda e: jnp.searchsorted(
+        e, jnp.arange(cap, dtype=jnp.int32), side="right")).lower(
+        jnp.zeros(1024, jnp.int32)).as_text()
+    assert "while" in searched
+    # two run counts of one capacity (and one run bucket): one program
+    a, b = col_of(700), col_of(913)
+    assert a.run_values.capacity == b.run_values.capacity
+    a.materialized()
+    after_first = E._expand_rle._cache_size()
+    b.materialized()
+    assert E._expand_rle._cache_size() == after_first
+    np.testing.assert_array_equal(np.asarray(b.data),
+                                  np.asarray(_searched(b)[0]))
+
+
+def _ticket_table(rows: int, seed: int) -> pa.Table:
+    """store_sales in miniature: rows grouped by ticket, so the ticket's
+    date and demographics repeat along it, and two item-level columns."""
+    rng = np.random.default_rng(seed)
+    ticket = np.sort(rng.integers(0, rows // 10, rows))
+    date_of = rng.integers(2450816, 2452642, rows // 10 + 1)
+    cdemo_of = rng.integers(1, 1920801, rows // 10 + 1)
+    return pa.table({
+        "ss_ticket_number": ticket,
+        "ss_sold_date_sk": date_of[ticket],
+        "ss_cdemo_sk": cdemo_of[ticket],
+        "ss_item_sk": rng.integers(1, 204001, rows),
+        "ss_quantity": rng.integers(1, 101, rows)})
+
+
+@pytest.mark.parametrize("columns,rle_a_batch", [
+    (("ss_sold_date_sk", "ss_cdemo_sk", "ss_quantity"), 2),
+    (("ss_item_sk", "ss_quantity"), 0)], ids=["ticket_level", "random"])
+def test_scan_counts_rle_columns(columns, rle_a_batch):
+    parts = 3
+    sess = _sess(True)
+    df = sess.create_dataframe(_ticket_table(6000, 41), num_partitions=parts)
+    got = (df.select(*columns).groupBy(columns[0])
+           .agg(*[F.sum(F.col(c)).alias(c) for c in columns[1:]])
+           .orderBy(columns[0]).collect().to_pylist())
+    assert len(got) > 0
+    m = sess.last_query_metrics
+    assert m.get("scanRleColumns", 0) == rle_a_batch * parts, m
+
+
 def test_high_cardinality_declines():
     t = pa.table({"s": pa.array([f"u{i}" for i in range(5000)])})
     enc = arrow_to_device(
