@@ -393,5 +393,7 @@ class TpuOverrides:
 def explain_potential_plan(df, all_ops: bool = True) -> str:
     """Public explain API (reference ``ExplainPlan.explainPotentialGpuPlan``)."""
     from .column_pruning import prune_columns
-    meta = TpuOverrides.apply(prune_columns(df._plan), df._session.conf)
+    from .join_order import order_joins
+    meta = TpuOverrides.apply(prune_columns(order_joins(df._plan)[0]),
+                              df._session.conf)
     return meta.explain(all_ops)

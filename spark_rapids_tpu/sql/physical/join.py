@@ -31,6 +31,7 @@ from ...ops.join import (JoinBuildSide, JoinInfo, compact_indices,
                          prepare_build_side, probe_join_info)
 from ..expressions.core import (AttributeReference, EvalContext, Expression,
                                 bind_references)
+from ..join_order import share_note
 from .base import TPU, PhysicalPlan, TaskContext
 from .exchange import BroadcastExchangeExec
 
@@ -116,6 +117,10 @@ _LIKE_SIZE = 8
 class BaseJoinExec(PhysicalPlan):
     """Shared machinery: side normalization (right joins flip to left),
     output schema, pair gathering, residual-condition assembly."""
+
+    #: the planner's estimate of the fact rows a star's dimension keeps, for
+    #: explain alone (``sql/join_order.py``)
+    kept_share: Optional[float] = None
 
     def __init__(self, how: str, left_keys: Sequence[Expression],
                  right_keys: Sequence[Expression],
@@ -861,7 +866,8 @@ class BaseJoinExec(PhysicalPlan):
         if self._probe_steps:
             chain = " -> ".join(s.node_name() for s in self._probe_steps)
             c += f" [fusedProbe: {chain}]"
-        return f"{self.node_name()} {self.how} [{keys}]{c}"
+        return (f"{self.node_name()} {self.how} [{keys}]{c}"
+                f"{share_note(self.kept_share)}")
 
 
 class ShuffledHashJoinExec(BaseJoinExec):
@@ -1217,6 +1223,8 @@ class AdaptiveJoinExec(PhysicalPlan):
     left child is; ``plan_join``), and its size is that of its live rows:
     a filter's output still has its input's capacity."""
 
+    kept_share: Optional[float] = None
+
     def __init__(self, node, left: PhysicalPlan, right: PhysicalPlan,
                  backend, conf, build_left: bool = False):
         super().__init__(left, right)
@@ -1311,7 +1319,8 @@ class AdaptiveJoinExec(PhysicalPlan):
     def simple_string(self):
         tag = self.chosen_strategy or "undecided"
         side = ", build=left" if self._build_left else ""
-        return f"{self.node_name()} {self._node.how} [aqe: {tag}{side}]"
+        return (f"{self.node_name()} {self._node.how} [aqe: {tag}{side}]"
+                f"{share_note(self.kept_share)}")
 
 
 # --------------------------------------------------------------------------

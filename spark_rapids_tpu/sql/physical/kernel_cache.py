@@ -365,6 +365,10 @@ def clear_cache() -> None:
     # recompile gather programs for sizes that immediately mis-speculate
     from .join import clear_selectivity
     clear_selectivity()
+    # and the join-order rule's estimates: a clear plans from nothing
+    # learned
+    from ..join_order import clear_estimates
+    clear_estimates()
 
 
 def release_compiled_programs() -> None:

@@ -319,6 +319,9 @@ class Join(LogicalPlan):
     #: (F.broadcast(df) / df.hint("broadcast")): the join planner skips
     #: the size threshold, like Spark's ResolveHints + JoinSelection
     broadcast_hint: bool = False
+    #: the share of a star's fact rows this join's dimension is estimated
+    #: to keep, where the join-order rule estimated it (``join_order.py``)
+    kept_share: Optional[float] = None
 
     def __post_init__(self):
         self.children = (self.left, self.right)
@@ -342,7 +345,8 @@ class Join(LogicalPlan):
     def simple_string(self):
         keys = ", ".join(f"{l.sql()}={r.sql()}" for l, r in
                          zip(self.left_keys, self.right_keys))
-        return f"Join {self.how} [{keys}]"
+        from .join_order import share_note
+        return f"Join {self.how} [{keys}]{share_note(self.kept_share)}"
 
 
 @dataclass(eq=False)

@@ -9,7 +9,7 @@ from typing import List, Optional
 from ..config import RapidsConf
 from ..parallel.partitioning import (HashPartitioning, RangePartitioning,
                                      RoundRobinPartitioning, SinglePartitioning)
-from . import column_pruning
+from . import column_pruning, join_order
 from . import plan as P
 from .expressions.core import AttributeReference
 from .overrides import PlanMeta, TpuOverrides
@@ -31,7 +31,8 @@ class Planner:
     # ------------------------------------------------------------------
     def plan(self, logical: P.LogicalPlan) -> PhysicalPlan:
         # a new tree: everything below reads (and keys by node identity)
-        # the pruned plan, the caller's is left as it was
+        # the reordered and pruned plan, the caller's is left as it was
+        logical, self.joins_reordered = join_order.order_joins(logical)
         logical = column_pruning.prune_columns(logical)
         self._window_group_limits = {}
         parents: dict = {}
@@ -71,7 +72,9 @@ class Planner:
         # plan-time fusion coverage counters (wholeStageOps/unfusedOps)
         # fold into last_query_metrics via the collect_metrics walk
         from .physical.fusion import annotate_stage_coverage
-        return annotate_stage_coverage(phys)
+        phys = annotate_stage_coverage(phys)
+        phys.metrics["joinsReordered"] = float(self.joins_reordered)
+        return phys
 
     # ------------------------------------------------------------------
     def _convert(self, meta: PlanMeta) -> PhysicalPlan:
@@ -137,6 +140,7 @@ class Planner:
         elif isinstance(node, P.Join):
             from .physical.join import plan_join
             exec_ = plan_join(node, kids[0], kids[1], be, self.conf)
+            exec_.kept_share = node.kept_share
         elif isinstance(node, P.MapInPandas):
             from .physical.python_execs import MapInPandasExec
             exec_ = MapInPandasExec(node.func, node.out_schema, kids[0],

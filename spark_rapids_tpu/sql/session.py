@@ -793,8 +793,10 @@ class TpuSession:
         if logical is None:
             raise RuntimeError("no query has been collected yet")
         from .column_pruning import prune_columns
+        from .join_order import order_joins
         # the placement report describes the plan that executes
-        meta = TpuOverrides.apply(prune_columns(logical), self._conf)
+        meta = TpuOverrides.apply(prune_columns(order_joins(logical)[0]),
+                                  self._conf)
         from ..config import OPTIMIZER_ENABLED
         if bool(self._conf.get(OPTIMIZER_ENABLED)):
             # keep the placement report consistent with the physical plan

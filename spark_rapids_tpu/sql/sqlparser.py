@@ -2243,7 +2243,9 @@ class QueryBuilder:
         conditions, joining relations in connected order (greedy, driven
         by equality conjuncts) so no unfiltered cross product ever
         materializes; anything unplaceable (subquery predicates,
-        ambiguous references) stays in the residual WHERE.  Returns
+        ambiguous references) stays in the residual WHERE.  The order is
+        connectivity alone; the planner reorders a star's dimensions by
+        the share of the fact each keeps (``sql/join_order.py``).  Returns
         (joined df, stmt with the consumed conjuncts removed)."""
         import dataclasses
 

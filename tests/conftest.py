@@ -40,6 +40,9 @@ QUICK_MODULES = {
     # TPC-DS q98 (ISSUE 35): the window, the global sort and the range
     # exchange against the benchmark's reference, NULLs, ties, zero totals
     "test_tpcds_report",
+    # the planner's star-join order: q98, q7 and Q3 planned with the rule and
+    # without it, the estimates, the runs it leaves alone
+    "test_join_order",
     "test_memory", "test_native", "test_cross_slice", "test_hive_udf",
     # observability tracer: tier-1 per ISSUE 3 (trace regressions must
     # surface in the quick gate, not only in full CI)
